@@ -210,12 +210,10 @@ class TestTpuEngineLauncher:
         proc = _spawn_broker(
             tmp_path, "tpu-0", off,
             # tests run the device kernel on CPU (conftest contract);
-            # the subprocess must do the same, with the shared compile
-            # cache so the kernel compile doesn't dominate the test
-            {
-                "JAX_PLATFORMS": "cpu",
-                "ZEEBE_JAX_CACHE_DIR": os.path.join(REPO, ".jax_cache"),
-            },
+            # the subprocess must do the same. Its compile cache is the
+            # launcher's own (<checkout>/.jax_cache unless the
+            # environment places it)
+            {"JAX_PLATFORMS": "cpu"},
             args=["--config", str(cfg_path), "--data-dir", str(tmp_path / "d")],
         )
         try:

@@ -20,49 +20,8 @@ from zeebe_tpu.models.bpmn.builder import Bpmn
 from zeebe_tpu.protocol.enums import RecordType, ValueType
 from zeebe_tpu.protocol.intents import WorkflowInstanceIntent as WI
 from zeebe_tpu.runtime import Broker, ControlledClock
+from zeebe_tpu.testing.parity import record_signature
 from zeebe_tpu.tpu import TpuPartitionEngine
-
-SIG_TYPES = {
-    int(ValueType.WORKFLOW_INSTANCE),
-    int(ValueType.JOB),
-    int(ValueType.INCIDENT),
-    int(ValueType.TIMER),
-    int(ValueType.MESSAGE),
-    int(ValueType.MESSAGE_SUBSCRIPTION),
-    int(ValueType.WORKFLOW_INSTANCE_SUBSCRIPTION),
-}
-
-
-def record_signature(records):
-    out = []
-    for r in records:
-        if int(r.metadata.value_type) not in SIG_TYPES:
-            continue
-        out.append(
-            (
-                r.position,
-                int(r.metadata.record_type),
-                int(r.metadata.value_type),
-                int(r.metadata.intent),
-                r.key,
-                r.source_record_position,
-                int(r.metadata.rejection_type),
-                r.metadata.rejection_reason,
-                getattr(r.value, "activity_id", None) or None,
-                dict(getattr(r.value, "payload", {}) or {}),
-                getattr(r.value, "scope_instance_key", None),
-                getattr(r.value, "workflow_instance_key", None),
-                getattr(r.value, "retries", None),
-                getattr(r.value, "worker", None),
-                getattr(r.value, "error_type", None),
-                getattr(r.value, "error_message", None),
-                getattr(
-                    getattr(r.value, "headers", None), "activity_instance_key", None
-                ),
-            )
-        )
-    return out
-
 
 class DualRig:
     """Runs the same scenario against oracle and TPU brokers."""

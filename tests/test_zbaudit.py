@@ -30,7 +30,6 @@ from zeebe_tpu.tpu import (
     kernel,
     state as state_mod,
 )
-from zeebe_tpu.tpu.shard import _shard_map
 
 from tools.zbaudit import audit, audit_program, load_budget
 from tools.zbaudit import passes as passes_mod
@@ -150,7 +149,7 @@ class TestCollectiveVolume:
     @staticmethod
     def _psum_program():
         mesh = Mesh(np.asarray(jax.devices()), ("partitions",))
-        return _shard_map(
+        return jax.shard_map(
             lambda x: jax.lax.psum(x, "partitions"),
             mesh=mesh, in_specs=P("partitions"), out_specs=P(),
         )

@@ -291,9 +291,16 @@ def pass_boundary(audited: List[AuditedEntry], budget: dict, report: dict):
 
 # -- collective-volume -------------------------------------------------------
 
+# jax 0.9.0's names for the primitives that move bytes between devices.
+# Under shard_map's default check_vma=True, ``lax.psum`` / ``all_gather``
+# trace to the ``*_invariant`` forms; the registered programs pass
+# check_vma=False and keep the plain names. Both are listed: a program
+# audited under either setting counts the same bytes.
 _COLLECTIVES = {
-    "all_to_all", "psum", "psum2", "all_gather", "ppermute", "pmin", "pmax",
-    "reduce_scatter", "psum_scatter",
+    "all_to_all", "ragged_all_to_all", "psum", "psum_invariant",
+    "all_gather", "all_gather_invariant", "all_gather_reduced", "ppermute",
+    "pmin", "pmax", "reduce_scatter", "unreduced_psum",
+    "unreduced_reduce_scatter", "pgather",
 }
 
 

@@ -39,21 +39,12 @@ def main(argv=None) -> int:
         args.config or args.config_positional or os.environ.get("ZEEBE_CFG")
     )
 
-    # Honor JAX_PLATFORMS even where a sitecustomize pre-injects another
-    # platform plugin: the engine choice must be the operator's.
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     # Persistent XLA compile cache: the device kernel is a large program
-    # and recompiling it on every broker start is minutes of downtime.
-    if os.environ.get("ZEEBE_JAX_CACHE_DIR"):
-        import jax
+    # and recompiling it on every broker start is boot time the operator
+    # pays again (JAX_COMPILATION_CACHE_DIR places it; else the checkout).
+    from zeebe_tpu import compile_cache
 
-        jax.config.update(
-            "jax_compilation_cache_dir", os.environ["ZEEBE_JAX_CACHE_DIR"]
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
+    compile_cache.enable()
 
     from zeebe_tpu.runtime.cluster_broker import ClusterBroker
     from zeebe_tpu.runtime.config import load_config

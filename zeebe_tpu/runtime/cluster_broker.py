@@ -2783,8 +2783,15 @@ class ClusterBroker(Actor):
                 # tear the subscription down when the worker's connection
                 # dies, else activated jobs black-hole into dead credits
                 # (reference: transport channel close listeners)
+                # (on the broker actor, not the transport thread that
+                # sees the close: dropping reads and rebinds engine state,
+                # which the actor's in-flight step has donated)
                 conn.on_close(
-                    lambda: self._drop_job_subscription(partition_id, subscriber_key)
+                    lambda: self.actor.run(
+                        lambda: self._drop_job_subscription(
+                            partition_id, subscriber_key
+                        )
+                    )
                 )
             backlog = server.engine.add_job_subscription(
                 JobSubscription(

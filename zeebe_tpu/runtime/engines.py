@@ -10,9 +10,14 @@ section; both serve the same record contract.
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+import time
+from typing import Callable, Dict, Optional
 
 from zeebe_tpu.runtime.config import BrokerCfg
+
+# seconds the last device-engine install spent in each boot step (the
+# autotune and the selfcheck are memoized: later installs read ~0)
+LAST_BOOT_SECONDS: Dict[str, float] = {}
 
 
 def engine_factory_from_config(
@@ -42,8 +47,12 @@ def engine_factory_from_config(
                 # off-TPU.
                 from zeebe_tpu.tpu import autotune, pallas_ops
 
+                t0 = time.perf_counter()
                 autotune.ensure_autotuned()
+                t1 = time.perf_counter()
                 pallas_ops.selfcheck()
+                LAST_BOOT_SECONDS["autotune"] = t1 - t0
+                LAST_BOOT_SECONDS["selfcheck"] = time.perf_counter() - t1
             # mesh placement: the broker's DevicePlan assigned this leader
             # partition a device at install time — the engine's state
             # commits there and its waves compute there, concurrently with
@@ -90,7 +99,9 @@ def engine_factory_from_config(
                 # first served batch (which blocks the broker actor and
                 # times out every client request) — off-TPU compiles are
                 # fast and tests deploy immediately, so skip there
+                t0 = time.perf_counter()
                 engine.warm()
+                LAST_BOOT_SECONDS["warm"] = time.perf_counter() - t0
             return engine
 
         return factory

@@ -379,17 +379,21 @@ class DiskFaults:
         raise ValueError(f"unknown crash point {point!r}")
 
 
-def replay_oracle(records, partition_id: int = 0, num_partitions: int = 1):
+def replay_oracle(
+    records, partition_id: int = 0, num_partitions: int = 1, repository=None
+):
     """Replay committed ``records`` through a fresh host oracle engine with
     side effects suppressed (results are discarded — every follow-up they
     would produce is already IN the committed sequence), exactly the
-    recovery replay contract. Returns the engine for state comparison."""
+    recovery replay contract. Returns the engine for state comparison.
+    Only partition 0's log holds the DEPLOYMENT records: replay it first
+    and pass its ``repository`` on to the replays of the other partitions."""
     from zeebe_tpu.engine.interpreter import PartitionEngine, WorkflowRepository
 
     engine = PartitionEngine(
         partition_id=partition_id,
         num_partitions=num_partitions,
-        repository=WorkflowRepository(),
+        repository=repository if repository is not None else WorkflowRepository(),
         clock=lambda: 0,
     )
     for record in records:

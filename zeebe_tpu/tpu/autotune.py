@@ -10,12 +10,25 @@ the winner has flipped between builds. A static env default (the old
 both paths per op family with a dependent-chain microbench ONCE at engine
 boot and installs the winners in ``pallas_ops``' dispatch table.
 
+What the A/B decides, and what it does not: it runs at ``_T`` = 2^12 table
+rows, so a family's winner holds only among tables that a pallas pass can
+hold in VMEM at all. The table-size rule (``pallas_ops._fits_vmem``, at
+every call site) decides the rest: a family that won here still takes the
+XLA form for a table whose padded windows do not fit — at the served 2^20
+rows, every 2D row table. The cost of a pallas pass also grows with the
+table it copies through VMEM, which a 2^12-row A/B does not see; whether
+the winner changes with size below the VMEM bound is not measured.
+
 Rules that shape the measurement (all empirical, see PERF_NOTES):
 - chains must be DEPENDENT (each op consumes the previous op's output) —
   isolated op timing is pipelined and lies ~20x;
 - decisions cache on disk keyed by a build fingerprint (jax/jaxlib
-  versions + device kind + platform version), so a fleet restart pays the
+  versions + device kind + platform version) under the checkout's cache
+  root (``zeebe_tpu.compile_cache``), so a fleet restart pays the
   microbench once per build, not once per boot;
+- an arm that fails to compile or run fails the boot: a Mosaic error is
+  never turned into a choice (a table too large for VMEM is not an error
+  — the size rule sends it to XLA before anything compiles);
 - ``ZB_PALLAS=0/1`` remains the manual override (checked inside
   ``pallas_ops.use_pallas``, so a tuned table never shadows it), and
   ``ZB_AUTOTUNE=0`` skips tuning entirely (keeps the defaults);
@@ -36,6 +49,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from zeebe_tpu import compile_cache
 from zeebe_tpu.tpu import hashmap, jit_registry, pallas_ops as pops
 
 _CHAIN = 8   # dependent ops per timed call (amortizes dispatch overhead)
@@ -47,7 +61,9 @@ _T = 1 << 12  # table rows for the probes
 _B = 1 << 11  # batch per op
 _K = 16       # row width
 
-_state: Dict[str, object] = {"done": False, "source": "default"}
+_state: Dict[str, object] = {
+    "done": False, "source": "default", "timings_us": {},
+}
 
 
 def dispatch_source() -> str:
@@ -57,31 +73,31 @@ def dispatch_source() -> str:
     return str(_state["source"])
 
 
+def dispatch_timings() -> dict:
+    """Per-family microbench timings (µs) behind the current dispatch —
+    measured this boot or read back from the cache; empty otherwise."""
+    return dict(_state["timings_us"])
+
+
 def build_fingerprint() -> str:
     """Identity of the (jax, jaxlib, libtpu/device) combination a cached
     decision table is valid for."""
+    import jax.extend.backend
     import jaxlib
 
-    try:
-        dev = jax.devices()[0]
-        kind = f"{dev.platform}:{getattr(dev, 'device_kind', '?')}"
-    except Exception:  # noqa: BLE001 - no backend at all
-        kind = "none"
-    parts = f"{jax.__version__}|{jaxlib.__version__}|{kind}"
-    try:
-        parts += f"|{jax.extend.backend.get_backend().platform_version}"
-    except Exception:  # noqa: BLE001 - platform_version is best-effort
-        pass
-    return parts
+    dev = jax.devices()[0]
+    return (
+        f"{jax.__version__}|{jaxlib.__version__}|"
+        f"{dev.platform}:{dev.device_kind}|"
+        f"{jax.extend.backend.get_backend().platform_version}"
+    )
 
 
 def _cache_path() -> str:
-    root = os.environ.get(
-        "ZB_AUTOTUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "zbtpu"),
-    )
     digest = hashlib.sha256(build_fingerprint().encode()).hexdigest()[:16]
-    return os.path.join(root, f"autotune-{digest}.json")
+    return os.path.join(
+        compile_cache.cache_root(), f"autotune-{digest}.json"
+    )
 
 
 def _load_cache() -> Optional[dict]:
@@ -306,20 +322,17 @@ def measure(progress: Optional[Callable[[str], None]] = None):
         else:
             with pops.forced("xla"):
                 t_xla = _time(jitted_x)
+        # a pallas arm that fails to compile or run raises out of the boot:
+        # at these shapes every table fits VMEM, so an error here is a
+        # broken kernel, not a size refusal
         with pops.forced("pallas"):
-            try:
-                t_pal = _time(jitted_p)
-            except Exception as e:  # noqa: BLE001 - a Mosaic lowering that
-                # fails to compile on this build simply loses the A/B
-                t_pal = float("inf")
-                timings.setdefault(family, {})["pallas_error"] = repr(e)[:200]
+            t_pal = _time(jitted_p)
         win = t_pal * _MARGIN < t_xla
         decisions[family] = bool(win)
-        timings.setdefault(family, {}).update(
-            xla_us=round(t_xla * 1e6, 1),
-            pallas_us=(None if t_pal == float("inf")
-                       else round(t_pal * 1e6, 1)),
-        )
+        timings[family] = {
+            "xla_us": round(t_xla * 1e6, 1),
+            "pallas_us": round(t_pal * 1e6, 1),
+        }
         if progress:
             progress(
                 f"autotune {family}: xla {t_xla*1e6:.0f}us "
@@ -351,14 +364,17 @@ def ensure_autotuned(
     cached = None if force else _load_cache()
     if cached is not None:
         pops.set_dispatch(cached["decisions"])
-        _state.update(done=True, source="cache")
+        _state.update(
+            done=True, source="cache",
+            timings_us=cached.get("timings_us") or {},
+        )
         if progress:
             progress(f"autotune: cached decisions {cached['decisions']}")
         return pops.get_dispatch()
     decisions, timings = measure(progress)
     pops.set_dispatch(decisions)
     _save_cache(decisions, timings)
-    _state.update(done=True, source="measured")
+    _state.update(done=True, source="measured", timings_us=timings)
     return pops.get_dispatch()
 
 
@@ -370,13 +386,12 @@ def get_decisions_json() -> str:
 def main() -> None:
     """Self-check CLI: run the microbench (ignoring the cache), print the
     per-family table, and verify the chosen dispatch still passes the
-    pallas selfcheck. Skips cleanly off-TPU (CI wires this as a
-    skip-on-no-TPU step)."""
+    pallas selfcheck. Needs a TPU and exits non-zero without one (ci.sh
+    calls it only where one is attached)."""
     import sys
 
     if jax.default_backend() != "tpu":
-        print("autotune self-check skipped: no TPU backend")
-        return
+        raise SystemExit("autotune self-check needs a TPU backend; found none")
     decisions = ensure_autotuned(progress=lambda m: print(m, flush=True),
                                  force=True)
     print(f"dispatch ({dispatch_source()}): {json.dumps(decisions)}")

@@ -26,9 +26,16 @@ Addressing rules (load-bearing, measured):
 - int64 never enters a kernel: i64 arrays are bitcast to (lo, hi) i32
   planes at the boundary (TPU i64 is emulated anyway).
 
+Every kernel holds its WHOLE table in VMEM for the pass, so a family takes
+the pallas form only for tables whose windows fit (``_fits_vmem``, a static
+shape rule applied at every call site); larger tables take the XLA form
+and the refusal is recorded (``size_rulings``). At the served 2^20-row
+capacity that sends every 2D row table to XLA — a ``[2^20, 6]`` i32 table
+pads to 512 MiB of VMEM — while the 1D tables still fit.
+
 Everything falls back to the XLA implementations off-TPU (tests run on
-the CPU mesh; the TPU path is exercised by bench.py and the device parity
-check in benchmarks/).
+the CPU mesh; tests/test_chip_compile.py compiles the kernels for a
+described v5e, chip_smoke.py runs them on one).
 """
 
 from __future__ import annotations
@@ -36,7 +43,8 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-from typing import List, Optional, Sequence, Tuple
+import logging
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -138,7 +146,72 @@ def _chunk(b: int) -> int:
     return max(c, 1)
 
 
-def _pallas_call(kernel, grid, in_specs, out_specs, out_shape, aliases, vmem_mb=110):
+# ---------------------------------------------------------------------------
+# table-size rule: which shapes a pallas pass can hold
+# ---------------------------------------------------------------------------
+#
+# What every kernel asks Mosaic for (``vmem_limit_bytes``). A v5e core has
+# 128 MiB of VMEM; asked through the chip's compiler, it grants a call's
+# scoped allocation up to the limit the call names and refuses the call
+# beyond it (row_update, two [T, 6] windows: T = 112,640 compiles at this
+# limit, 113,664 is refused; at a limit of 128 MiB and above the ceiling is
+# 130,048 rows, the operand blocks taking the rest). The same number
+# bounds the rule below, so what the rule admits the compiler accepts.
+VMEM_LIMIT_BYTES = 110 * 1024 * 1024
+_SUBLANES = 8  # an i32 VMEM window is tiled (8, 128)
+
+_log = logging.getLogger(__name__)
+# (family, table windows) -> {"admitted", "vmem_bytes"}; filled at trace
+# time, only for calls that would otherwise take the pallas form
+_SIZE_RULINGS: Dict[tuple, dict] = {}
+
+
+def _window_bytes(shape: Sequence[int]) -> int:
+    """VMEM bytes of one i32 window: rows pad to 8 sublanes, columns to 128
+    lanes — a [T, 6] table costs 512 B per row, not 24."""
+    rows, cols = (1, shape[0]) if len(shape) == 1 else shape
+    return (
+        -(-rows // _SUBLANES) * _SUBLANES * -(-cols // LANES) * LANES * 4
+    )
+
+
+def _fits_vmem(
+    family: str,
+    tables: Sequence[Sequence[int]],
+    blocks: Sequence[Sequence[int]] = (),
+) -> bool:
+    """The static shape rule: do one call's VMEM windows fit under
+    ``VMEM_LIMIT_BYTES``? ``tables`` are the whole-table windows held for
+    the pass (an aliased output is its own window, so a read-modify-write
+    table counts twice); ``blocks`` are the per-chunk operand blocks, which
+    the grid pipeline double-buffers. SMEM operands are not VMEM. A refusal
+    sends the call to its XLA form and is recorded, never raised."""
+    need = sum(_window_bytes(t) for t in tables) + 2 * sum(
+        _window_bytes(b) for b in blocks
+    )
+    admitted = need <= VMEM_LIMIT_BYTES
+    key = (family, tuple(tuple(t) for t in tables))
+    if key not in _SIZE_RULINGS:
+        _SIZE_RULINGS[key] = {"admitted": admitted, "vmem_bytes": need}
+        if not admitted:
+            _log.info(
+                "pallas %s refused for tables %s: %d B of VMEM windows > "
+                "%d B; XLA form", family, list(key[1]), need,
+                VMEM_LIMIT_BYTES,
+            )
+    return admitted
+
+
+def size_rulings() -> List[dict]:
+    """Every (family, table windows) the size rule has ruled on so far in
+    this process, admitted or refused, with the padded VMEM bytes."""
+    return [
+        {"family": fam, "tables": [list(t) for t in tables], **ruling}
+        for (fam, tables), ruling in _SIZE_RULINGS.items()
+    ]
+
+
+def _pallas_call(kernel, grid, in_specs, out_specs, out_shape, aliases):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -150,7 +223,7 @@ def _pallas_call(kernel, grid, in_specs, out_specs, out_shape, aliases, vmem_mb=
         out_shape=out_shape,
         input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=vmem_mb * 1024 * 1024,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
             dimension_semantics=("arbitrary",),
         ),
     )
@@ -197,9 +270,18 @@ def masked_row_update(
 
     Equivalent to the XLA ``table.at[where(active, slots, T)].set(vals,
     mode="drop")`` chain (last writer in batch order wins)."""
-    if not _use_pallas("row_update"):
+    b = slots.shape[0]
+    t, k = table.shape
+    c = _chunk(b)
+    blind = lane_mask is None
+    if not (
+        _use_pallas("row_update")
+        and _fits_vmem(
+            "row_update", [(t, k)] * 2, [(c, k)] * (1 if blind else 2)
+        )
+    ):
         idx = jnp.where(active, slots, table.shape[0])
-        if lane_mask is None:
+        if blind:
             return table.at[idx].set(vals, mode="drop")
         # element-wise scatter: two active records may target DISJOINT
         # lanes of the same row (parallel-join arrivals) — a row-level
@@ -213,10 +295,6 @@ def masked_row_update(
         )
         return table.at[rows, cols].set(vals, mode="drop")
 
-    b = slots.shape[0]
-    t, k = table.shape
-    c = _chunk(b)
-    blind = lane_mask is None
     if blind:
         lane_mask = jnp.ones((1, 1), jnp.int32)  # placeholder operand
 
@@ -293,13 +371,15 @@ def masked_row_max(
     """Serial ``table[slot[i]] = maximum(old, vals[i])`` for active records
     (the ``.at[slots].max(vals, mode="drop")`` analogue; max commutes, so
     batch order does not matter)."""
-    if not _use_pallas("row_max"):
-        idx = jnp.where(active, slots, table.shape[0])
-        return table.at[idx].max(vals.astype(table.dtype), mode="drop")
-
     b = slots.shape[0]
     t, k = table.shape
     c = _chunk(b)
+    if not (
+        _use_pallas("row_max")
+        and _fits_vmem("row_max", [(t, k)] * 2, [(c, k)])
+    ):
+        idx = jnp.where(active, slots, table.shape[0])
+        return table.at[idx].max(vals.astype(table.dtype), mode="drop")
 
     def kernel(slots_ref, active_ref, vals_ref, tbl_ref, out_ref):
         _init_out(out_ref, tbl_ref)
@@ -344,15 +424,20 @@ def masked_row_add(
     """Serial ``table[slot[i], lane] += vals[i, lane]`` for active records
     and masked lanes (integer addition commutes, so batch order does not
     matter; duplicates accumulate like ``.at[].add(..., mode="drop")``)."""
-    if not _use_pallas("row_add"):
-        idx = jnp.where(active, slots, table.shape[0])
-        add = vals if lane_mask is None else jnp.where(lane_mask, vals, 0)
-        return table.at[idx].add(add.astype(table.dtype), mode="drop")
-
     b = slots.shape[0]
     t, k = table.shape
     c = _chunk(b)
     blind = lane_mask is None
+    if not (
+        _use_pallas("row_add")
+        and _fits_vmem(
+            "row_add", [(t, k)] * 2, [(c, k)] * (1 if blind else 2)
+        )
+    ):
+        idx = jnp.where(active, slots, table.shape[0])
+        add = vals if lane_mask is None else jnp.where(lane_mask, vals, 0)
+        return table.at[idx].add(add.astype(table.dtype), mode="drop")
+
     if blind:
         lane_mask = jnp.ones((1, 1), jnp.int32)  # placeholder operand
 
@@ -431,7 +516,11 @@ def _lane_kernel(accumulate: bool):
 def _lane_op(table1d, slots, active, vals, accumulate):
     t = table1d.shape[0]
     b = slots.shape[0]
-    if not _use_pallas("lane") or t % LANES:
+    if not (
+        t % LANES == 0
+        and _use_pallas("lane")
+        and _fits_vmem("lane", [(t // LANES, LANES)] * 2)
+    ):
         idx = jnp.where(active, slots, t)
         if accumulate:
             return table1d.at[idx].add(vals.astype(table1d.dtype), mode="drop")
@@ -496,8 +585,14 @@ def vec64_to_planes(x: jax.Array) -> jax.Array:
 
 def masked_vec64_update(table1d, slots, active, vals64):
     """1D i64 table scatter: ``table[slot[i]] = vals64[i]`` via planes."""
-    if not _use_pallas("vec64"):
-        idx = jnp.where(active, slots, table1d.shape[0])
+    t = table1d.shape[0]
+    if not (
+        _use_pallas("vec64")
+        and _fits_vmem(
+            "vec64", [(t, 2)] * 2, [(_chunk(slots.shape[0]), 2)]
+        )
+    ):
+        idx = jnp.where(active, slots, t)
         return table1d.at[idx].set(vals64.astype(table1d.dtype), mode="drop")
     planes = i64_to_planes(table1d[:, None])
     # force the inner row update onto the pallas path: this call must be
@@ -565,36 +660,47 @@ def _apply_op_unfused(tbl: jax.Array, op: TableOp) -> jax.Array:
 
 
 def fused_table_commit(
-    tables: Sequence[jax.Array], ops: Sequence[TableOp], vmem_mb: int = 128
+    tables: Sequence[jax.Array], ops: Sequence[TableOp]
 ) -> List[jax.Array]:
     """Apply ``ops`` to ``tables`` (all i32; i64 state enters as planes) as
     ONE pallas serial pass — or, when the fused family lost the autotune
-    A/B (or off-TPU), as the equivalent unfused op chain. Returns the new
-    tables in input order.
+    A/B, the tables together do not fit VMEM, or off-TPU, as the
+    equivalent unfused op chain (each op then decides on its own table).
+    Returns the new tables in input order.
     """
     ops = list(ops)
     if not ops:
         return list(tables)
     b = ops[0].slots.shape[0]
+    c = _chunk(b)
     fusable = (
-        use_pallas("fused")
-        and all(t.ndim == 1 or t.ndim == 2 for t in tables)
+        all(t.ndim == 1 or t.ndim == 2 for t in tables)
         and all(t.shape[0] % LANES == 0 for t in tables if t.ndim == 1)
         and all(op.slots.shape[0] == b for op in ops)
+        and use_pallas("fused")
     )
+    is1d = [t.ndim == 1 for t in tables]
+    if fusable:
+        folded_shapes = [
+            (t.shape[0] // LANES, LANES) if t.ndim == 1 else tuple(t.shape)
+            for t in tables
+        ]
+        blocks = []
+        for op in ops:
+            if not is1d[op.table]:
+                k = tables[op.table].shape[1]
+                blocks += [(c, k)] * (1 if op.mask is None else 2)
+        fusable = _fits_vmem("fused", folded_shapes * 2, blocks)
     if not fusable:
         out = list(tables)
         for op in ops:
             out[op.table] = _apply_op_unfused(out[op.table], op)
         return out
 
-    c = _chunk(b)
     ntab = len(tables)
     folded = [
-        t.reshape(t.shape[0] // LANES, LANES) if t.ndim == 1 else t
-        for t in tables
+        t.reshape(shape) for t, shape in zip(tables, folded_shapes)
     ]
-    is1d = [t.ndim == 1 for t in tables]
 
     # static operand layout: per op (slots, active, vals[, mask]) then the
     # tables; refs arrive in the same flat order, outputs one per table
@@ -685,7 +791,6 @@ def fused_table_commit(
             jax.ShapeDtypeStruct(f.shape, f.dtype) for f in folded
         ),
         aliases={n_operands + j: j for j in range(ntab)},
-        vmem_mb=vmem_mb,
     )(*operands, *folded)
     return [
         o.reshape(tables[j].shape) if is1d[j] else o
@@ -786,11 +891,25 @@ def _gather_unfused(
     return results  # type: ignore[return-value]
 
 
+def _gather_is_lane(t: jax.Array) -> bool:
+    """1D non-i64 tables fold to [T/128, 128] and are read by lane."""
+    return t.ndim == 1 and t.dtype != jnp.int64
+
+
+def _gather_norm_shape(t: jax.Array) -> Tuple[int, int]:
+    """Shape of a table's i32 normal form inside ``fused_gather_rows``."""
+    wide = 2 if t.dtype == jnp.int64 else 1
+    if t.ndim == 2:
+        return (t.shape[0], t.shape[1] * wide)
+    if _gather_is_lane(t):
+        return (t.shape[0] // LANES, LANES)
+    return (t.shape[0], 2)
+
+
 def fused_gather_rows(
     tables: Sequence[jax.Array],
     ops: Sequence[GatherOp],
     family: str = "gather",
-    vmem_mb: int = 110,
 ) -> List[jax.Array]:
     """``[tables[op.table][op.slots] for op in ops]`` as ONE pallas serial
     pass — or, off the pallas path, as one concatenated XLA gather per
@@ -807,18 +926,26 @@ def fused_gather_rows(
     if not ops:
         return []
     b = ops[0].slots.shape[0]
+    c = _chunk(b)
     fusable = (
-        use_pallas(family)
-        and all(op.slots.shape[0] == b for op in ops)
+        all(op.slots.shape[0] == b for op in ops)
         and all(t.ndim in (1, 2) for t in tables)
         and all(t.shape[0] % LANES == 0 for t in tables if t.ndim == 1)
-        # every table must be VMEM-resident for the whole pass
-        and sum(t.size * 4 for t in tables) <= vmem_mb * 1024 * 1024 * 3 // 4
+        and use_pallas(family)
     )
+    if fusable:
+        # every table is VMEM-resident for the whole pass, in the i32
+        # normal form built below
+        norm_shapes = [_gather_norm_shape(t) for t in tables]
+        blocks = [
+            (c, norm_shapes[op.table][1])
+            for op in ops
+            if not _gather_is_lane(tables[op.table])
+        ]
+        fusable = _fits_vmem(family, norm_shapes, blocks)
     if not fusable:
         return _gather_unfused(tables, ops)
 
-    c = _chunk(b)
     ntab = len(tables)
     n_ops = len(ops)
 
@@ -858,6 +985,8 @@ def fused_gather_rows(
                 )
                 decode.append(("lane", t.dtype))
 
+    # the size rule ruled on these shapes before anything was built
+    assert [tuple(nt.shape) for nt in norm] == norm_shapes
     lane_modes = ("lane", "lane_bitcast")
     in_specs = [_smem_spec(c) for _ in ops]
     in_specs += [_vmem_full_spec(nt.shape) for nt in norm]
@@ -906,7 +1035,6 @@ def fused_gather_rows(
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         aliases={},
-        vmem_mb=vmem_mb,
     )(*[op.slots.astype(jnp.int32) for op in ops], *norm)
 
     results: List[jax.Array] = []
@@ -973,7 +1101,11 @@ def lookup(table: HashTable, keys: jax.Array, valid: jax.Array):
     """Batched probe; identical results to hashmap.lookup."""
     t = table.keys.shape[0]
     b = keys.shape[0]
-    if not _use_pallas("lookup") or t % LANES:
+    if not (
+        t % LANES == 0
+        and _use_pallas("lookup")
+        and _fits_vmem("lookup", [(t // LANES, LANES)] * 3)
+    ):
         return hashmap.lookup(table, keys, valid)
     c = _chunk(b)
     lo, hi = _split_keys(keys)
@@ -1045,7 +1177,11 @@ def insert(table: HashTable, keys: jax.Array, vals: jax.Array, valid: jax.Array)
     layout may differ on collisions — see module docstring)."""
     t = table.keys.shape[0]
     b = keys.shape[0]
-    if not _use_pallas("insert") or t % LANES:
+    if not (
+        t % LANES == 0
+        and _use_pallas("insert")
+        and _fits_vmem("insert", [(t // LANES, LANES)] * 6)
+    ):
         return hashmap.insert(table, keys, vals, valid)
     c = _chunk(b)
     lo, hi = _split_keys(keys)
@@ -1131,7 +1267,11 @@ def delete(table: HashTable, keys: jax.Array, valid: jax.Array) -> HashTable:
     """Batched delete (tombstones); identical to hashmap.delete."""
     t = table.keys.shape[0]
     b = keys.shape[0]
-    if not _use_pallas("delete") or t % LANES:
+    if not (
+        t % LANES == 0
+        and _use_pallas("delete")
+        and _fits_vmem("delete", [(t // LANES, LANES)] * 4)
+    ):
         return hashmap.delete(table, keys, valid)
     c = _chunk(b)
     lo, hi = _split_keys(keys)

@@ -28,19 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax promoted shard_map out of experimental at different versions; this
-# build only ships the experimental name (and spells the replication-check
-# kwarg ``check_rep`` instead of ``check_vma``). Resolve once here so the
-# two shard_map call sites below work on either build.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _exp_shard_map(f, **kw)
-
 from zeebe_tpu.engine import keyspace
 from zeebe_tpu.protocol.enums import RecordType, ValueType
 from zeebe_tpu.tpu import batch as rb
@@ -178,7 +165,7 @@ def build_sharded_step(mesh: Mesh, exchange_slots: int = 128):
         return jax.tree.map(lambda _: spec, tree)
 
     def sharded_step(graph, state, batch, sends, now):
-        fn = _shard_map(
+        fn = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(
@@ -241,7 +228,7 @@ def build_frame_exchange(mesh: Mesh, slots: int, frame_bytes: int):
     spec = P(axis)
     fn = jit_registry.register_jit(
         "shard.frame_exchange",
-        _shard_map(
+        jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(spec, spec, spec),
@@ -430,7 +417,7 @@ def build_sharded_drive(
         return jax.tree.map(lambda _: spec, tree)
 
     def drive(graph, state, queue, now):
-        fn = _shard_map(
+        fn = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(
@@ -703,7 +690,7 @@ def build_state_step(mesh: Mesh, state_template: EngineState):
         )
         return _zip_specs(keep, new_state, specs_tree), out, stats
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), specs_tree, P(), P(), P()),
@@ -912,7 +899,7 @@ def build_state_step_routed(mesh: Mesh, state_template: EngineState):
         }
         return new_state, out, stats
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), specs_tree, P(axis), P(), P()),
@@ -993,7 +980,7 @@ def build_state_step_fallback(mesh: Mesh, state_template: EngineState):
 
         return _zip_specs(keep, new_state, specs_tree), out, stats
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), specs_tree, P(), P(), P()),
