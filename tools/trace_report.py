@@ -3,7 +3,8 @@
 
 Input: the JSON document ``RecordTracer.dump`` writes (format
 ``zeebe-tpu-trace-v1``: record-lifecycle spans, per-wave device
-timelines, and the flight-recorder event ring).
+timelines with their host phases, the drains', ticks' and raft group
+commits' phases, and the flight-recorder event ring).
 
 Output: Chrome trace-event JSON (load in ``chrome://tracing`` or
 https://ui.perfetto.dev):
@@ -11,6 +12,10 @@ https://ui.perfetto.dev):
 - one track per traced record (``pid="records"``, ``tid=trace-<id>``)
   with an ``X`` slice per stage interval plus instant events at each
   stamp — the per-stage attribution view;
+- one row per host track (``pid="host"``: ``wave``, ``drain``, ``tick``
+  on the broker actor, ``raft`` on the raft actor), sorted above the
+  devices, with an ``X`` slice per phase (docs/operations/tracing.md,
+  "Wave phases");
 - one track per mesh device (``pid="devices"``) with an ``X`` slice per
   wave segment (dispatch → collect), labeled with fill and the
   host/device time split;
@@ -83,6 +88,24 @@ def wave_events(wave: dict) -> list:
     return out
 
 
+def phase_events(cycle: dict) -> list:
+    """One slice per host phase of a wave (``wave_id``) or of a drain,
+    tick or raft group commit (``track``, ``cycle_id``)."""
+    track = cycle.get("track", "wave")
+    ident = {
+        k: cycle[k] for k in ("wave_id", "cycle_id", "partition")
+        if k in cycle
+    }
+    return [
+        {
+            "name": name, "cat": "phase", "ph": "X", "ts": int(t0),
+            "dur": max(0, int(t1) - int(t0)), "pid": "host", "tid": track,
+            "args": ident,
+        }
+        for name, t0, t1 in cycle.get("phases", [])
+    ]
+
+
 def flight_events(events: list, span_t0_wall=None) -> list:
     if not events:
         return []
@@ -118,6 +141,15 @@ def convert(doc: dict) -> dict:
         events.extend(span_events(span))
     for wave in doc.get("waves", []):
         events.extend(wave_events(wave))
+        events.extend(phase_events(wave))
+    for cycle in doc.get("cycles", []):
+        events.extend(phase_events(cycle))
+    # the host rows sit above the devices they keep waiting
+    events.extend(
+        {"name": "process_sort_index", "ph": "M", "pid": pid,
+         "args": {"sort_index": i}}
+        for i, pid in enumerate(("host", "devices"))
+    )
     events.extend(
         flight_events(doc.get("events", []), doc.get("span_t0_wall"))
     )
@@ -151,6 +183,11 @@ def selftest() -> int:
                 "t_dispatch_us": 20, "t_collect_us": 44,
                 "host_s": 0.001, "device_s": 0.002,
             }],
+            "phases": [["pack", 18, 20], ["route", 20, 22], ["stage", 22, 30]],
+        }],
+        "cycles": [{
+            "track": "raft", "cycle_id": 0, "partition": 0,
+            "phases": [["log_append", 24, 27], ["fsync", 27, 29]],
         }],
         "events": [
             {"seq": 0, "t": 100.0, "cat": "raft", "msg": "state -> leader"},
@@ -160,6 +197,11 @@ def selftest() -> int:
     events = out["traceEvents"]
     assert any(e["ph"] == "X" and e["pid"] == "records" for e in events)
     assert any(e["ph"] == "X" and e["pid"] == "devices" for e in events)
+    host = [e for e in events if e["pid"] == "host" and e["ph"] == "X"]
+    assert [(e["tid"], e["name"]) for e in host] == [
+        ("wave", "pack"), ("wave", "route"), ("wave", "stage"),
+        ("raft", "log_append"), ("raft", "fsync"),
+    ]
     flight = [e for e in events if e["pid"] == "flight"]
     assert flight
     # flight events align onto the span timebase via span_t0_wall

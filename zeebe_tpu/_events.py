@@ -26,3 +26,11 @@ def set_gauge(name: str, value: float, help_text: str = "", **labels: str) -> No
     from zeebe_tpu.runtime.metrics import global_gauge
 
     global_gauge(name, help_text, **labels).set(value)
+
+
+def observe_phases(clock, cycle=None) -> None:
+    """Flush a cycle's PhaseClock into the phase counters; same shim rules
+    as :func:`count_event`."""
+    from zeebe_tpu.runtime.metrics import observe_phases as _impl
+
+    _impl(clock, cycle)

@@ -35,7 +35,9 @@ import random
 import threading
 from typing import Callable, Dict, List, Optional
 
+from zeebe_tpu import tracing
 from zeebe_tpu._events import count_event as _count_event
+from zeebe_tpu._events import observe_phases as _observe_phases
 from zeebe_tpu.tracing.recorder import record_event as _flight
 from zeebe_tpu.log.logstream import LogStream
 from zeebe_tpu.protocol import codec, msgpack
@@ -251,6 +253,29 @@ class Raft(Actor):
             for _records, future in batch:
                 future.complete_exceptionally(RuntimeError("not leader"))
             return
+        # the group commit's phases (tracing/phases.py): log_append, fsync,
+        # commit, back to back on the raft actor
+        clock = tracing.cycle_clock(
+            "raft", partition=getattr(self.log, "partition_id", 0)
+        )
+        try:
+            with clock.phase("log_append"):
+                merged, last = self._append_group(batch)
+            with clock.phase("fsync"):
+                self.log.flush()  # ONE durable fsync for the whole group
+        except Exception as e:
+            # storage failure (e.g. closed mid-shutdown): fail every
+            # queued caller instead of leaving futures to hang
+            for _records, future in batch:
+                future.complete_exceptionally(e)
+            raise
+        with clock.phase("commit"):
+            self._register_group(batch, merged, last)
+        _observe_phases(clock, "groups")
+
+    def _append_group(self, batch) -> tuple:
+        """Stamp the term, merge the queued appends in call order and write
+        them as ONE log append; returns ``(merged, last position)``."""
         from zeebe_tpu.protocol.columnar import ColumnarBatch, MixedBatch
 
         term = self.persistent.term
@@ -282,16 +307,12 @@ class Raft(Actor):
                 else:
                     entries.extend(records)
             merged = MixedBatch(entries)
+        return merged, self.log.append(merged, commit=False)
+
+    def _register_group(self, batch, merged, last: int) -> None:
+        """After the fsync: register the callers' commit waits, bind traced
+        commands, advance the commit and fan out replication."""
         group_sizes = [len(records) for records, _future in batch]
-        try:
-            last = self.log.append(merged, commit=False)
-            self.log.flush()  # ONE durable fsync for the whole group
-        except Exception as e:
-            # storage failure (e.g. closed mid-shutdown): fail every
-            # queued caller instead of leaving futures to hang
-            for _records, future in batch:
-                future.complete_exceptionally(e)
-            raise
         if len(batch) > 1:
             _count_event(
                 "log_group_commit_coalesced",

@@ -145,6 +145,7 @@ class _BrokerFeed:
         self.broker = broker
         self.partition = partition
         self.partition_id = partition.partition_id
+        self.wave_phases = None
 
     @property
     def device_index(self) -> int:
@@ -200,6 +201,9 @@ class _BrokerFeed:
         host_s, device_s = getattr(p.engine, "last_wave_seconds", (None, 0.0))
         if host_s is None:
             host_s, device_s = _time.perf_counter() - t0, 0.0
+        # the device engine's phases of this wave; the scheduler reads them
+        # right after this call, as it does shard_fill
+        self.wave_phases = getattr(p.engine, "last_wave_phases", None)
         return None, host_s, device_s
 
     def collect(self, pending):  # synchronous dispatch: nothing pending
@@ -679,7 +683,12 @@ class Broker:
                         host_s, device_s = getattr(
                             partition.engine, "last_wave_seconds", (0.0, 0.0)
                         )
-                        observe_wave(len(wave), wave_cap, host_s, device_s)
+                        observe_wave(
+                            len(wave), wave_cap, host_s, device_s,
+                            getattr(
+                                partition.engine, "last_wave_phases", None
+                            ),
+                        )
                         if processed > max_iterations:
                             raise RuntimeError("broker did not reach quiescence")
                     progress = True

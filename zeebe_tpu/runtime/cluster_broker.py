@@ -51,6 +51,7 @@ from zeebe_tpu.runtime.metrics import (
     MetricsFileWriter,
     MetricsRegistry,
     count_event,
+    observe_phases,
 )
 from zeebe_tpu.transport import ClientTransport, RemoteAddress, ServerTransport
 from zeebe_tpu import tracing
@@ -574,16 +575,22 @@ class PartitionServer:
         return dispatch(records), 0.0, 0.0
 
     def collect(self, pending):
+        """Materialize a dispatched wave's outputs and apply them (appends,
+        responses, sends, pushes) in log order. What follows the engine's
+        collect is the wave's phase ``apply``."""
         from zeebe_tpu.engine.interpreter import ProcessingResult
 
-        merged = ProcessingResult.merged(self.engine.collect_wave(pending))
-        tracer = tracing.TRACER
-        if tracer is not None and tracer.by_position:
-            tracer.stamp_positions(
-                self.partition_id, tracing.positions_of(pending.records),
-                tracing.DEVICE_COLLECT, device=self.device_index,
-            )
-        self._apply_chunk(pending.records, merged)
+        results = self.engine.collect_wave(pending)
+        clock = getattr(pending, "phases", None) or tracing.PhaseClock()
+        with clock.phase("apply"):
+            merged = ProcessingResult.merged(results)
+            tracer = tracing.TRACER
+            if tracer is not None and tracer.by_position:
+                tracer.stamp_positions(
+                    self.partition_id, tracing.positions_of(pending.records),
+                    tracing.DEVICE_COLLECT, device=self.device_index,
+                )
+            self._apply_chunk(pending.records, merged)
         return pending.host_seconds, pending.device_seconds
 
     def rewind(self, position: int) -> None:
@@ -628,6 +635,12 @@ class PartitionServer:
         and re-enter the shared waves as committed records."""
         if not self.is_leader or self.engine is None:
             return
+        clock = tracing.cycle_clock("tick", partition=self.partition_id)
+        with clock.phase("tick"):
+            self._tick_sweeps()
+        observe_phases(clock, "ticks")
+
+    def _tick_sweeps(self) -> None:
         from zeebe_tpu.tpu.engine import PROBE_DEADLINES, PROBE_JOB_BACKLOG
 
         engine = self.engine
@@ -770,22 +783,12 @@ class PartitionServer:
         return wave
 
     def _collect_chunk(self, wave) -> None:
-        """Materialize a dispatched wave's outputs and apply them (appends,
-        responses, sends, pushes) in log order."""
-        from zeebe_tpu.engine.interpreter import ProcessingResult
         from zeebe_tpu.runtime.metrics import observe_wave
 
-        merged = ProcessingResult.merged(self.engine.collect_wave(wave))
-        tracer = tracing.TRACER
-        if tracer is not None and tracer.by_position:
-            tracer.stamp_positions(
-                self.partition_id, tracing.positions_of(wave.records),
-                tracing.DEVICE_COLLECT, device=self.device_index,
-            )
-        self._apply_chunk(wave.records, merged)
+        host_s, device_s = self.collect(wave)
         observe_wave(
-            len(wave.records), self._DRAIN_BATCH,
-            wave.host_seconds, wave.device_seconds,
+            len(wave.records), self._DRAIN_BATCH, host_s, device_s,
+            getattr(wave, "phases", None),
         )
 
     def _apply_chunk(self, records: list, result) -> None:
@@ -1151,6 +1154,7 @@ class ClusterBroker(Actor):
             else None
         )
         self._drain_scheduled = False
+        self._drain_scheduled_us = 0  # span-clock stamp of that scheduling
         # mesh-sharded serving plane: leader partitions place across the
         # visible devices (scheduler/placement.DevicePlan) so different
         # partitions' wave segments compute on DIFFERENT devices within
@@ -1646,25 +1650,34 @@ class ClusterBroker(Actor):
         if self.wave_scheduler is None or self._drain_scheduled:
             return
         self._drain_scheduled = True
+        # a drain's first phase, ``drain_wait``, starts here, on whichever
+        # thread saw the commit, and ends where the broker actor runs it
+        self._drain_scheduled_us = tracing.now_us()
         self.actor_control.run(self._drain_committed)
 
     def _drain_committed(self) -> None:
         self._drain_scheduled = False
         if self.wave_scheduler is None:
             return
+        clock = tracing.cycle_clock("drain")
+        clock.waited("drain_wait", self._drain_scheduled_us)
         try:
             self.wave_scheduler.drain()
         finally:
-            # the round's cross-partition frames ride ONE collective over
-            # the mesh (route_send queued them during the waves' applies)
-            self._flush_mesh_exchange()
-        for server in list(self.partitions.values()):
-            if server.is_leader:
-                # parked-record fetches start only once every in-flight
-                # wave collected (a DEPLOYMENT inside the drain may have
-                # provided the workflow)
-                server.maybe_start_fetch()
-                server.pump_topic_subscriptions()
+            with clock.phase("pump"):
+                # the round's cross-partition frames ride ONE collective
+                # over the mesh (route_send queued them during the waves'
+                # applies)
+                self._flush_mesh_exchange()
+        with clock.phase("pump"):
+            for server in list(self.partitions.values()):
+                if server.is_leader:
+                    # parked-record fetches start only once every in-flight
+                    # wave collected (a DEPLOYMENT inside the drain may
+                    # have provided the workflow)
+                    server.maybe_start_fetch()
+                    server.pump_topic_subscriptions()
+        observe_phases(clock, "drains")
 
     def _queue_depth(self) -> int:
         """Admission probe: committed records awaiting the drain (plus

@@ -269,10 +269,6 @@ def _wave_handles() -> dict:
             fill=g.gauge(
                 "serving_wave_fill", "Records in the most recent drain wave"
             ),
-            fill_mean=g.gauge(
-                "serving_wave_fill_mean",
-                "Mean records per drain wave since process start",
-            ),
             occupancy=g.gauge(
                 "serving_wave_occupancy",
                 "Most recent wave's fill fraction of the drain-chunk capacity",
@@ -290,19 +286,147 @@ def _wave_handles() -> dict:
     return _WAVE_HANDLES
 
 
+# -- wave-cycle phases ---------------------------------------------------------
+# The serving cycle phase by phase (tracing/phases.py; the operator's table
+# is docs/operations/tracing.md "Wave phases"): one flat counter per phase
+# and per byte count, always on, flushed once per wave / drain / tick / raft
+# group commit from that cycle's PhaseClock. Flat names, no labels: readers
+# take a counter by event_count(name).
+_PHASE_HANDLES: dict = {}
+
+
+def _phase_handles() -> dict:
+    if not _PHASE_HANDLES:
+        g = GLOBAL_REGISTRY
+        # keyed by phase / count name; built whole, then ONE update: the
+        # broker actor and the raft actor both flush here, and neither may
+        # see the table half filled
+        _PHASE_HANDLES.update(
+            pack=g.counter(
+                "serving_pack_seconds_total",
+                "Scheduler seconds packing shared waves (feed.take, DRR)",
+            ),
+            route=g.counter(
+                "serving_route_seconds_total",
+                "Engine dispatch seconds outside device segments: "
+                "recompile check, per-record routing, host-routed "
+                "records, key sync",
+            ),
+            stage=g.counter(
+                "serving_stage_seconds_total",
+                "Seconds staging device segments: pre-work scans, column "
+                "fill, family matrices",
+            ),
+            h2d=g.counter(
+                "serving_h2d_seconds_total",
+                "Seconds in the device_put calls of the staged batch",
+            ),
+            launch=g.counter(
+                "serving_launch_seconds_total",
+                "Seconds in the call into the step program until it "
+                "returns",
+            ),
+            blocked=g.counter(
+                "serving_blocked_seconds_total",
+                "HOST seconds waiting for the device at a wave's first "
+                "sync",
+            ),
+            readback=g.counter(
+                "serving_readback_seconds_total",
+                "Seconds in device_get of a wave's emission batch",
+            ),
+            decode=g.counter(
+                "serving_decode_seconds_total",
+                "Seconds decoding emissions into records (residency "
+                "notes, columnar decode, source stamping)",
+            ),
+            apply=g.counter(
+                "serving_apply_seconds_total",
+                "Broker seconds applying a collected wave: follow-ups to "
+                "raft.append, responses, sends, pushes",
+            ),
+            drain_wait=g.counter(
+                "serving_drain_wait_seconds_total",
+                "Seconds from a drain being scheduled to its start on the "
+                "broker actor",
+            ),
+            pump=g.counter(
+                "serving_pump_seconds_total",
+                "Seconds after a drain's waves: mesh exchange flush, "
+                "parked fetches, topic-subscription pushes",
+            ),
+            tick=g.counter(
+                "serving_tick_seconds_total",
+                "Seconds in the partitions' deadline ticks (host sweeps "
+                "and the due probe's launch)",
+            ),
+            log_append=g.counter(
+                "raft_log_append_seconds_total",
+                "Raft group-commit seconds up to the end of log.append: "
+                "term stamping, merge, codec encode, write",
+            ),
+            fsync=g.counter(
+                "raft_fsync_seconds_total",
+                "Raft group-commit seconds in log.flush",
+            ),
+            commit=g.counter(
+                "raft_commit_seconds_total",
+                "Raft group-commit seconds after the fsync: traced binds, "
+                "commit advance, replication fan-out",
+            ),
+            h2d_bytes=g.counter(
+                "serving_h2d_bytes_total",
+                "Bytes handed to device_put by wave staging",
+            ),
+            d2h_bytes=g.counter(
+                "serving_d2h_bytes_total",
+                "Bytes fetched by device_get at wave collect",
+            ),
+            drains=g.counter(
+                "serving_drains_total",
+                "Shared-wave drains run",
+            ),
+            ticks=g.counter(
+                "serving_ticks_total",
+                "Partition deadline ticks run",
+            ),
+            groups=g.counter(
+                "raft_group_commits_total",
+                "Raft group commits (one log.flush each)",
+            ),
+        )
+    return _PHASE_HANDLES
+
+
+def observe_phases(clock, cycle: Optional[str] = None) -> None:
+    """Flush one cycle's PhaseClock into the phase counters. ``cycle``
+    names the count to bump for a cycle that is no wave (``drains``,
+    ``ticks``, ``groups``); waves count in ``observe_wave``."""
+    h = _phase_handles()
+    for name, us in clock.us.items():
+        h[name].inc(us / 1e6)
+    for name, n in clock.counts.items():
+        h[name].inc(n)
+    if cycle is not None:
+        h[cycle].inc()
+
+
 def observe_wave(
     records: int,
     capacity: int,
     host_seconds: float = 0.0,
     device_seconds: float = 0.0,
+    phases=None,
 ) -> None:
     """Record one committed-record drain wave (process-global; shows up on
-    every /metrics dump and metrics file via ``render_with_global``)."""
+    every /metrics dump and metrics file via ``render_with_global``).
+    ``phases`` is the wave's PhaseClock where the engine keeps one."""
+    if phases is not None:
+        observe_phases(phases)
     h = _wave_handles()
     h["waves"].inc()
     h["records"].inc(records)
     h["fill"].set(records)
-    h["fill_mean"].set(h["records"].value / max(h["waves"].value, 1.0))
     if capacity > 0:
         h["occupancy"].set(records / capacity)
     if host_seconds > 0:
@@ -339,10 +463,6 @@ def _sched_handles() -> dict:
                 "Sum of contributing partitions over all shared waves "
                 "(mean = this / scheduler_shared_waves_total)",
             ),
-            sources_mean=g.gauge(
-                "serving_wave_sources_mean",
-                "Mean partitions per shared wave since process start",
-            ),
         )
     return _SCHED_HANDLES
 
@@ -353,17 +473,15 @@ def observe_shared_wave(
     sources: int,
     host_seconds: float = 0.0,
     device_seconds: float = 0.0,
+    phases=None,
 ) -> None:
     """Record one SHARED drain wave (scheduler path): the plain wave
     series (fill/occupancy/time split) plus the traffic-mix gauges."""
-    observe_wave(records, capacity, host_seconds, device_seconds)
+    observe_wave(records, capacity, host_seconds, device_seconds, phases)
     h = _sched_handles()
     h["shared_waves"].inc()
     h["sources"].set(sources)
     h["sources_total"].inc(sources)
-    h["sources_mean"].set(
-        h["sources_total"].value / max(h["shared_waves"].value, 1.0)
-    )
 
 
 # -- mesh serving instrumentation --------------------------------------------
@@ -371,7 +489,8 @@ def observe_shared_wave(
 # leader partitions across devices; these series prove the spread is real:
 # per-device wave/record/occupancy/time-split (labeled by plan device
 # index) and the per-shared-wave distinct-device count — ">1 device active
-# per scheduling round" is serving_wave_devices_mean > 1.
+# per scheduling round" is scheduler_wave_devices_total /
+# scheduler_shared_waves_total > 1.
 _DEVICE_WAVE_HANDLES: dict = {}
 _MESH_WAVE_HANDLES: dict = {}
 
@@ -453,18 +572,9 @@ def observe_mesh_wave(devices_active: int) -> None:
                 "Sum of active mesh devices over all shared waves "
                 "(mean = this / scheduler_shared_waves_total)",
             ),
-            waves=g.counter("scheduler_shared_waves_total"),
-            devices_mean=g.gauge(
-                "serving_wave_devices_mean",
-                "Mean mesh devices active per shared wave since process "
-                "start (>1 = device compute overlaps across the mesh)",
-            ),
         )
     h["devices"].set(devices_active)
     h["devices_total"].inc(devices_active)
-    h["devices_mean"].set(
-        h["devices_total"].value / max(h["waves"].value, 1.0)
-    )
 
 
 _SHARDED_WAVE_HANDLES: Dict[str, Metric] = {}

@@ -141,15 +141,19 @@ class SharedWave:
     """A wave packed from several partitions' committed tails."""
 
     __slots__ = ("segments", "total", "host_seconds", "device_seconds",
-                 "dispatched", "trace")
+                 "dispatched", "trace", "wave_id", "phases")
 
-    def __init__(self):
+    def __init__(self, phases: tracing.PhaseClock):
         self.segments: List[WaveSegment] = []
         self.total = 0
         self.host_seconds = 0.0
         self.device_seconds = 0.0
         self.dispatched = False
         self.trace = None  # wave-timeline event (tracing on)
+        self.wave_id = -1  # global wave sequence number (tracing on)
+        # the wave's phases (tracing/phases.py): ``pack`` is stamped here,
+        # the segments' engine clocks add their totals at collect
+        self.phases = phases
 
 
 class _FeedState:
@@ -191,6 +195,9 @@ class WaveScheduler:
         self._feeds: Dict[int, _FeedState] = {}
         self._order: List[int] = []  # sorted pids (deterministic packing)
         self._rr = 0  # rotating start index into _order
+        # the clock of packs that found nothing (every drain ends on one):
+        # the next wave carries their time as part of its ``pack``
+        self._pack_clock: Optional[tracing.PhaseClock] = None
         # slow-wave watchdog: warn once per stall episode (every slow
         # wave still counts + flight-records; a fast wave re-arms)
         self._slow_wave_warned = False
@@ -226,10 +233,23 @@ class WaveScheduler:
 
     # -- packing (deficit round-robin) -------------------------------------
     def _pack(self) -> Optional[SharedWave]:
+        clock = self._pack_clock
+        if clock is None:
+            # a slice list while a tracer is installed: whether the stride
+            # selects this wave is known only once it exists (_dispatch)
+            clock = tracing.phase_clock(
+                [] if tracing.TRACER is not None else None
+            )
+        with clock.phase("pack"):
+            wave = self._pack_wave(clock)
+        self._pack_clock = clock if wave is None else None
+        return wave
+
+    def _pack_wave(self, clock: tracing.PhaseClock) -> Optional[SharedWave]:
         order = self._order
         if not order:
             return None
-        wave = SharedWave()
+        wave = SharedWave(clock)
         room = self.wave_size
         start = self._rr
         rotated = order[start:] + order[:start]
@@ -304,9 +324,21 @@ class WaveScheduler:
         tracer = tracing.TRACER
         if tracer is not None:
             waves = tracer.waves
-            wave_id = next(waves.seq)
-            if wave_id % waves.stride == 0:
-                wave.trace = waves.begin(wave_id, self.wave_size)
+            wave.wave_id = next(waves.seq)
+            if wave.wave_id % waves.stride == 0:
+                wave.trace = waves.begin(
+                    wave.wave_id, self.wave_size, wave.phases.slices
+                )
+        if wave.trace is None:
+            wave.phases.slices = None  # not selected: totals only
+        # the engines' clocks of a selected wave write into its event's list
+        tracing.select_slices(wave.phases.slices)
+        try:
+            self._dispatch_segments(wave, tracer)
+        finally:
+            tracing.select_slices(None)
+
+    def _dispatch_segments(self, wave: SharedWave, tracer) -> None:
         for i, seg in enumerate(wave.segments):
             state = self._feeds.get(seg.feed.partition_id)
             pid = seg.feed.partition_id
@@ -320,6 +352,7 @@ class WaveScheduler:
                     tracer.stamp_positions(
                         pid, tracing.positions_of(seg.records),
                         tracing.WAVE_DISPATCH, device=device,
+                        wave_id=wave.wave_id,
                     )
             try:
                 pending, host_s, device_s = seg.feed.dispatch(seg.records)
@@ -359,6 +392,9 @@ class WaveScheduler:
                 # synchronous engine: the segment processed+applied inline,
                 # so its per-device accounting lands here (pipelined
                 # segments report at collect, when their times are known)
+                clock = getattr(seg.feed, "wave_phases", None)
+                if clock is not None:
+                    wave.phases.add(clock)
                 observe_device_wave(
                     getattr(seg.feed, "device_index", -1), seg.count,
                     wave.total, host_s, device_s,
@@ -384,6 +420,9 @@ class WaveScheduler:
                 host_s, device_s = seg.feed.collect(pending)
                 wave.host_seconds += host_s
                 wave.device_seconds += device_s
+                clock = getattr(pending, "phases", None)
+                if clock is not None:
+                    wave.phases.add(clock)
                 observe_device_wave(
                     getattr(seg.feed, "device_index", -1), seg.count,
                     wave.total, host_s, device_s,
@@ -407,7 +446,7 @@ class WaveScheduler:
         self._check_slow_wave(wave)
         observe_shared_wave(
             wave.total, self.wave_size, len(wave.segments),
-            wave.host_seconds, wave.device_seconds,
+            wave.host_seconds, wave.device_seconds, wave.phases,
         )
         devices = set()
         for seg in wave.segments:

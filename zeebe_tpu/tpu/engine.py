@@ -37,6 +37,7 @@ from zeebe_tpu.engine.interpreter import (
     ProcessingResult,
     WorkflowRepository,
 )
+from zeebe_tpu import tracing
 from zeebe_tpu.engine.mappings import MappingError, extract, merge
 from zeebe_tpu.models.el.interpreter import ConditionEvalError, evaluate_condition
 from zeebe_tpu.protocol.enums import ErrorType, RecordType, RejectionType, ValueType
@@ -246,9 +247,25 @@ class PendingWave:
     segments: List[_PendingSegment] = dataclasses.field(default_factory=list)
     positions: List[int] = dataclasses.field(default_factory=list)
     partition_id: int = -1
-    host_seconds: float = 0.0    # staging + host-routed records + readback
-    device_seconds: float = 0.0  # blocked on device outputs at collect
+    # the wave's phases (tracing/phases.py), stamped by dispatch_wave,
+    # collect_wave and the broker's apply; its totals are what the wave
+    # reports, so nothing is timed twice
+    phases: tracing.PhaseClock = dataclasses.field(
+        default_factory=tracing.PhaseClock
+    )
     collected: Optional[List[ProcessingResult]] = None  # one-shot cache
+
+    @property
+    def host_seconds(self) -> float:
+        """Host work of the wave path: routing, staging, transfer, launch
+        and decode."""
+        return self.phases.seconds(*tracing.phases.WAVE_HOST_PHASES)
+
+    @property
+    def device_seconds(self) -> float:
+        """HOST seconds blocked on device outputs at collect (first sync
+        and readback) — not the device's busy time."""
+        return self.phases.seconds(*tracing.phases.WAVE_BLOCKED_PHASES)
 
 
 class TpuPartitionEngine:
@@ -272,6 +289,10 @@ class TpuPartitionEngine:
         routed_lane_slots: int = 512,
     ):
         self.partition_id = partition_id
+        # the PhaseClock of the wave being dispatched; between waves (warm,
+        # selfcheck) staging stamps a clock that nothing reads
+        self._idle_clock = tracing.PhaseClock()
+        self._clock = self._idle_clock
         self.num_partitions = num_partitions
         # mesh placement (scheduler/placement.DevicePlan): this engine's
         # state lives COMMITTED on `device`, batches stage onto it, and the
@@ -1698,9 +1719,15 @@ class TpuPartitionEngine:
         and later stages straight from the emission batch's columns — no
         ``Record`` ever materializes for it (the columnar plane's
         device-path slice)."""
-        import time as _time
+        clock = tracing.phase_clock(tracing.selected_slices())
+        self._clock = clock
+        try:
+            with clock.phase("route"):
+                return self._route_wave(records, clock)
+        finally:
+            self._clock = self._idle_clock
 
-        t0 = _time.perf_counter()
+    def _route_wave(self, records, clock) -> PendingWave:
         # The repository is shared by a broker's partitions, and only the
         # partition that processes a DEPLOYMENT record recompiles on it: a
         # workflow deployed through (or fetched from) another partition
@@ -1725,7 +1752,7 @@ class TpuPartitionEngine:
         per_record: List[Optional[ProcessingResult]] = [None] * n
         wave = PendingWave(
             records=records, per_record=per_record,
-            partition_id=self.partition_id,
+            partition_id=self.partition_id, phases=clock,
         )
         positions = wave.positions
         # segment processing: device rows batch up, but whenever a
@@ -1922,7 +1949,6 @@ class TpuPartitionEngine:
         push_host_keys()
         if positions:
             self.last_processed_position = positions[-1]
-        wave.host_seconds += _time.perf_counter() - t0
         return wave
 
     def _lazy_device_row(self, entry, vt, rt, intent, key) -> bool:
@@ -2102,29 +2128,27 @@ class TpuPartitionEngine:
         segment, columnar emission decode, per-record source stamping.
         Returns per-record results in log order (a record with no output
         yields an empty result)."""
-        import time as _time
-
         from zeebe_tpu.protocol.records import stamp_source_positions
 
         if wave.collected is not None:  # collection is one-shot
             return wave.collected
-        t0 = _time.perf_counter()
-        device_s = 0.0
-        for seg in wave.segments:
-            device_s += self._collect_device(seg)
-            for i, res in zip(seg.rows, seg.results):
-                wave.per_record[i] = res
-        results: List[ProcessingResult] = []
-        for pos, res in zip(wave.positions, wave.per_record):
-            if res is None:  # poisoned host record: contained, no output
-                res = ProcessingResult()
-            stamp_source_positions(res.written, pos)
-            results.append(res)
-        wave.device_seconds += device_s
-        wave.host_seconds += (_time.perf_counter() - t0) - device_s
-        # (host, device) seconds of the last collected wave — read by the
-        # brokers' wave metrics (same attribute as the host oracle's)
+        clock = wave.phases
+        with clock.phase("decode"):
+            for seg in wave.segments:
+                self._collect_device(seg, clock)
+                for i, res in zip(seg.rows, seg.results):
+                    wave.per_record[i] = res
+            results: List[ProcessingResult] = []
+            for pos, res in zip(wave.positions, wave.per_record):
+                if res is None:  # poisoned host record: contained, no output
+                    res = ProcessingResult()
+                stamp_source_positions(res.written, pos)
+                results.append(res)
+        # (host, device) seconds and phases of the last collected wave —
+        # read by the in-process broker's wave metrics (the seconds are the
+        # same attribute as the host oracle's)
         self.last_wave_seconds = (wave.host_seconds, wave.device_seconds)
+        self.last_wave_phases = clock
         wave.collected = results
         return results
 
@@ -2376,20 +2400,22 @@ class TpuPartitionEngine:
             i64_l[lane_owner] = i64
             i32_l[lane_owner] = i32
             bool_l[lane_owner] = bools
-            i64_dev = put(i64_l)
-            i32_dev = put(i32_l)
-            bool_dev = put(bool_l)
+            laned = [i64_l, i32_l, bool_l]
+            for name in ("v_vt", "v_num", "v_str"):
+                mat = cols[name]
+                lanes = np.zeros((D,) + mat.shape, mat.dtype)
+                lanes[lane_owner] = mat
+                laned.append(lanes)
+            i64_dev, i32_dev, bool_dev, vt_dev, num_dev, str_dev = (
+                self._put_staged(put, laned)
+            )
             for j, name in enumerate(self._I64_COLS):
                 kw[name] = i64_dev[:, :, j]
             for j, name in enumerate(self._I32_COLS):
                 kw[name] = i32_dev[:, :, j]
             for j, name in enumerate(self._BOOL_COLS):
                 kw[name] = bool_dev[:, :, j]
-            for name in ("v_vt", "v_num", "v_str"):
-                mat = cols[name]
-                lanes = np.zeros((D,) + mat.shape, mat.dtype)
-                lanes[lane_owner] = mat
-                kw[name] = put(lanes)
+            kw.update(v_vt=vt_dev, v_num=num_dev, v_str=str_dev)
             return RecordBatch(**kw)
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -2401,19 +2427,29 @@ class TpuPartitionEngine:
                 jnp.asarray if self.device is None
                 else (lambda a: jax.device_put(a, self.device))
             )
-        i64_dev = put(i64)
-        i32_dev = put(i32)
-        bool_dev = put(bools)
+        i64_dev, i32_dev, bool_dev, vt_dev, num_dev, str_dev = (
+            self._put_staged(
+                put,
+                [i64, i32, bools, cols["v_vt"], cols["v_num"], cols["v_str"]],
+            )
+        )
         for j, name in enumerate(self._I64_COLS):
             kw[name] = i64_dev[:, j]
         for j, name in enumerate(self._I32_COLS):
             kw[name] = i32_dev[:, j]
         for j, name in enumerate(self._BOOL_COLS):
             kw[name] = bool_dev[:, j]
-        kw["v_vt"] = put(cols["v_vt"])
-        kw["v_num"] = put(cols["v_num"])
-        kw["v_str"] = put(cols["v_str"])
+        kw.update(v_vt=vt_dev, v_num=num_dev, v_str=str_dev)
         return RecordBatch(**kw)
+
+    def _put_staged(self, put, arrays: list) -> list:
+        """The wave's host->device transfers: one ``put`` per staged family
+        matrix, as phase ``h2d`` of the wave being dispatched."""
+        clock = self._clock
+        with clock.phase("h2d"):
+            placed = [put(a) for a in arrays]
+        clock.count("h2d_bytes", sum(a.nbytes for a in arrays))
+        return placed
 
     def warm(self, sizes=(512,)) -> None:
         """Pre-compile the step program for the hot batch shapes BEFORE the
@@ -2450,23 +2486,24 @@ class TpuPartitionEngine:
         stats)``. All programs are bit-identical by construction, so
         callers never branch on the mode."""
         pid = jnp.asarray(self.partition_id, jnp.int32)
-        if self._resident_mode:
-            program = (
-                self._state_step_routed
-                if lane_owner is not None
-                else self._state_step_fallback
-            )
-            self.state, out, stats = program(
-                self.graph, self.state, batch, now, pid
-            )
-        elif self._state_step is not None:
-            self.state, out, stats = self._state_step(
-                self.graph, self.state, batch, now, pid
-            )
-        else:
-            self.state, out, stats = kernel.step_jit(
-                self.graph, self.state, batch, now, partition_id=pid
-            )
+        with self._clock.phase("launch"):
+            if self._resident_mode:
+                program = (
+                    self._state_step_routed
+                    if lane_owner is not None
+                    else self._state_step_fallback
+                )
+                self.state, out, stats = program(
+                    self.graph, self.state, batch, now, pid
+                )
+            elif self._state_step is not None:
+                self.state, out, stats = self._state_step(
+                    self.graph, self.state, batch, now, pid
+                )
+            else:
+                self.state, out, stats = kernel.step_jit(
+                    self.graph, self.state, batch, now, partition_id=pid
+                )
         if self._mesh is not None:
             from zeebe_tpu.runtime import metrics as metrics_mod
             from zeebe_tpu.tpu import shard as shard_mod
@@ -2644,6 +2681,16 @@ class TpuPartitionEngine:
         metas: "Optional[List[tuple]]" = None,
         route=None,
     ) -> _PendingSegment:
+        """One device segment of the wave being dispatched, as its phase
+        ``stage`` (the transfers and the launch inside it are phases of
+        their own: ``_put_staged``, ``_run_step``)."""
+        with self._clock.phase("stage"):
+            return self._stage_and_launch(records, positions, metas, route)
+
+    def _stage_and_launch(
+        self, records: List, positions: List[int],
+        metas: "Optional[List[tuple]]", route,
+    ) -> _PendingSegment:
         """Host pre-work + staging + kernel launch for one device segment;
         returns the pending segment WITHOUT synchronizing on the device
         (overflow check and emission fetch happen in ``_collect_device``).
@@ -2808,28 +2855,32 @@ class TpuPartitionEngine:
         seg.stats = stats
         return seg
 
-    def _collect_device(self, seg: _PendingSegment) -> float:
+    def _collect_device(self, seg: _PendingSegment, clock) -> None:
         """Synchronize on one dispatched segment: overflow check + ONE
         bulk device→host fetch of the whole emission batch, then columnar
-        decode into the segment's per-record results. Returns the seconds
-        spent blocked on the device (the host/device time-split metric)."""
-        import time as _time
-
+        decode into the segment's per-record results. The first sync is
+        the wave's phase ``blocked`` (host time waiting for the device),
+        the fetch its ``readback``; the decode runs in the caller's
+        phase."""
         if seg.out is None:
-            return 0.0
-        t0 = _time.perf_counter()
-        if bool(seg.stats["overflow"]):
+            return
+        with clock.phase("blocked"):
+            overflow = bool(seg.stats["overflow"])
+        if overflow:
             raise RuntimeError(
                 "device table overflow — raise TpuPartitionEngine capacity"
             )
-        o = jax.device_get(seg.out)
+        with clock.phase("readback"):
+            o = jax.device_get(seg.out)
+        clock.count(
+            "d2h_bytes", sum(a.nbytes for a in jax.tree_util.tree_leaves(o))
+        )
         # collection is one-shot: clear the device refs BEFORE decoding so
         # a re-collect of this wave (the drain's finally path after an
         # exception elsewhere) can never append duplicate emissions into
         # seg.results
         seg.out = None
         seg.stats = None
-        waited = _time.perf_counter() - t0
         if seg.route_owner is not None:
             self._note_residency(o, seg.route_owner, seg.seq)
         elif seg.fb_pop:
@@ -2848,7 +2899,6 @@ class TpuPartitionEngine:
             o, [seg.positions[i] for i in seg.live], seg.results, seg.live,
             seg.suppress,
         )
-        return waited
 
     def _next_wf_key_host(self) -> int:
         """Allocate a workflow key host-side, keeping the device counter in

@@ -9,6 +9,9 @@ Three layers (docs/operations/tracing.md is the operator guide):
 - **Wave timelines** (:class:`spans.WaveTimeline`): per-wave dispatch/
   collect events per device segment, exportable as Chrome-trace JSON via
   ``tools/trace_report.py``.
+- **Wave phases** (:mod:`phases`): every serving cycle cut into named,
+  contiguous host phases by the code that does the work; the same stamps
+  feed always-on counters, the timelines and the profiler trace.
 - **Flight recorder** (:mod:`recorder`): always on regardless of the
   span tracer — a bounded lock-free ring of recent control-plane events,
   dumped to disk on chaos-invariant failure or explicit signal.
@@ -16,8 +19,14 @@ Three layers (docs/operations/tracing.md is the operator guide):
 
 from __future__ import annotations
 
+import sys
 from typing import Optional
 
+from zeebe_tpu.tracing.phases import (  # noqa: F401 - public surface
+    PhaseClock,
+    select_slices,
+    selected_slices,
+)
 from zeebe_tpu.tracing.recorder import (  # noqa: F401 - public surface
     FLIGHT,
     FlightRecorder,
@@ -44,6 +53,7 @@ from zeebe_tpu.tracing.spans import (  # noqa: F401 - public surface
     RecordTracer,
     Span,
     now_us,
+    wall_ns,
 )
 
 # the process-wide span tracer; None = spans off (flight recorder stays on)
@@ -89,6 +99,25 @@ def ensure_tracer(cfg=None) -> Optional[RecordTracer]:
         commit_stall_ms=cfg.commit_stall_ms,
         slow_wave_ms=cfg.slow_wave_ms,
     ))
+
+
+def phase_clock(slices: Optional[list] = None) -> PhaseClock:
+    """The clock of one cycle (``tracing/phases.py``). Its totals always
+    count; with a tracer installed its open phases also sit in a profiler
+    trace (where jax is loaded: a host-engine broker imports nothing for
+    this), and ``slices`` is the timeline list of a stride-selected cycle."""
+    if TRACER is None:
+        return PhaseClock()
+    return PhaseClock(slices, "jax" in sys.modules)
+
+
+def cycle_clock(track: str, **fields) -> PhaseClock:
+    """The clock of a drain, a tick or a raft group commit; the cycles the
+    tracer's stride selects land in ``TRACER.cycles`` with their phases."""
+    tracer = TRACER
+    if tracer is None:
+        return PhaseClock()
+    return PhaseClock(tracer.cycles.cycle(track, **fields), "jax" in sys.modules)
 
 
 def no_ack_plane(partition_or_server) -> bool:

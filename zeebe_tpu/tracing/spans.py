@@ -75,6 +75,12 @@ def now_us() -> int:
     return (time.perf_counter_ns() - _T0_NS) // 1000
 
 
+def wall_ns(t_us: int) -> int:
+    """A span-clock stamp as wall-clock nanoseconds (how a reader puts
+    stamps beside clocks of other processes or of a profiler trace)."""
+    return int((_T0_WALL + t_us / 1e6) * 1e9)
+
+
 class Span:
     """One sampled record's lifecycle. Mutated only under the tracer lock."""
 
@@ -139,12 +145,14 @@ class WaveTimeline:
         # no lock: begin()/segment()/snapshot() rely on GIL-atomic deque
         # append and single-writer dict mutation (the scheduler thread)
 
-    def begin(self, wave_id: int, capacity: int) -> dict:
+    def begin(self, wave_id: int, capacity: int,
+              phases: Optional[list] = None) -> dict:
         """Record a timeline for an already stride-selected wave. The
         dispatcher draws ``wave_id`` from ``next(waves.seq)`` and checks
         ``wave_id % waves.stride`` inline — on the 1-record-wave
         degenerate path even one extra method call per wave is measurable
-        against the ≤2% overhead gate."""
+        against the ≤2% overhead gate. ``phases`` is the slice list the
+        wave's phase clocks append to (``tracing/phases.py``)."""
         event = {
             "wave_id": wave_id,
             "t_dispatch_us": now_us(),
@@ -152,9 +160,23 @@ class WaveTimeline:
             "capacity": capacity,
             "records": 0,
             "segments": [],
+            "phases": phases if phases is not None else [],
         }
         self._ring.append(event)
         return event
+
+    def cycle(self, track: str, **fields) -> Optional[list]:
+        """Stride-select one cycle that is no wave (a drain, a tick, a raft
+        group commit): returns the slice list of its event for the cycle's
+        phase clock to fill, or None where the stride passes it over."""
+        cycle_id = next(self.seq)
+        if cycle_id % self.stride:
+            return None
+        phases: list = []
+        self._ring.append(
+            {"track": track, "cycle_id": cycle_id, "phases": phases, **fields}
+        )
+        return phases
 
     @staticmethod
     def segment(event: dict, partition: int, device: int, records: int) -> dict:
@@ -230,6 +252,11 @@ class RecordTracer:
         elif self.sample_rate < 1.0:
             stride = min(1000, max(1, round(1.0 / self.sample_rate)))
         self.waves = WaveTimeline(stride=stride)
+        # drains, ticks and raft group commits: same class, same stride.
+        # They come several times as often as waves (a group commit per
+        # client command at worst), and a reader of a 51 s run at rate 1.0
+        # still wants the seconds in its middle when the run has ended.
+        self.cycles = WaveTimeline(capacity=32768, stride=stride)
         self._dropped = 0
         self._sampled = 0
 
@@ -552,8 +579,8 @@ class RecordTracer:
             }
 
     def dump(self, path: str) -> str:
-        """Write spans + wave timelines + the flight-recorder ring as one
-        JSON document (the ``tools/trace_report.py`` input format)."""
+        """Write spans + wave and cycle timelines + the flight-recorder ring
+        as one JSON document (the ``tools/trace_report.py`` input format)."""
         import json
 
         from zeebe_tpu.tracing.recorder import FLIGHT
@@ -564,6 +591,7 @@ class RecordTracer:
             "stats": self.stats(),
             "spans": [span.to_dict() for span in self.spans()],
             "waves": self.waves.snapshot(),
+            "cycles": self.cycles.snapshot(),
             "events": FLIGHT.snapshot(),
         }
         with open(path, "w") as f:
