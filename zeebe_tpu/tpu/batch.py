@@ -225,15 +225,51 @@ def columns_to_payload(
     return doc
 
 
-# row-take packing groups (schema-derived so a new field fails loudly here
-# instead of silently dropping from the packed takes)
-_I32_SCALARS = ["rtype", "vtype", "intent", "elem", "wf", "req_stream",
-                "type_id", "retries", "worker", "src", "rej"]
-_I64_SCALARS = ["key", "instance_key", "scope_key", "req", "aux_key",
-                "aux2_key", "deadline"]
-_I8_SCALARS = ["valid", "resp", "push"]
-assert set(_I32_SCALARS + _I64_SCALARS + _I8_SCALARS
-           + ["v_vt", "v_num", "v_str"]) == set(_FIELDS)
+# the scalar columns' dtype families, schema-derived so a new field fails
+# loudly here instead of silently dropping: the ONE layout shared by the
+# packed row takes below and by a staged wave (``StagedBatch``), whose
+# family matrices hold these columns in this order
+I32_COLS = ("rtype", "vtype", "intent", "elem", "wf", "req_stream",
+            "type_id", "retries", "worker", "src", "rej")
+I64_COLS = ("key", "instance_key", "scope_key", "req", "aux_key",
+            "aux2_key", "deadline")
+BOOL_COLS = ("valid", "resp", "push")
+assert set(I32_COLS + I64_COLS + BOOL_COLS
+           + ("v_vt", "v_num", "v_str")) == set(_FIELDS)
+
+
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["i64", "i32", "bools", "v_vt", "v_num", "v_str"],
+    meta_fields=[],
+)
+@dataclasses.dataclass
+class StagedBatch:
+    """A wave as it crosses to the device: one matrix per dtype family
+    (six leaves, one transfer each) instead of one array per column. Any
+    leading dims (the routed ``[num_shards]`` lane dim) ride along."""
+
+    i64: jax.Array    # [.., B, len(I64_COLS)] i64
+    i32: jax.Array    # [.., B, len(I32_COLS)] i32
+    bools: jax.Array  # [.., B, len(BOOL_COLS)] bool
+    v_vt: jax.Array   # [.., B, V] i8
+    v_num: jax.Array  # [.., B, V] f32
+    v_str: jax.Array  # [.., B, V] i32
+
+
+def column_views(batch) -> RecordBatch:
+    """The ``RecordBatch`` of a staged wave's column views (last-axis
+    slices of its family matrices); a ``RecordBatch`` passes through.
+    The step program calls this at trace time, so the slices are part of
+    the compiled program and the host launches none."""
+    if isinstance(batch, RecordBatch):
+        return batch
+    kw = {n: batch.i64[..., j] for j, n in enumerate(I64_COLS)}
+    kw.update({n: batch.i32[..., j] for j, n in enumerate(I32_COLS)})
+    kw.update({n: batch.bools[..., j] for j, n in enumerate(BOOL_COLS)})
+    return RecordBatch(
+        v_vt=batch.v_vt, v_num=batch.v_num, v_str=batch.v_str, **kw
+    )
 
 
 def take_rows(batch: RecordBatch, idx: jax.Array) -> RecordBatch:
@@ -250,16 +286,16 @@ def take_rows(batch: RecordBatch, idx: jax.Array) -> RecordBatch:
 
     v = batch.num_vars
     i32_mat = jnp.concatenate(
-        [jnp.stack([getattr(batch, n) for n in _I32_SCALARS], axis=-1),
+        [jnp.stack([getattr(batch, n) for n in I32_COLS], axis=-1),
          batch.v_str,
          jax.lax.bitcast_convert_type(batch.v_num, jnp.int32),
          pops.i64_to_planes(
-             jnp.stack([getattr(batch, n) for n in _I64_SCALARS], axis=-1)
+             jnp.stack([getattr(batch, n) for n in I64_COLS], axis=-1)
          )],
         axis=1,
     )
     i8_mat = jnp.concatenate(
-        [jnp.stack([getattr(batch, n).astype(jnp.int8) for n in _I8_SCALARS],
+        [jnp.stack([getattr(batch, n).astype(jnp.int8) for n in BOOL_COLS],
                    axis=-1),
          batch.v_vt],
         axis=1,
@@ -269,10 +305,10 @@ def take_rows(batch: RecordBatch, idx: jax.Array) -> RecordBatch:
         [pops.GatherOp(0, idx), pops.GatherOp(1, idx)],
         family="emit",
     )
-    n32 = len(_I32_SCALARS)
+    n32 = len(I32_COLS)
     i64_mat = pops.planes_to_i64(t32[:, n32 + 2 * v :])
-    out = {n: t32[:, i] for i, n in enumerate(_I32_SCALARS)}
-    out.update({n: i64_mat[:, i] for i, n in enumerate(_I64_SCALARS)})
+    out = {n: t32[:, i] for i, n in enumerate(I32_COLS)}
+    out.update({n: i64_mat[:, i] for i, n in enumerate(I64_COLS)})
     out.update(
         valid=t8[:, 0].astype(bool),
         resp=t8[:, 1].astype(bool),

@@ -191,8 +191,8 @@ def _frame_defaults():
 
 
 # rows staged for the device STRAIGHT from readback columns (no Record
-# build) — the counterpart of serving_rows_materialized_total; cached
-# handle, this sits on the staging hot loop
+# build; index gathers in _stage_from_emission) — the counterpart of
+# serving_rows_materialized_total; cached handle, bumped once per wave
 _staged_columnar_counter = None
 
 
@@ -203,8 +203,9 @@ def _count_staged_columnar(n: int = 1) -> None:
 
         _staged_columnar_counter = GLOBAL_REGISTRY.counter(
             "serving_rows_staged_columnar_total",
-            "Device rows re-staged straight from emission-batch columns "
-            "(no Record object ever materialized for them)",
+            "Device rows staged by index gathers straight from their "
+            "emission batch's columns (no Record object ever materialized "
+            "for them)",
         )
     _staged_columnar_counter.inc(n)
 
@@ -443,11 +444,11 @@ class TpuPartitionEngine:
             self._shard_exchange_bytes = shard_mod.state_exchange_bytes(
                 self.state, self._state_shards
             )
-        # key watermark of the last rebuild_lookup_state run: the direct-
+        # key advance since the last rebuild_lookup_state run: the direct-
         # mapped indexes are collision-free only within a window of index-
         # capacity consecutive keys, so the serving path re-derives the
-        # fallback maps before the window can wrap (process_batch)
-        self._keys_at_rebuild = 0
+        # fallback maps before the window can wrap (_stage_and_launch)
+        self._note_lookup_rebuilt()
         self._compiled_count = 0
         # repository size at the last _recompile (see dispatch_wave)
         self._repo_compiled = 0
@@ -1029,7 +1030,7 @@ class TpuPartitionEngine:
         # the ring runs dry and inserts report spurious table overflow
         # while the freed rows sit unused until the next cadence rebuild
         self.state = state_mod.rebuild_lookup_state(new_state)
-        self._keys_at_rebuild = 0
+        self._note_lookup_rebuilt()
 
     def _routes_to_host(self, record: Record) -> bool:
         """True when a device-value-type record belongs to a host-only
@@ -1636,7 +1637,7 @@ class TpuPartitionEngine:
             self._shard_exchange_bytes = shard_mod.state_exchange_bytes(
                 st, self._state_shards
             )
-        self._keys_at_rebuild = 0
+        self._note_lookup_rebuilt()
         self.capacity = st.capacity
         self.num_vars = st.num_vars
         self.last_processed_position = int(
@@ -2152,16 +2153,29 @@ class TpuPartitionEngine:
         wave.collected = results
         return results
 
+    def _device_key_counters(self) -> Tuple[int, int]:
+        """The device's next workflow and job keys (a blocking device→host
+        read of two scalars)."""
+        # .item() extracts the scalar for any size-1 array; plain int() on a
+        # ndim>0 array is deprecated NumPy behavior that will hard-error
+        return (
+            int(np.asarray(self.state.next_wf_key).item()),
+            int(np.asarray(self.state.next_job_key).item()),
+        )
+
+    def _note_lookup_rebuilt(self) -> None:
+        """The lookup structures were just derived from the rows: the key
+        window (see ``_stage_and_launch``) opens at the counters of now."""
+        self._keys_at_rebuild = 0
+        self._keys_rebuilt = self._device_key_counters()
+
     def _pull_device_keys_into_host(self) -> None:
         """Advance the embedded oracle's key generators past the device
         counters (one device→host scalar read; called only at
         device-segment → host-record boundaries)."""
         from zeebe_tpu.engine import keyspace
 
-        # .item() extracts the scalar for any size-1 array; plain int() on a
-        # ndim>0 array is deprecated NumPy behavior that will hard-error
-        dev_wf = int(np.asarray(self.state.next_wf_key).item())
-        dev_job = int(np.asarray(self.state.next_job_key).item())
+        dev_wf, dev_job = self._device_key_counters()
         if self._host.wf_keys.peek < dev_wf or self._host.job_keys.peek < dev_job:
             self._host.snapshot_mark_dirty(("h/control",))
         if self._host.wf_keys.peek < dev_wf:
@@ -2232,14 +2246,8 @@ class TpuPartitionEngine:
     # -- host record → batch row -------------------------------------------
     _TPU_BATCH = 512  # one canonical staged shape on TPU (= drain chunk)
 
-    # dtype families for the packed host→device transfer: one bulk
-    # device_put per family (6 total) instead of one per column (24) —
-    # each transfer is its own host→device dispatch
-    _I64_COLS = ("key", "instance_key", "scope_key", "req", "aux_key",
-                 "aux2_key", "deadline")
-    _I32_COLS = ("rtype", "vtype", "intent", "elem", "wf", "req_stream",
-                 "type_id", "retries", "worker", "src", "rej")
-    _BOOL_COLS = ("valid", "resp", "push")
+    # staging defaults of the scalar columns (an all-default row is an
+    # invalid row); the family each column rides in is ``rb.*_COLS``
     _COL_DEFAULTS = {
         "valid": False, "rtype": 0, "vtype": 0, "intent": 0, "key": -1,
         "elem": -1, "wf": -1, "instance_key": -1, "scope_key": -1,
@@ -2250,7 +2258,7 @@ class TpuPartitionEngine:
 
     def _stage(
         self, records: List[Record], pad_to: int = 0, lane_owner=None
-    ) -> RecordBatch:
+    ) -> rb.StagedBatch:
         n = len(records)
         # on TPU every batch pads to ONE canonical shape: invalid rows are
         # SIMD-masked and near-free, while each distinct pow2 bucket would
@@ -2266,96 +2274,107 @@ class TpuPartitionEngine:
             pad_to = max(pad_to, self._TPU_BATCH)
         size = max(_pow2(n), pad_to)
         v = self.num_vars
-        # columnar fill: scalar columns are plain Python lists (C-speed
-        # setitem per row, ONE numpy conversion per column at pack time)
-        # — per-element numpy scalar writes were the measured host cost of
-        # staging a serving wave. Payload matrices stay numpy: their rows
-        # assign vectorized.
-        cols: Dict[str, object] = {
-            name: [default] * size
-            for name, default in self._COL_DEFAULTS.items()
+        # the wave is filled where it ships from: one host matrix per dtype
+        # family, its defaults written with one broadcast. A routed wave
+        # (``lane_owner``, sharded-state v2) gets a leading [num_shards]
+        # lane dim: the owner's lane takes the rows, every other lane
+        # keeps the all-invalid defaults.
+        lead = () if lane_owner is None else (self._state_shards,)
+
+        def family(names, dtype):
+            mat = np.empty(lead + (size, len(names)), dtype)
+            mat[...] = [self._COL_DEFAULTS[name] for name in names]
+            return mat
+
+        staged = rb.StagedBatch(
+            i64=family(rb.I64_COLS, np.int64),
+            i32=family(rb.I32_COLS, np.int32),
+            bools=family(rb.BOOL_COLS, bool),
+            v_vt=np.zeros(lead + (size, v), np.int8),
+            v_num=np.zeros(lead + (size, v), np.float32),
+            v_str=np.zeros(lead + (size, v), np.int32),
+        )
+        lane = (
+            staged if lane_owner is None
+            else jax.tree.map(lambda a: a[lane_owner], staged)
+        )
+        # the columns by name, as numpy VIEWS of the matrices (the same
+        # slicing the step program does on the device): the row and column
+        # writers below fill the matrices through them
+        views = rb.column_views(lane)
+        cols = {
+            f.name: getattr(views, f.name) for f in dataclasses.fields(views)
         }
-        cols["v_vt"] = np.zeros((size, v), np.int8)
-        cols["v_num"] = np.zeros((size, v), np.float32)
-        cols["v_str"] = np.zeros((size, v), np.int32)
-        staged_lazy = 0
+        # lazy emission refs (_lazy_device_row admitted them) copy the
+        # device columns straight from their readback batch — payloads
+        # skip the columns→payload→columns round trip — one index gather
+        # per column and source batch; materialized Records (client
+        # commands) fill row by row
+        by_source: Dict[int, tuple] = {}
         for i, record in enumerate(records):
             if type(record) is tuple:
-                # lazy emission ref (_lazy_device_row admitted it): copy
-                # the device columns straight from the readback batch —
-                # payloads skip the columns→payload→columns round trip
                 src, j = record[0].device_ref(record[1])
-                self._stage_from_emission(cols, i, src, j)
-                staged_lazy += 1
+                group = by_source.get(id(src))
+                if group is None:
+                    group = by_source[id(src)] = (src, [], [])
+                group[1].append(i)
+                group[2].append(j)
             else:
                 self._stage_row(cols, i, record)
-        if staged_lazy:
-            _count_staged_columnar(staged_lazy)
-        return self._pack_batch(cols, size, lane_owner=lane_owner)
+        for src, rows, js in by_source.values():
+            self._stage_from_emission(cols, src, np.array(rows), np.array(js))
+        if by_source:
+            _count_staged_columnar(
+                sum(len(rows) for _src, rows, _js in by_source.values())
+            )
+        return self._pack_batch(staged, cols, lane_owner=lane_owner)
 
-    def _stage_from_emission(self, cols, i, src, j) -> None:
-        """Stage one row by COPYING the backing emission batch's columns
-        (the kernel emitted them; re-deriving via a materialized Record is
-        the identity — pinned by the lazy-vs-eager log bit-identity test).
-        Only the columns ``_stage_row`` would set for the value type are
-        copied; everything else keeps the staging defaults (``src``,
-        ``resp``, ``push`` are per-staging flags, never carried over)."""
-        o, s, _epoch = src.device_source
-        vt = s["vtype"][j]
-        cols["valid"][i] = True
-        cols["rtype"][i] = s["rtype"][j]
-        cols["vtype"][i] = vt
-        cols["intent"][i] = s["intent"][j]
-        cols["key"][i] = s["key"][j]
-        cols["req"][i] = s["req"][j]
-        cols["req_stream"][i] = s["req_stream"][j]
-        wf = s["wf"][j]
-        if vt == int(ValueType.WORKFLOW_INSTANCE):
-            cols["wf"][i] = wf
-            cols["elem"][i] = s["elem"][j] if wf >= 0 else -1
-            cols["instance_key"][i] = s["instance_key"][j]
-            cols["scope_key"][i] = s["scope_key"][j]
-        elif vt == int(ValueType.JOB):
-            cols["type_id"][i] = s["type_id"][j]
-            cols["retries"][i] = s["retries"][j]
-            cols["deadline"][i] = s["deadline"][j]
-            cols["worker"][i] = s["worker"][j]
-            cols["aux_key"][i] = s["aux_key"][j]
-            cols["instance_key"][i] = s["instance_key"][j]
-            cols["wf"][i] = wf
-            cols["elem"][i] = s["elem"][j] if wf >= 0 else -1
+    def _stage_from_emission(self, cols, src, rows, js) -> None:
+        """Stage ``rows`` by COPYING rows ``js`` of the backing emission
+        batch's columns (the kernel emitted them; re-deriving via a
+        materialized Record is the identity — pinned by the lazy-vs-eager
+        log bit-identity test). Only the columns ``_stage_row`` would set
+        for each row's value type are copied; everything else keeps the
+        staging defaults (``src``, ``resp``, ``push`` are per-staging
+        flags, never carried over)."""
+        o = src.device_source[0]
+        cols["valid"][rows] = True
+        for name in ("rtype", "vtype", "intent", "key", "req", "req_stream"):
+            cols[name][rows] = o[name][js]
+        vt = o["vtype"][js]
+        is_wi = vt == int(ValueType.WORKFLOW_INSTANCE)
+        is_job = vt == int(ValueType.JOB)
+        for names, mask in (
+            (("wf", "elem", "instance_key"), is_wi | is_job),
+            (("scope_key",), is_wi),
+            (("type_id", "retries", "deadline", "worker", "aux_key"), is_job),
+        ):
+            r, j = rows[mask], js[mask]
+            for name in names:
+                cols[name][r] = o[name][j]
+        # an element index means something only under a workflow slot
+        cols["elem"][rows[o["wf"][js] < 0]] = -1
         # payload columns copy MASKED by the type column: zeros where no
         # variable is set — exactly what payload_to_columns(
         # columns_to_payload(...)) would produce (unset lanes must not
         # carry junk)
-        vt_row = o["v_vt"][j]
-        mask = vt_row != 0
-        cols["v_vt"][i] = vt_row
-        cols["v_num"][i] = np.where(mask, o["v_num"][j], 0)
-        cols["v_str"][i] = np.where(mask, o["v_str"][j], 0)
+        v_vt = o["v_vt"][js]
+        cols["v_vt"][rows] = v_vt
+        cols["v_num"][rows] = np.where(v_vt != 0, o["v_num"][js], 0)
+        cols["v_str"][rows] = np.where(v_vt != 0, o["v_str"][js], 0)
 
     def _pack_batch(
-        self, cols: Dict[str, object], size: int, lane_owner=None
-    ) -> RecordBatch:
-        """Scalar columns → one matrix per dtype family → one device_put
-        each; the batch's per-column views are device slices (safe: the
-        step program donates only the state argument, never the batch).
+        self, staged: rb.StagedBatch, cols: Dict[str, np.ndarray],
+        lane_owner=None,
+    ) -> rb.StagedBatch:
+        """The filled host matrices → the wave on the device: one
+        device_put per dtype family and nothing else — the step program
+        takes the column views itself (``rb.column_views``), so no device
+        op runs between the fill and the launch.
 
-        ``lane_owner`` (resident routing, sharded-state v2) packs the same
-        family matrices into a ``[num_shards, size]`` laned layout — the
-        owner shard's lane carries the staged rows, every other lane holds
-        the all-invalid staging defaults — and the put is lane-sharded
-        over the mesh axis, so each device receives ONLY its own routed
-        rows while the transfer count stays one per dtype family."""
-        i64 = np.empty((size, len(self._I64_COLS)), np.int64)
-        for j, name in enumerate(self._I64_COLS):
-            i64[:, j] = cols[name]
-        i32 = np.empty((size, len(self._I32_COLS)), np.int32)
-        for j, name in enumerate(self._I32_COLS):
-            i32[:, j] = cols[name]
-        bools = np.empty((size, len(self._BOOL_COLS)), bool)
-        for j, name in enumerate(self._BOOL_COLS):
-            bools[:, j] = cols[name]
+        A routed wave (``lane_owner``) is put lane-sharded over the mesh
+        axis, so each device receives ONLY its own lane while the
+        transfer count stays one per dtype family."""
         # sharded-state routing accounting: record the staged row split
         # (residency basis: instance_key in resident mode, advisory key
         # hash otherwise) and the valid count — _run_step observes them
@@ -2371,76 +2390,30 @@ class TpuPartitionEngine:
             self._last_stage_split = shard_mod.shard_row_counts_host(
                 basis, cols["valid"], self._state_shards
             )
-            self._last_stage_valid = int(
-                np.count_nonzero(np.asarray(cols["valid"], bool))
-            )
-        # staged columns commit to THIS engine's mesh device (placement is
+            self._last_stage_valid = int(np.count_nonzero(cols["valid"]))
+        # the matrices commit to THIS engine's mesh device (placement is
         # what routes the step program to it); sharded mode replicates
         # them over the span via _place-style NamedSharding (lane-sharded
         # in routed staging); default device otherwise
-        kw: Dict[str, jax.Array] = {}
-        if self._mesh is not None and lane_owner is not None:
+        if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             from zeebe_tpu.tpu import shard as shard_mod
 
-            D = self._state_shards
-            lane_spec = NamedSharding(
-                self._mesh, PartitionSpec(shard_mod.STATE_AXIS)
+            sharding = NamedSharding(
+                self._mesh,
+                PartitionSpec() if lane_owner is None
+                else PartitionSpec(shard_mod.STATE_AXIS),
             )
-            put = lambda a: jax.device_put(a, lane_spec)  # noqa: E731
-            i64_def = np.array(
-                [self._COL_DEFAULTS[n] for n in self._I64_COLS], np.int64
-            )
-            i32_def = np.array(
-                [self._COL_DEFAULTS[n] for n in self._I32_COLS], np.int32
-            )
-            i64_l = np.broadcast_to(i64_def, (D, size, i64_def.size)).copy()
-            i32_l = np.broadcast_to(i32_def, (D, size, i32_def.size)).copy()
-            bool_l = np.zeros((D, size, len(self._BOOL_COLS)), bool)
-            i64_l[lane_owner] = i64
-            i32_l[lane_owner] = i32
-            bool_l[lane_owner] = bools
-            laned = [i64_l, i32_l, bool_l]
-            for name in ("v_vt", "v_num", "v_str"):
-                mat = cols[name]
-                lanes = np.zeros((D,) + mat.shape, mat.dtype)
-                lanes[lane_owner] = mat
-                laned.append(lanes)
-            i64_dev, i32_dev, bool_dev, vt_dev, num_dev, str_dev = (
-                self._put_staged(put, laned)
-            )
-            for j, name in enumerate(self._I64_COLS):
-                kw[name] = i64_dev[:, :, j]
-            for j, name in enumerate(self._I32_COLS):
-                kw[name] = i32_dev[:, :, j]
-            for j, name in enumerate(self._BOOL_COLS):
-                kw[name] = bool_dev[:, :, j]
-            kw.update(v_vt=vt_dev, v_num=num_dev, v_str=str_dev)
-            return RecordBatch(**kw)
-        if self._mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            _repl = NamedSharding(self._mesh, PartitionSpec())
-            put = lambda a: jax.device_put(a, _repl)  # noqa: E731
+            put = lambda a: jax.device_put(a, sharding)  # noqa: E731
         else:
             put = (
                 jnp.asarray if self.device is None
                 else (lambda a: jax.device_put(a, self.device))
             )
-        i64_dev, i32_dev, bool_dev, vt_dev, num_dev, str_dev = (
-            self._put_staged(
-                put,
-                [i64, i32, bools, cols["v_vt"], cols["v_num"], cols["v_str"]],
-            )
+        leaves, treedef = jax.tree_util.tree_flatten(staged)
+        return jax.tree_util.tree_unflatten(
+            treedef, self._put_staged(put, leaves)
         )
-        for j, name in enumerate(self._I64_COLS):
-            kw[name] = i64_dev[:, j]
-        for j, name in enumerate(self._I32_COLS):
-            kw[name] = i32_dev[:, j]
-        for j, name in enumerate(self._BOOL_COLS):
-            kw[name] = bool_dev[:, j]
-        kw.update(v_vt=vt_dev, v_num=num_dev, v_str=str_dev)
-        return RecordBatch(**kw)
 
     def _put_staged(self, put, arrays: list) -> list:
         """The wave's host->device transfers: one ``put`` per staged family
@@ -2464,7 +2437,7 @@ class TpuPartitionEngine:
             self._recompile()
         if self.graph is None:
             return
-        now = jnp.asarray(self.clock(), jnp.int64)
+        now = np.int64(self.clock())
         for n in sizes:
             batch = self._stage([], pad_to=n)
             # zero valid rows: a semantic no-op step that only compiles
@@ -2477,7 +2450,7 @@ class TpuPartitionEngine:
             _out, _stats = self._run_step(batch, now, lane_owner=0)
         jax.block_until_ready(self.state.ei_i32)
 
-    def _run_step(self, batch: RecordBatch, now, lane_owner=None) -> tuple:
+    def _run_step(self, batch: rb.StagedBatch, now, lane_owner=None) -> tuple:
         """Launch ONE wave through the active step program — routed or
         fallback in resident mode (``lane_owner`` picks; the choice is
         host-side so the routed lowering never contains the fallback's
@@ -2485,7 +2458,9 @@ class TpuPartitionEngine:
         otherwise — rebinding ``self.state`` and returning ``(out,
         stats)``. All programs are bit-identical by construction, so
         callers never branch on the mode."""
-        pid = jnp.asarray(self.partition_id, jnp.int32)
+        # ``now`` and the partition id ride in the launch as numpy scalars
+        # (an eager ``jnp.asarray`` would be a device dispatch of its own)
+        pid = np.int32(self.partition_id)
         with self._clock.phase("launch"):
             if self._resident_mode:
                 program = (
@@ -2832,23 +2807,35 @@ class TpuPartitionEngine:
         batch = self._stage(
             [records[i] for i in live], lane_owner=lane_owner
         )
-        now = jnp.asarray(self.clock(), jnp.int64)
+        now = np.int64(self.clock())
         # re-derive the fallback maps before the key window can wrap past
         # the direct-mapped index capacity (see rebuild_lookup_state).
-        # Conservative host-side bound — one record can allocate up to
-        # emit_width keys (parallel split / multi-instance fan-out), each
-        # advancing the counter by the stride (5) — so the serving path
-        # pays no device sync. Resident mode skips the cadence entirely:
-        # BOTH its step programs rebuild the lookup structures in-program
-        # every wave, so no at-rest window can go stale.
+        # Wave by wave the advance is a conservative host-side bound — one
+        # record can allocate up to emit_width keys (parallel split /
+        # multi-instance fan-out), each advancing the counter by the
+        # stride (5) — so the serving path pays no device sync; where the
+        # bound crosses the window, the device's own counters say what was
+        # really allocated (one blocking read of two scalars in some
+        # hundreds of waves), and the rebuild — whole-column passes, and
+        # their compiles the first time — runs only when that is due.
+        # Resident mode skips the cadence entirely: BOTH its step programs
+        # rebuild the lookup structures in-program every wave, so no
+        # at-rest window can go stale.
         if not self._resident_mode:
             fanout = max(
                 1, self.graph.emit_width if self.graph is not None else 1
             )
-            self._keys_at_rebuild += 5 * fanout * len(live)
-            if self._keys_at_rebuild > self.state.ei_index.shape[0] // 4:
-                self.state = state_mod.rebuild_lookup_state(self.state)
-                self._keys_at_rebuild = 0
+            window = self.state.ei_index.shape[0] // 4
+            this_wave = 5 * fanout * len(live)
+            self._keys_at_rebuild += this_wave
+            if self._keys_at_rebuild > window:
+                self._keys_at_rebuild = this_wave + max(
+                    now_key - then_key for now_key, then_key
+                    in zip(self._device_key_counters(), self._keys_rebuilt)
+                )
+                if self._keys_at_rebuild > window:
+                    self.state = state_mod.rebuild_lookup_state(self.state)
+                    self._note_lookup_rebuilt()
         self._mark_device_dirty()  # a kernel step may write any table
         out, stats = self._run_step(batch, now, lane_owner=lane_owner)
         seg.out = out

@@ -343,7 +343,12 @@ def step_kernel(
     emits an instant COMPLETE command — the worker round-trip of
     ``gateway/.../impl/subscription/job/JobSubscriber.java:51`` without
     leaving the device.
+
+    ``batch`` is a ``RecordBatch`` or a staged wave (``rb.StagedBatch``,
+    what the serving engine transfers): the column views of the latter
+    are taken here, inside the program.
     """
+    batch = rb.column_views(batch)
     b = batch.size
     v = state.num_vars
     e_w = graph.emit_width

@@ -835,13 +835,14 @@ def build_state_step_routed(mesh: Mesh, state_template: EngineState):
       (graph, state, lanes, now, partition_id) → (state', out, stats)
 
     ``state`` arrives sharded per ``state_partition_specs`` exactly like
-    ``shard.state_step``; ``lanes`` is a RecordBatch with a leading
-    ``[num_shards]`` lane dim, sharded over the mesh axis, so each device
-    receives ONLY its own routed rows (one host→device put per dtype
-    family covers all lanes). Each shard translates the parent-slot column
-    into its local row space, rebuilds the lookup structures from its own
-    block, and steps the UNMODIFIED kernel on local rows + local lane —
-    no table gather anywhere in the lowering. Emissions, stats, and the
+    ``shard.state_step``; ``lanes`` is a staged wave (or a RecordBatch)
+    with a leading ``[num_shards]`` lane dim, sharded over the mesh axis,
+    so each device receives ONLY its own routed rows (one host→device put
+    per dtype family covers all lanes). Each shard translates the
+    parent-slot column into its local row space, rebuilds the lookup
+    structures from its own block, and steps the UNMODIFIED kernel on
+    local rows + local lane — no table gather anywhere in the lowering.
+    Emissions, stats, and the
     deltas of replicated leaves (key counters, worker-subscription
     tables) reduce with ``psum``; single-owner waves make every reduction
     exact, so outputs are replicated and bit-identical to the
@@ -861,7 +862,7 @@ def build_state_step_routed(mesh: Mesh, state_template: EngineState):
         from zeebe_tpu.tpu.kernel import scope_to_global, scope_to_local
 
         idx = jax.lax.axis_index(axis)
-        batch = _squeeze(lanes)
+        batch = rb.column_views(_squeeze(lanes))
         mine = jnp.any(batch.valid)
         lrows = state.ei_i32.shape[0]
         prev_scope = state.ei_i32[:, state_mod.EI_SCOPE]

@@ -427,10 +427,14 @@ def rebuild_lookup_state(state: EngineState) -> EngineState:
         join_map=join_map, timer_map=timer_map,
         msub_map=msub_map, msg_map=msg_map,
         free_ei=free_ei,
-        free_ei_pop=_jnp.zeros((), _jnp.int64),
+        # zeros OF the old cursors, not fresh ones: a leaf made from
+        # nothing is uncommitted where the rest of the state is committed,
+        # which is another signature of the step program — the first wave
+        # after a rebuild on the serving path would compile it again
+        free_ei_pop=_jnp.zeros_like(state.free_ei_pop),
         free_ei_push=_jnp.sum(ei_free_mask, dtype=_jnp.int64),
         free_job=free_job,
-        free_job_pop=_jnp.zeros((), _jnp.int64),
+        free_job_pop=_jnp.zeros_like(state.free_job_pop),
         free_job_push=_jnp.sum(job_free_mask, dtype=_jnp.int64),
     )
 
