@@ -96,6 +96,9 @@ class TestRoundRobinCursor:
         state.sub_rr, so consecutive drains continue the rotation."""
         eng = _engine(1, [(1, "work", 10), (2, "work", 10)])
         first = eng.device_backlog_activations()
+        # the job's ACTIVATE is on its way: the next sweeps leave it alone
+        assert eng.device_backlog_activations() == []
+        eng._assigning.clear()  # as if its wave were collected, rejected
         second = eng.device_backlog_activations()
         assert first[0].metadata.request_stream_id == 1
         assert second[0].metadata.request_stream_id == 2

@@ -179,7 +179,7 @@ class TestSpanCompleteness:
         stub = SimpleNamespace(partition_id=0, device_index=3)
         stub.engine = SimpleNamespace(collect_wave=lambda pending: [])
 
-        def apply_chunk(records, merged):
+        def apply_chunk(records, merged, clock=None):
             # the real _apply_chunk stamps APPLY at its top
             tracer.stamp_positions(
                 0, tracing.positions_of(records), tracing.APPLY

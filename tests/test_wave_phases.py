@@ -29,7 +29,16 @@ MODEL = (
 PHASE_COUNTERS = {
     phase: f"serving_{phase}_seconds_total" for phase in phases_mod.TRACKS["wave"]
 }
-COUNTERS = sorted(PHASE_COUNTERS.values()) + [
+# the job path's own (ISSUE 27): phases ``push`` and ``job_read`` are in
+# PHASE_COUNTERS, ``backlog`` is the tick's
+JOB_COUNTERS = [
+    "serving_job_read_seconds_total", "serving_push_seconds_total",
+    "serving_backlog_seconds_total", "serving_job_row_reads_total",
+    "serving_job_pushes_total", "serving_backlog_activations_total",
+    "serving_backlog_skipped_in_flight_total",
+    "serving_job_commands_serialised_total",
+]
+COUNTERS = sorted(set(PHASE_COUNTERS.values()) | set(JOB_COUNTERS)) + [
     "serving_host_seconds_total", "serving_device_seconds_total",
     "serving_waves_total", "serving_h2d_bytes_total", "serving_d2h_bytes_total",
     "serving_drains_total", "serving_drain_wait_seconds_total",
@@ -44,18 +53,13 @@ def counters() -> dict:
     return {name: event_count(name) for name in COUNTERS}
 
 
-@pytest.fixture(scope="module")
-def served(tmp_path_factory):
-    """A small served run: one ClusterBroker leading one partition on the
-    device engine, 24 instances over the client socket, every wave, drain,
-    tick and group commit on the timeline (rate 1.0). Yields what the run
-    left behind."""
-    from zeebe_tpu.gateway.cluster_client import ClusterClient
+def _broker(data_dir: str):
+    """One ClusterBroker on the device engine, every cycle on the timeline
+    (rate 1.0); ``_lead`` makes it the leader of partition 0."""
     from zeebe_tpu.runtime.cluster_broker import ClusterBroker
     from zeebe_tpu.runtime.config import BrokerCfg
     from zeebe_tpu.runtime.engines import engine_factory_from_config
 
-    tracer = tracing.install(tracing.RecordTracer(sample_rate=1.0, seed=25))
     cfg = BrokerCfg()
     cfg.network.client_port = 0
     cfg.network.management_port = 0
@@ -64,20 +68,36 @@ def served(tmp_path_factory):
     cfg.engine.type = "tpu"
     cfg.engine.capacity = 1024
     cfg.tracing.sample_rate = 1.0
-    broker = ClusterBroker(
-        cfg, str(tmp_path_factory.mktemp("phases")),
-        engine_factory=engine_factory_from_config(cfg),
+    return ClusterBroker(
+        cfg, data_dir, engine_factory=engine_factory_from_config(cfg)
     )
+
+
+def _lead(broker):
+    broker.open_partition(0).join(120)
+    broker.bootstrap_partition(0, {})
+    deadline = time.time() + 120
+    while time.time() < deadline and not broker.partitions[0].is_leader:
+        time.sleep(0.01)
+    server = broker.partitions[0]
+    assert server.is_leader
+    return server
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """A small served run: one ClusterBroker leading one partition on the
+    device engine, 24 instances over the client socket, every wave, drain,
+    tick and group commit on the timeline (rate 1.0). Yields what the run
+    left behind."""
+    from zeebe_tpu.gateway.cluster_client import ClusterClient
+
+    tracer = tracing.install(tracing.RecordTracer(sample_rate=1.0, seed=25))
+    broker = _broker(str(tmp_path_factory.mktemp("phases")))
     staged = []
     flushes = []
     try:
-        broker.open_partition(0).join(120)
-        broker.bootstrap_partition(0, {})
-        deadline = time.time() + 120
-        while time.time() < deadline and not broker.partitions[0].is_leader:
-            time.sleep(0.01)
-        server = broker.partitions[0]
-        assert server.is_leader
+        server = _lead(broker)
         assert tracing.TRACER is tracer  # the boot kept the installed tracer
 
         stage = server.engine._stage
@@ -149,6 +169,136 @@ def _runs(slices):
         else:
             runs.append([cur])
     return runs
+
+
+ORDER_MODEL = (
+    Bpmn.create_process("order")
+    .start_event("start")
+    .service_task("pay", type="payment-service")
+    .end_event("end")
+    .done()
+)
+
+
+@pytest.fixture(scope="module")
+def served_jobs(tmp_path_factory):
+    """The same broker serving a process with a job to the client's own
+    worker, which has ONE credit for six jobs: the kernel's pool assigns
+    while the credit is free, and the jobs that found none wait for the
+    tick's sweep (``backlog``, with a row read each) after the credit's
+    return."""
+    from zeebe_tpu.gateway.cluster_client import ClusterClient
+    from zeebe_tpu.protocol.enums import ValueType
+    from zeebe_tpu.protocol.intents import JobIntent
+
+    installed = tracing.TRACER
+    tracer = tracing.install(tracing.RecordTracer(sample_rate=1.0, seed=27))
+    broker = _broker(str(tmp_path_factory.mktemp("job_phases")))
+    jobs = 6
+    try:
+        server = _lead(broker)
+        before = counters()
+        client = ClusterClient([broker.client_address], num_partitions=1)
+        try:
+            client.deploy_model(ORDER_MODEL)
+            worker = client.open_job_worker(
+                "payment-service", lambda _pid, _rec: {"paid": True}, credits=1
+            )
+            for i in range(jobs):
+                client.create_instance("order", {"orderId": i})
+
+            def completed() -> int:
+                return sum(
+                    1 for r in server.log.reader(0).read_committed()
+                    if r.metadata.value_type == ValueType.JOB
+                    and r.metadata.intent == int(JobIntent.COMPLETED)
+                )
+
+            deadline = time.time() + 180
+            while time.time() < deadline and completed() < jobs:
+                time.sleep(0.05)
+            assert completed() == jobs
+            time.sleep(0.5)  # the last wave's clock and a tick's flush
+            worker.close()
+        finally:
+            client.close()
+        records = list(server.log.reader(0).read_committed())
+    finally:
+        broker.close()
+    after = counters()
+    yield {
+        "tracer": tracer,
+        "delta": {name: after[name] - before[name] for name in COUNTERS},
+        "jobs": jobs,
+        "activated": sum(
+            1 for r in records
+            if r.metadata.value_type == ValueType.JOB
+            and r.metadata.intent == int(JobIntent.ACTIVATED)
+        ),
+    }
+    tracing.install(installed)
+
+
+class TestJobPathPhases:
+    def test_zero_on_waves_without_jobs(self, served):
+        """(g) a process with no job leaves every counter of the job path
+        where it was."""
+        for name in JOB_COUNTERS:
+            assert served["delta"][name] == 0, name
+
+    def test_flush_into_their_counters(self, served_jobs):
+        d = served_jobs["delta"]
+        assert served_jobs["activated"] == served_jobs["jobs"]  # each once
+        assert d["serving_job_pushes_total"] == served_jobs["activated"]
+        assert d["serving_push_seconds_total"] > 0
+        # one credit for six jobs: the sweep handed most of them out, each
+        # with one row read, and nothing was handed out twice
+        swept = d["serving_backlog_activations_total"]
+        assert 1 <= swept <= served_jobs["jobs"]
+        assert d["serving_job_row_reads_total"] == swept
+        assert d["serving_backlog_seconds_total"] > 0
+        assert d["serving_job_read_seconds_total"] > 0
+        assert d["serving_job_commands_serialised_total"] == 0
+
+    def test_self_times_on_their_tracks(self, served_jobs):
+        """``push`` is cut out of ``apply``, ``backlog`` out of ``tick`` and
+        ``job_read`` out of ``backlog``: no slice overlaps another, and a
+        phase that was cut resumes where the inner one ended."""
+        tracer = served_jobs["tracer"]
+        waves = [w for w in tracer.waves.snapshot() if w["segments"]]
+        pushed = [w for w in waves if any(s[0] == "push" for s in w["phases"])]
+        assert pushed
+        for wave in pushed:
+            slices = wave["phases"]
+            for prev, cur in zip(slices, slices[1:]):
+                assert cur[1] >= prev[2], (prev, cur)
+            names = [s[0] for s in slices]
+            assert names[names.index("push") - 1] == "apply", names
+            assert set(names) <= set(phases_mod.TRACKS["wave"]), names
+        ticks = [c for c in tracer.cycles.snapshot() if c["track"] == "tick"]
+        swept = [
+            t for t in ticks if any(s[0] == "job_read" for s in t["phases"])
+        ]
+        assert swept
+        for tick in ticks:
+            slices = tick["phases"]
+            assert {s[0] for s in slices} <= set(phases_mod.TRACKS["tick"])
+            for prev, cur in zip(slices, slices[1:]):
+                assert cur[1] == prev[2], (prev, cur)  # contiguous: self times
+        for tick in swept:
+            names = [s[0] for s in tick["phases"]]
+            assert names[0] == "tick" and names[-1] == "tick", names
+            assert names[names.index("job_read") - 1] == "backlog", names
+        # the counters are the slices' sums
+        d = served_jobs["delta"]
+        for phase, counter in (
+            ("backlog", "serving_backlog_seconds_total"),
+            ("job_read", "serving_job_read_seconds_total"),
+        ):
+            total = sum(
+                s[2] - s[1] for t in ticks for s in t["phases"] if s[0] == phase
+            ) / 1e6
+            assert total == pytest.approx(d[counter], rel=0.01, abs=1e-5), phase
 
 
 class TestWavePhases:

@@ -29,6 +29,7 @@ codec frames.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import random
@@ -590,7 +591,7 @@ class PartitionServer:
                     self.partition_id, tracing.positions_of(pending.records),
                     tracing.DEVICE_COLLECT, device=self.device_index,
                 )
-            self._apply_chunk(pending.records, merged)
+            self._apply_chunk(pending.records, merged, clock)
         return pending.host_seconds, pending.device_seconds
 
     def rewind(self, position: int) -> None:
@@ -636,8 +637,12 @@ class PartitionServer:
         if not self.is_leader or self.engine is None:
             return
         clock = tracing.cycle_clock("tick", partition=self.partition_id)
+        # the device engine stamps its own part of a tick on the tick's
+        # clock (``backlog``, and ``job_read`` inside it)
+        on_clock = getattr(self.engine, "on_clock", None)
         with clock.phase("tick"):
-            self._tick_sweeps()
+            with on_clock(clock) if on_clock else contextlib.nullcontext():
+                self._tick_sweeps()
         observe_phases(clock, "ticks")
 
     def _tick_sweeps(self) -> None:
@@ -791,7 +796,7 @@ class PartitionServer:
             getattr(wave, "phases", None),
         )
 
-    def _apply_chunk(self, records: list, result) -> None:
+    def _apply_chunk(self, records: list, result, clock=None) -> None:
         tracer = tracing.TRACER
         if tracer is not None and tracer.by_position:
             tracer.stamp_positions(
@@ -815,8 +820,16 @@ class PartitionServer:
             self.broker.send_client_response(response, server=self)
         for target_pid, send in result.sends:
             self.broker.route_send(self.partition_id, target_pid, send)
-        for subscriber_key, push in result.pushes:
-            self.broker.push_to_subscriber(subscriber_key, self.partition_id, push)
+        if result.pushes:
+            # phase ``push`` of the wave (cut out of ``apply``): ACTIVATED
+            # records marshalled and sent to their job subscribers
+            clock = clock or tracing.PhaseClock()
+            with clock.phase("push"):
+                clock.count("job_pushes", len(result.pushes))
+                for subscriber_key, push in result.pushes:
+                    self.broker.push_to_subscriber(
+                        subscriber_key, self.partition_id, push
+                    )
         self.broker.metrics_events_processed.inc(len(records))
         if self.partition_id == 0:
             # topic orchestration lives on the system partition only; the

@@ -345,6 +345,22 @@ def _phase_handles() -> dict:
                 "Broker seconds applying a collected wave: follow-ups to "
                 "raft.append, responses, sends, pushes",
             ),
+            job_read=g.counter(
+                "serving_job_read_seconds_total",
+                "Seconds reading a device job's row back and building its "
+                "record (one device_get of three row slices a job), for a "
+                "sweep's ACTIVATE or TIME_OUT or a subscription's backlog",
+            ),
+            push=g.counter(
+                "serving_push_seconds_total",
+                "Broker seconds marshalling and sending a collected wave's "
+                "ACTIVATED records to their job subscribers",
+            ),
+            backlog=g.counter(
+                "serving_backlog_seconds_total",
+                "Seconds in the tick's sweep of the device job table for "
+                "jobs no credit was free for (device_backlog_activations)",
+            ),
             drain_wait=g.counter(
                 "serving_drain_wait_seconds_total",
                 "Seconds from a drain being scheduled to its start on the "
@@ -381,6 +397,31 @@ def _phase_handles() -> dict:
             d2h_bytes=g.counter(
                 "serving_d2h_bytes_total",
                 "Bytes fetched by device_get at wave collect",
+            ),
+            job_row_reads=g.counter(
+                "serving_job_row_reads_total",
+                "Device job rows read back to build a record",
+            ),
+            job_pushes=g.counter(
+                "serving_job_pushes_total",
+                "ACTIVATED records pushed to job subscribers by collected "
+                "waves",
+            ),
+            backlog_activations=g.counter(
+                "serving_backlog_activations_total",
+                "ACTIVATE commands the tick's backlog sweep appended",
+            ),
+            backlog_skipped_in_flight=g.counter(
+                "serving_backlog_skipped_in_flight_total",
+                "Activatable jobs a backlog sweep left alone because a pool "
+                "event or an ACTIVATE of theirs was on its way to a wave",
+            ),
+            job_commands_serialised=g.counter(
+                "serving_job_commands_serialised_total",
+                "Job commands that met an earlier command on the same job "
+                "in their wave: turned away by the kernel's first-per-key "
+                "rule (same intent) or moved to a segment of their own "
+                "(another intent)",
             ),
             drains=g.counter(
                 "serving_drains_total",

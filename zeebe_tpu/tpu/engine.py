@@ -22,6 +22,7 @@ device graph simply covers the compatible subset.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 from typing import Callable, Dict, List, Optional, Tuple
@@ -79,6 +80,22 @@ _MESSAGE_VALUE_TYPES = {
 
 _ERR_NO_RETRIES = 105  # kernel's JOB_NO_RETRIES incident code
 
+
+# job commands that change a job's row (kernel.step: "Commands on ONE row")
+_JOB_ROW_COMMANDS = frozenset(
+    int(i) for i in (
+        JI.ACTIVATE, JI.COMPLETE, JI.FAIL, JI.TIME_OUT, JI.UPDATE_RETRIES,
+        JI.CANCEL,
+    )
+)
+_JI_ACTIVATE = int(JI.ACTIVATE)
+_JI_UPDATE_RETRIES = int(JI.UPDATE_RETRIES)
+# job events the kernel's activation pool judges (kernel.step: m_actpool)
+_JOB_POOL_EVENTS = frozenset(
+    int(i) for i in (
+        JI.CREATED, JI.TIMED_OUT, JI.FAILED, JI.RETRIES_UPDATED,
+    )
+)
 
 PROBE_DEADLINES = 1  # bit0: some job/timer/message deadline is due
 PROBE_JOB_BACKLOG = 2  # bit1: assignable jobs exist AND credits are free
@@ -255,6 +272,9 @@ class PendingWave:
         default_factory=tracing.PhaseClock
     )
     collected: Optional[List[ProcessingResult]] = None  # one-shot cache
+    # keys of the job ACTIVATE commands and pool events this wave steps
+    # (TpuPartitionEngine._assigning forgets them at collect)
+    assigning_stepped: List[int] = dataclasses.field(default_factory=list)
 
     @property
     def host_seconds(self) -> float:
@@ -294,6 +314,25 @@ class TpuPartitionEngine:
         # selfcheck) staging stamps a clock that nothing reads
         self._idle_clock = tracing.PhaseClock()
         self._clock = self._idle_clock
+        # WHO ASSIGNS A JOB. The kernel's activation pool (kernel.step,
+        # m_actpool) owns a job while one of its pool events (CREATED,
+        # TIMED_OUT, FAILED, RETRIES_UPDATED with retries left) is on its
+        # way to a wave: it assigns the job when it steps that event, if a
+        # credit is free. The sweep (device_backlog_activations, and the
+        # backlog scan of add_job_subscription) owns a job only once that
+        # event was stepped WITHOUT an assignment (no credit then): a row
+        # in state CREATED / FAILED / TIMED_OUT with retries left and
+        # nothing on its way. This set is how the sweep tells the two
+        # apart, and the ONE place that is kept: the keys of device jobs
+        # with a pool event or an ACTIVATE command handed out (emitted by a
+        # collected wave, or returned by a sweep) and not yet seen stepped.
+        # A key leaves when the wave that carries its record is collected
+        # (as ACTIVATED, as a rejection, or as an event the pool let pass),
+        # before that wave's own emissions enter. Empty after a restore:
+        # exactly-once does not rest on it (the kernel rejects a late
+        # duplicate and returns its credit), it only spares the pipeline
+        # an ACTIVATE, a rejection and a credit round trip per tick and job.
+        self._assigning: set = set()
         self.num_partitions = num_partitions
         # mesh placement (scheduler/placement.DevicePlan): this engine's
         # state lives COMMITTED on `device`, batches stage onto it, and the
@@ -1157,6 +1196,9 @@ class TpuPartitionEngine:
         for key, slot in sorted(candidates):
             if credits <= 0:
                 break
+            if key in self._assigning:
+                continue  # the pool's, or already on its way (__init__)
+            self._assigning.add(key)
             activated = self._job_value_from_slot(int(slot))
             activated.deadline = self.clock() + sub.timeout
             activated.worker = sub.worker
@@ -1234,7 +1276,15 @@ class TpuPartitionEngine:
         as the host engine's backlog_activations; the kernel only assigns
         jobs when it processes a job event with credits available).
         Credits are consumed up front, exactly like add_job_subscription's
-        backlog scan — the kernel returns them on ACTIVATE rejection."""
+        backlog scan — the kernel returns them on ACTIVATE rejection. A
+        job with a pool event or an ACTIVATE on its way is not this
+        sweep's (``_assigning``, see ``__init__``): it is left alone and
+        no credit is taken for it. The caller appends what this returns;
+        phase ``backlog`` of the cycle it runs in (the tick's)."""
+        with self._clock.phase("backlog"):
+            return self._sweep_job_backlog()
+
+    def _sweep_job_backlog(self) -> List[Record]:
         s = self.state
         valid = np.asarray(s.sub_valid)
         if not valid.any():
@@ -1257,6 +1307,8 @@ class TpuPartitionEngine:
             )[0]
             if int(job_i32[slot, state_mod.JB_STATE]) in activatable
         ]
+        assigning = self._assigning
+        skipped = 0
         out: List[Record] = []
         now = self.clock()
         sub_slots = [int(i) for i in np.nonzero(valid)[0]]
@@ -1267,6 +1319,9 @@ class TpuPartitionEngine:
         # host-oracle parity
         rr = int(np.asarray(s.sub_rr)) % len(sub_slots)
         for key, slot in sorted(candidates):
+            if key in assigning:
+                skipped += 1
+                continue
             type_id = int(job_i32[slot, state_mod.JB_TYPE])
             target = None
             for j in range(len(sub_slots)):
@@ -1278,6 +1333,7 @@ class TpuPartitionEngine:
             if target is None:
                 continue  # no credits for this type; try other jobs' types
             sub_credits[target] -= 1
+            assigning.add(key)
             activated = self._job_value_from_slot(int(slot))
             activated.deadline = now + int(sub_timeouts[target])
             activated.worker = self.interns.string(int(sub_workers[target])) or ""
@@ -1293,7 +1349,10 @@ class TpuPartitionEngine:
                     ),
                 )
             )
+        if skipped:
+            self._clock.count("backlog_skipped_in_flight", skipped)
         if out:  # rr only advances on an assignment, which also appends
+            self._clock.count("backlog_activations", len(out))
             self._mark_device_dirty("sub")
             # placed like the leaves they replace: an uncommitted leaf
             # on the default device gives the step and the due probe a
@@ -1560,6 +1619,7 @@ class TpuPartitionEngine:
         if snap.get("fmt") != stateser.FORMAT_DEVICE_V1:
             raise ValueError("not a device-engine snapshot")
         self._dirty_device = None  # restored engine: next take is full
+        self._assigning.clear()  # what is on its way is not in a snapshot
         # host oracle first: restores the shared repository (workflows) and
         # the control-plane state families
         self._host.restore_state(snap["host"])
@@ -1651,39 +1711,44 @@ class TpuPartitionEngine:
             self._migrate_message_store_to_device()
 
     def _job_value_from_slot(self, slot: int) -> JobRecord:
-        # three ROW reads (sliced on the device, a few hundred bytes over
-        # the wire) — whole-column pulls here cost ~230 MB per job at 2^20
-        # rows, once per backlog activation and per timed-out job
-        s = self.state
-        i32, i64, pay = jax.device_get(
-            (s.job_i32[slot], s.job_i64[slot], s.job_pay[slot])
-        )
-        wf_slot = int(i32[state_mod.JB_WF])
-        elem = int(i32[state_mod.JB_ELEM])
-        workflow = (
-            self.meta.workflows[wf_slot]
-            if self.meta and 0 <= wf_slot < len(self.meta.workflows)
-            else None
-        )
-        return JobRecord(
-            type=self.interns.string(int(i32[state_mod.JB_TYPE])) or "",
-            retries=int(i32[state_mod.JB_RETRIES]),
-            deadline=int(i64[state_mod.JBL_DEADLINE]),
-            worker=self.interns.string(int(i32[state_mod.JB_WORKER])) or "",
-            payload=rb.columns_to_payload(
-                *_host_unpack_payload(pay),
-                self.meta.varspace.names if self.meta else [],
-                self.interns,
-            ),
-            headers=JobHeaders(
-                workflow_instance_key=int(i64[state_mod.JBL_IKEY]),
-                bpmn_process_id=workflow.id if workflow else "",
-                workflow_definition_version=workflow.version if workflow else -1,
-                workflow_key=workflow.key if workflow else -1,
-                activity_id=self.meta.element_id(wf_slot, elem) if self.meta else "",
-                activity_instance_key=int(i64[state_mod.JBL_AIK]),
-            ),
-        )
+        """A device job's record, from three ROW reads (sliced on the
+        device, a few hundred bytes over the wire: whole-column pulls here
+        cost ~230 MB per job at 2^20 rows), once per backlog activation
+        and per timed-out job. Phase ``job_read`` of whichever cycle asks
+        (a tick's sweep, a subscription's backlog scan): the read and the
+        record built on it."""
+        with self._clock.phase("job_read"):
+            self._clock.count("job_row_reads", 1)
+            s = self.state
+            i32, i64, pay = jax.device_get(
+                (s.job_i32[slot], s.job_i64[slot], s.job_pay[slot])
+            )
+            wf_slot = int(i32[state_mod.JB_WF])
+            elem = int(i32[state_mod.JB_ELEM])
+            workflow = (
+                self.meta.workflows[wf_slot]
+                if self.meta and 0 <= wf_slot < len(self.meta.workflows)
+                else None
+            )
+            return JobRecord(
+                type=self.interns.string(int(i32[state_mod.JB_TYPE])) or "",
+                retries=int(i32[state_mod.JB_RETRIES]),
+                deadline=int(i64[state_mod.JBL_DEADLINE]),
+                worker=self.interns.string(int(i32[state_mod.JB_WORKER])) or "",
+                payload=rb.columns_to_payload(
+                    *_host_unpack_payload(pay),
+                    self.meta.varspace.names if self.meta else [],
+                    self.interns,
+                ),
+                headers=JobHeaders(
+                    workflow_instance_key=int(i64[state_mod.JBL_IKEY]),
+                    bpmn_process_id=workflow.id if workflow else "",
+                    workflow_definition_version=workflow.version if workflow else -1,
+                    workflow_key=workflow.key if workflow else -1,
+                    activity_id=self.meta.element_id(wf_slot, elem) if self.meta else "",
+                    activity_instance_key=int(i64[state_mod.JBL_AIK]),
+                ),
+            )
 
     # ------------------------------------------------------------------
     # batch processing
@@ -1721,10 +1786,16 @@ class TpuPartitionEngine:
         ``Record`` ever materializes for it (the columnar plane's
         device-path slice)."""
         clock = tracing.phase_clock(tracing.selected_slices())
+        with self.on_clock(clock), clock.phase("route"):
+            return self._route_wave(records, clock)
+
+    @contextlib.contextmanager
+    def on_clock(self, clock):
+        """The engine's phases and counts land on ``clock`` meanwhile: a
+        wave's in ``dispatch_wave``, a tick's around its sweeps."""
         self._clock = clock
         try:
-            with clock.phase("route"):
-                return self._route_wave(records, clock)
+            yield
         finally:
             self._clock = self._idle_clock
 
@@ -1804,7 +1875,22 @@ class TpuPartitionEngine:
                 int(md.value_type), int(md.record_type), int(md.intent),
             )
 
+        # commands on ONE job row in one device segment (kernel.step,
+        # "Commands on ONE row"): the kernel turns a later row of the same
+        # intent away by itself; a later row of another intent, or a second
+        # UPDATE_RETRIES, is judged against the first one's outcome, so it
+        # starts a new segment (the next step program of this wave, in log
+        # order, on the table the first one left)
+        seg_job_cmds: Dict[int, int] = {}
+        # job keys whose activation record this wave steps: they leave
+        # ``_assigning`` when the wave is collected
+        assigning_stepped = wave.assigning_stepped
+        vt_job = int(ValueType.JOB)
+        rt_command = int(RecordType.COMMAND)
+        rt_event = int(RecordType.EVENT)
+
         def flush() -> None:
+            seg_job_cmds.clear()
             if not pending:
                 return
             push_host_keys()  # device allocations continue after the host's
@@ -1833,6 +1919,11 @@ class TpuPartitionEngine:
                 intent = int(md.intent)
                 pos, key = entry.position, entry.key
             positions.append(pos)
+            if vt == vt_job and (
+                (rt == rt_command and intent == _JI_ACTIVATE)
+                or (rt == rt_event and intent in _JOB_POOL_EVENTS)
+            ):
+                assigning_stepped.append(key)
             device_vt = vt in _DEVICE_VALUE_TYPES or (
                 vt in _MESSAGE_VALUE_TYPES
                 and self.graph is not None
@@ -1878,6 +1969,16 @@ class TpuPartitionEngine:
                 rc = self._wave_route_class(record, False, vt, rt, intent)
                 if pending and rc != pending_route[0]:
                     flush()
+                if (
+                    vt == vt_job and rt == rt_command
+                    and intent in _JOB_ROW_COMMANDS
+                ):
+                    first = seg_job_cmds.get(key)
+                    if first is not None:
+                        clock.count("job_commands_serialised", 1)
+                        if first != intent or intent == _JI_UPDATE_RETRIES:
+                            flush()
+                    seg_job_cmds[key] = intent
                 pending_route[0] = rc
                 pending.append(i)
             else:
@@ -2135,6 +2236,7 @@ class TpuPartitionEngine:
             return wave.collected
         clock = wave.phases
         with clock.phase("decode"):
+            self._assigning.difference_update(wave.assigning_stepped)
             for seg in wave.segments:
                 self._collect_device(seg, clock)
                 for i, res in zip(seg.rows, seg.results):
@@ -2973,15 +3075,27 @@ class TpuPartitionEngine:
         lazy_ok = self.lazy_emissions
         rt_cmd = int(RecordType.COMMAND)
         rt_rej = int(RecordType.COMMAND_REJECTION)
+        rt_event = int(RecordType.EVENT)
+        vt_job = int(ValueType.JOB)
         for r in range(count):
             src = srcs[r]
             res = results[live_rows[src]] if 0 <= src < len(live_rows) else results[0]
-            # cross-partition subscription commands are SENDS, not appended
-            # records — exactly the oracle's out.sends channel
-            # (SubscriptionCommandSender.java:96-108)
             vt = cols["vtype"][r]
             rt = cols["rtype"][r]
             intent = cols["intent"][r]
+            if vt == vt_job and (
+                (rt == rt_cmd and intent == _JI_ACTIVATE)
+                or (
+                    rt == rt_event and intent in _JOB_POOL_EVENTS
+                    and cols["retries"][r] > 0
+                )
+            ):
+                # the pool's assignment, or the event it will judge, is on
+                # its way: not the sweep's until it was stepped (__init__)
+                self._assigning.add(cols["key"][r])
+            # cross-partition subscription commands are SENDS, not appended
+            # records — exactly the oracle's out.sends channel
+            # (SubscriptionCommandSender.java:96-108)
             if rt == rt_cmd and vt == int(
                 ValueType.MESSAGE_SUBSCRIPTION
             ) and intent in (int(MS.OPEN), int(MS.CLOSE)):

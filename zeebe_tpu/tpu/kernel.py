@@ -632,19 +632,49 @@ def step_kernel(
         | (job_state == int(JI.TIMED_OUT))
     )
     completable = (job_state == int(JI.ACTIVATED)) | (job_state == int(JI.TIMED_OUT))
-    jact_ok = m_jactivate & jb_found & activatable
-    jact_rej = m_jactivate & ~(jb_found & activatable)
-    jcomp_ok = m_jcomplete & jb_found & completable
-    jcomp_rej = m_jcomplete & ~(jb_found & completable)
-    jfail_ok = m_jfail & jb_found & (job_state == int(JI.ACTIVATED))
-    jfail_rej = m_jfail & ~(jb_found & (job_state == int(JI.ACTIVATED)))
-    jtime_ok = m_jtimeout & jb_found & (job_state == int(JI.ACTIVATED))
-    jtime_rej = m_jtimeout & ~(jb_found & (job_state == int(JI.ACTIVATED)))
+    # Commands on ONE row in one wave serialise in log order. The lookups
+    # above read the tables as they stood BEFORE the wave, so of several
+    # command rows on one key only the FIRST is judged against them
+    # (``first_cmd``, one comparison for jobs and timers):
+    # - jobs: rows with the same key AND the same intent. Whatever the
+    #   first comes to (accepted: the job leaves the state the intent
+    #   needs, or the table; rejected: the state stays one the intent
+    #   cannot take), the oracle rejects every later one with the reason
+    #   of a job that is not in that state (CANCEL: that does not exist),
+    #   and a rejected ACTIVATE returns its credit below. UPDATE_RETRIES is
+    #   not in the rule (a second one is accepted again), and a later row
+    #   of ANOTHER intent is judged against the first one's outcome: the
+    #   engine keeps both out of the wave (TpuPartitionEngine._route_wave
+    #   starts a new device segment at such a row), so they never meet
+    #   here.
+    # - timers: TRIGGER and CANCEL both pop the timer, so rows with the
+    #   same key whatever their intent. A later TRIGGER is rejected (the
+    #   timer fired once, not once per TRIGGER the tick appended); a later
+    #   CANCEL is the oracle's silent no-op (the engine emits a disarm
+    #   cancel AND a terminate-catch-scan cancel for one armed timer, and
+    #   under the wave drain both land in one step).
+    m_jonce = m_jactivate | m_jcomplete | m_jfail | m_jtimeout | m_jcancel
+    m_ttrigger = timer_cmd & (it == int(TI.TRIGGER))
+    m_tcancel = timer_cmd & (it == int(TI.CANCEL))
+    m_tpop = m_ttrigger | m_tcancel
+    first_cmd = _first_per_key(
+        batch.key * 16 + jnp.where(m_tpop, 15, it).astype(jnp.int64),
+        m_jonce | m_tpop,
+    )
+    jb_first = jb_found & first_cmd
+    jact_ok = m_jactivate & jb_first & activatable
+    jact_rej = m_jactivate & ~(jb_first & activatable)
+    jcomp_ok = m_jcomplete & jb_first & completable
+    jcomp_rej = m_jcomplete & ~(jb_first & completable)
+    jfail_ok = m_jfail & jb_first & (job_state == int(JI.ACTIVATED))
+    jfail_rej = m_jfail & ~(jb_first & (job_state == int(JI.ACTIVATED)))
+    jtime_ok = m_jtimeout & jb_first & (job_state == int(JI.ACTIVATED))
+    jtime_rej = m_jtimeout & ~(jb_first & (job_state == int(JI.ACTIVATED)))
     jret_ok = m_jretries & jb_found & (job_state == int(JI.FAILED)) & (batch.retries > 0)
     jret_badv = m_jretries & jb_found & (job_state == int(JI.FAILED)) & (batch.retries <= 0)
     jret_rej = m_jretries & ~(jb_found & (job_state == int(JI.FAILED)))
-    jcan_ok = m_jcancel & jb_found
-    jcan_rej = m_jcancel & ~jb_found
+    jcan_ok = m_jcancel & jb_first
+    jcan_rej = m_jcancel & ~jb_first
 
     # job events (workflow-side processors + activation pool + incidents)
     jev_created = job_ev & (it == int(JI.CREATED))
@@ -659,16 +689,10 @@ def step_kernel(
 
     # timer commands
     m_tcreate = timer_cmd & (it == int(TI.CREATE))
-    ttrig_ok = timer_cmd & (it == int(TI.TRIGGER)) & tm_found
-    ttrig_rej = timer_cmd & (it == int(TI.TRIGGER)) & ~tm_found
-    # two CANCELs for ONE timer key legitimately share a batch (the engine
-    # emits a disarm cancel AND a terminate-catch-scan cancel for the same
-    # armed timer; under the wave drain both land in one step). The oracle
-    # pops the timer on the first and the second is a silent no-op —
-    # tm_found alone sees the PRE-step table and would emit CANCELED
-    # twice, so only the FIRST cancel row per key stays eligible.
-    m_tcancel = timer_cmd & (it == int(TI.CANCEL))
-    tcan_ok = m_tcancel & tm_found & _first_per_key(batch.key, m_tcancel)
+    tm_first = tm_found & first_cmd
+    ttrig_ok = m_ttrigger & tm_first
+    ttrig_rej = m_ttrigger & ~tm_first
+    tcan_ok = m_tcancel & tm_first
     # timer trigger resumes the catch event when still active
     ttrig_inst = ttrig_ok & aik_found & (
         jnp.where(aik_found, aik_rows[:, EI_STATE], -1) == int(WI.ELEMENT_ACTIVATED)

@@ -33,13 +33,19 @@ from zeebe_tpu.tracing.spans import now_us
 # phase that does not occur in a cycle has zero length and leaves no slice)
 TRACKS = {
     "wave": ("pack", "route", "stage", "h2d", "launch", "blocked",
-             "readback", "decode", "apply"),
+             "readback", "decode", "apply", "push", "job_read"),
     "drain": ("drain_wait", "pump"),
-    "tick": ("tick",),
+    "tick": ("tick", "backlog", "job_read"),
     "raft": ("log_append", "fsync", "commit"),
 }
+# The job path's phases are cut out of the phase they run in: ``push`` out
+# of ``apply``, ``backlog`` out of ``tick``, and ``job_read`` (a device
+# job's row read back and made a record) out of whichever cycle asks for
+# it (a tick's ``backlog`` and deadline sweep; a subscription's backlog scan
+# runs outside every cycle and is not counted).
 # what PendingWave.host_seconds / device_seconds sum: host work of the wave
-# path, and host time waiting for the device (never a device time)
+# path, and host time waiting for the device (never a device time); the job
+# path's phases are in neither
 WAVE_HOST_PHASES = ("route", "stage", "h2d", "launch", "decode")
 WAVE_BLOCKED_PHASES = ("blocked", "readback")
 
