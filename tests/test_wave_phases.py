@@ -37,6 +37,8 @@ JOB_COUNTERS = [
     "serving_job_pushes_total", "serving_backlog_activations_total",
     "serving_backlog_skipped_in_flight_total",
     "serving_job_commands_serialised_total",
+    "serving_backlog_sweeps_total", "serving_backlog_table_scans_total",
+    "serving_backlog_parked_total",
 ]
 COUNTERS = sorted(set(PHASE_COUNTERS.values()) | set(JOB_COUNTERS)) + [
     "serving_host_seconds_total", "serving_device_seconds_total",
@@ -185,8 +187,8 @@ def served_jobs(tmp_path_factory):
     """The same broker serving a process with a job to the client's own
     worker, which has ONE credit for six jobs: the kernel's pool assigns
     while the credit is free, and the jobs that found none wait for the
-    tick's sweep (``backlog``, with a row read each) after the credit's
-    return."""
+    tick's sweep (``backlog``; their values are their events', so no
+    ``job_read``) after the credit's return."""
     from zeebe_tpu.gateway.cluster_client import ClusterClient
     from zeebe_tpu.protocol.enums import ValueType
     from zeebe_tpu.protocol.intents import JobIntent
@@ -251,18 +253,25 @@ class TestJobPathPhases:
         assert served_jobs["activated"] == served_jobs["jobs"]  # each once
         assert d["serving_job_pushes_total"] == served_jobs["activated"]
         assert d["serving_push_seconds_total"] > 0
-        # one credit for six jobs: the sweep handed most of them out, each
-        # with one row read, and nothing was handed out twice
+        # one credit for six jobs: the sweep handed most of them out, from
+        # the engine's own account of them (each entered it once, with the
+        # value its event carried: no row is read, the table is scanned
+        # once, by the subscription, which found nothing known), and
+        # nothing was handed out twice
         swept = d["serving_backlog_activations_total"]
         assert 1 <= swept <= served_jobs["jobs"]
-        assert d["serving_job_row_reads_total"] == swept
+        assert d["serving_backlog_parked_total"] == swept
+        assert 1 <= d["serving_backlog_sweeps_total"] <= d["serving_ticks_total"]
+        assert d["serving_backlog_table_scans_total"] == 1
+        assert d["serving_job_row_reads_total"] == 0
+        assert d["serving_job_read_seconds_total"] == 0
         assert d["serving_backlog_seconds_total"] > 0
-        assert d["serving_job_read_seconds_total"] > 0
         assert d["serving_job_commands_serialised_total"] == 0
 
     def test_self_times_on_their_tracks(self, served_jobs):
-        """``push`` is cut out of ``apply``, ``backlog`` out of ``tick`` and
-        ``job_read`` out of ``backlog``: no slice overlaps another, and a
+        """``push`` is cut out of ``apply`` and ``backlog`` out of ``tick``
+        (``job_read`` out of ``backlog`` only after a restore:
+        tests/test_job_backlog_probe.py): no slice overlaps another, and a
         phase that was cut resumes where the inner one ended."""
         tracer = served_jobs["tracer"]
         waves = [w for w in tracer.waves.snapshot() if w["segments"]]
@@ -277,7 +286,7 @@ class TestJobPathPhases:
             assert set(names) <= set(phases_mod.TRACKS["wave"]), names
         ticks = [c for c in tracer.cycles.snapshot() if c["track"] == "tick"]
         swept = [
-            t for t in ticks if any(s[0] == "job_read" for s in t["phases"])
+            t for t in ticks if any(s[0] == "backlog" for s in t["phases"])
         ]
         assert swept
         for tick in ticks:
@@ -288,7 +297,8 @@ class TestJobPathPhases:
         for tick in swept:
             names = [s[0] for s in tick["phases"]]
             assert names[0] == "tick" and names[-1] == "tick", names
-            assert names[names.index("job_read") - 1] == "backlog", names
+            # the parked jobs' values are the events': no row is read back
+            assert "job_read" not in names, names
         # the counters are the slices' sums
         d = served_jobs["delta"]
         for phase, counter in (

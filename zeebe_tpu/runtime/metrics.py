@@ -358,8 +358,8 @@ def _phase_handles() -> dict:
             ),
             backlog=g.counter(
                 "serving_backlog_seconds_total",
-                "Seconds in the tick's sweep of the device job table for "
-                "jobs no credit was free for (device_backlog_activations)",
+                "Seconds in the tick's sweep of the engine's parked jobs, "
+                "those no credit was free for (device_backlog_activations)",
             ),
             drain_wait=g.counter(
                 "serving_drain_wait_seconds_total",
@@ -410,6 +410,21 @@ def _phase_handles() -> dict:
             backlog_activations=g.counter(
                 "serving_backlog_activations_total",
                 "ACTIVATE commands the tick's backlog sweep appended",
+            ),
+            backlog_sweeps=g.counter(
+                "serving_backlog_sweeps_total",
+                "Backlog sweeps that got past the gate: a job was parked, "
+                "or the parked set was not known (after a restore)",
+            ),
+            backlog_table_scans=g.counter(
+                "serving_backlog_table_scans_total",
+                "Whole scans of the device job table for parked jobs: one "
+                "per restore, by the first sweep or subscription after it",
+            ),
+            backlog_parked=g.counter(
+                "serving_backlog_parked_total",
+                "Jobs that entered the engine's parked set: a collected "
+                "wave stepped their pool event and no credit was free",
             ),
             backlog_skipped_in_flight=g.counter(
                 "serving_backlog_skipped_in_flight_total",

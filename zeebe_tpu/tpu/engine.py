@@ -97,6 +97,20 @@ _JOB_POOL_EVENTS = frozenset(
     )
 )
 
+# the row states a job can be activated from (JB_STATE only ever holds these
+# and ACTIVATED: the kernel keeps state FAILED on UPDATE_RETRIES and bumps
+# only the retries column, so FAILED with retries left covers
+# retries-updated jobs)
+_JOB_ACTIVATABLE_STATES = (
+    int(JI.CREATED), int(JI.TIMED_OUT), int(JI.FAILED),
+)
+# job events after which a job is no longer parked: it is activated, or it
+# left the table (TpuPartitionEngine._job_left)
+_JI_ACTIVATED = int(JI.ACTIVATED)
+_JOB_LEFT_EVENTS = frozenset(
+    int(i) for i in (JI.ACTIVATED, JI.COMPLETED, JI.CANCELED)
+)
+
 PROBE_DEADLINES = 1  # bit0: some job/timer/message deadline is due
 PROBE_JOB_BACKLOG = 2  # bit1: assignable jobs exist AND credits are free
 
@@ -275,6 +289,9 @@ class PendingWave:
     # keys of the job ACTIVATE commands and pool events this wave steps
     # (TpuPartitionEngine._assigning forgets them at collect)
     assigning_stepped: List[int] = dataclasses.field(default_factory=list)
+    # (key, entry) of the job pool events its device segments step: judged
+    # at collect (TpuPartitionEngine._park_unassigned)
+    pool_events: List[tuple] = dataclasses.field(default_factory=list)
 
     @property
     def host_seconds(self) -> float:
@@ -322,17 +339,40 @@ class TpuPartitionEngine:
         # backlog scan of add_job_subscription) owns a job only once that
         # event was stepped WITHOUT an assignment (no credit then): a row
         # in state CREATED / FAILED / TIMED_OUT with retries left and
-        # nothing on its way. This set is how the sweep tells the two
-        # apart, and the ONE place that is kept: the keys of device jobs
-        # with a pool event or an ACTIVATE command handed out (emitted by a
-        # collected wave, or returned by a sweep) and not yet seen stepped.
-        # A key leaves when the wave that carries its record is collected
-        # (as ACTIVATED, as a rejection, or as an event the pool let pass),
-        # before that wave's own emissions enter. Empty after a restore:
-        # exactly-once does not rest on it (the kernel rejects a late
-        # duplicate and returns its credit), it only spares the pipeline
-        # an ACTIVATE, a rejection and a credit round trip per tick and job.
+        # nothing on its way. The engine keeps that account itself, on the
+        # host, in three places and no other:
+        # - ``_assigning`` (in flight): the keys of device jobs with a pool
+        #   event or an ACTIVATE command handed out (emitted by a collected
+        #   wave, or returned by a sweep) and not yet seen stepped. A key
+        #   leaves when the wave that carries its record is collected (as
+        #   ACTIVATED, as a rejection, or as an event the pool let pass),
+        #   before that wave's own emissions enter.
+        # - ``_parked`` (the sweep's): key -> (type id, job value as the
+        #   stepped event carried it). A job enters when a collected wave
+        #   stepped its pool event and neither holds an ACTIVATE of that
+        #   key nor ended the job (collect_wave, _park_unassigned: what the
+        #   host engine keeps as _awaiting_jobs). It leaves when a sweep
+        #   hands out its ACTIVATE, when a collected wave emits its
+        #   ACTIVATED, COMPLETED or CANCELED, when another pool event of
+        #   it is stepped (judged anew), and when its instance is demoted.
+        #   The sweep walks this set and never the device's job table.
+        #   None = not known: after a restore, and on an engine over a
+        #   table it did not step, the first sweep the due probe asks for
+        #   scans the table once (_scan_parked_jobs; such an entry holds
+        #   the row's slot and is read back when handed out) and from then
+        #   on the set is kept. Not in a snapshot.
+        # - ``_ended``: jobs that left the table (COMPLETED, CANCELED,
+        #   demoted) while a record of theirs was still in flight; the pool
+        #   event that arrives late must not park them. Pruned to the keys
+        #   in ``_assigning`` at every collect.
+        # All three are empty or unknown after a restore: exactly-once
+        # does not rest on them (the kernel rejects a late duplicate and
+        # returns its credit), they spare the tick a pull of the job table
+        # and the pipeline an ACTIVATE, a rejection and a credit round
+        # trip per tick and job.
         self._assigning: set = set()
+        self._parked: Optional[Dict[int, tuple]] = None
+        self._ended: set = set()
         self.num_partitions = num_partitions
         # mesh placement (scheduler/placement.DevicePlan): this engine's
         # state lives COMMITTED on `device`, batches stage onto it, and the
@@ -945,11 +985,21 @@ class TpuPartitionEngine:
 
         for sl in job_slots:
             jkey = int(job_i64[sl, state_mod.JBL_KEY])
-            self._host.jobs[jkey] = JobState(
+            self._job_left(jkey, True)
+            job = self._host.jobs[jkey] = JobState(
                 state=int(job_i32[sl, state_mod.JB_STATE]),
                 record=self._job_value_from_slot(sl),
                 deadline=int(job_i64[sl, state_mod.JBL_DEADLINE]),
             )
+            if (
+                job.state in _JOB_ACTIVATABLE_STATES
+                and job.record.retries > 0
+                and jkey not in self._assigning
+            ):
+                # it waited for a credit here: it goes on waiting there
+                self._host._awaiting_jobs.setdefault(
+                    job.record.type, {}
+                )[jkey] = None
 
         # migrate this tree's timers
         from zeebe_tpu.engine.interpreter import TimerState
@@ -1174,47 +1224,30 @@ class TpuPartitionEngine:
         if free < 0 or valid[free]:
             raise RuntimeError("subscription table full")
 
-        # backlog scan over the device job table (host-side; not hot path).
-        # JB_STATE only ever holds CREATED/ACTIVATED/FAILED/TIMED_OUT (the
-        # kernel keeps state FAILED on UPDATE_RETRIES and bumps only the
-        # retries column), so FAILED + retries>0 covers retries-updated jobs
-        activatable = {int(JI.CREATED), int(JI.TIMED_OUT), int(JI.FAILED)}
+        # the backlog of this type, from the engine's account of parked
+        # jobs (the table is scanned only where that is not known). A
+        # subscription arrives outside every cycle: what it counts (a table
+        # scan, row reads) is flushed from a clock of its own
+        from zeebe_tpu.runtime.metrics import observe_phases
+
         type_id = self.interns.intern(sub.job_type)
-        job_i32 = np.asarray(s.job_i32)
-        job_keys = np.asarray(s.job_key)
         backlog: List[Record] = []
         credits = sub.credits
-        candidates = [
-            (int(job_keys[slot]), slot)
-            for slot in np.nonzero(
-                (job_i32[:, state_mod.JB_STATE] != -1)
-                & (job_i32[:, state_mod.JB_TYPE] == type_id)
-                & (job_i32[:, state_mod.JB_RETRIES] > 0)
-            )[0]
-            if int(job_i32[slot, state_mod.JB_STATE]) in activatable
-        ]
-        for key, slot in sorted(candidates):
-            if credits <= 0:
-                break
-            if key in self._assigning:
-                continue  # the pool's, or already on its way (__init__)
-            self._assigning.add(key)
-            activated = self._job_value_from_slot(int(slot))
-            activated.deadline = self.clock() + sub.timeout
-            activated.worker = sub.worker
-            backlog.append(
-                Record(
-                    key=key,
-                    value=activated,
-                    metadata=RecordMetadata(
-                        record_type=RecordType.COMMAND,
-                        value_type=ValueType.JOB,
-                        intent=int(JI.ACTIVATE),
-                        request_stream_id=sub.subscriber_key,
-                    ),
+        now = self.clock()
+        clock = tracing.PhaseClock()
+        with self.on_clock(clock):
+            for key in self._parked_keys({type_id}):
+                if credits <= 0:
+                    break
+                if key in self._assigning:
+                    continue  # the pool's, or already on its way (__init__)
+                backlog.append(
+                    self._activate_parked(
+                        key, now + sub.timeout, sub.worker, sub.subscriber_key
+                    )
                 )
-            )
-            credits -= 1
+                credits -= 1
+        observe_phases(clock)
 
         self._mark_device_dirty("sub")
         self.state = dataclasses.replace(
@@ -1274,17 +1307,23 @@ class TpuPartitionEngine:
         """ACTIVATE commands for device-table jobs that became activatable
         while every subscription was out of credits (same stranding class
         as the host engine's backlog_activations; the kernel only assigns
-        jobs when it processes a job event with credits available).
-        Credits are consumed up front, exactly like add_job_subscription's
-        backlog scan — the kernel returns them on ACTIVATE rejection. A
-        job with a pool event or an ACTIVATE on its way is not this
-        sweep's (``_assigning``, see ``__init__``): it is left alone and
-        no credit is taken for it. The caller appends what this returns;
-        phase ``backlog`` of the cycle it runs in (the tick's)."""
+        jobs when it processes a job event with credits available), found
+        in the engine's own account of them (``_parked``, see
+        ``__init__``), in key order. With nothing parked this returns
+        before it touches the device. Credits are consumed up front,
+        exactly like add_job_subscription's backlog scan — the kernel
+        returns them on ACTIVATE rejection. A job with a pool event or an
+        ACTIVATE on its way is not this sweep's (``_assigning``): it is
+        left alone and no credit is taken for it. The caller appends what
+        this returns; phase ``backlog`` of the cycle it runs in (the
+        tick's)."""
         with self._clock.phase("backlog"):
+            if self._parked is not None and not self._parked:
+                return []
             return self._sweep_job_backlog()
 
     def _sweep_job_backlog(self) -> List[Record]:
+        self._clock.count("backlog_sweeps", 1)
         s = self.state
         valid = np.asarray(s.sub_valid)
         if not valid.any():
@@ -1296,33 +1335,28 @@ class TpuPartitionEngine:
         sub_workers = np.asarray(s.sub_worker)
         if not (sub_credits[valid] > 0).any():
             return []
-        activatable = {int(JI.CREATED), int(JI.TIMED_OUT), int(JI.FAILED)}
-        job_i32 = np.asarray(s.job_i32)
-        job_keys = np.asarray(s.job_key)
-        candidates = [
-            (int(job_keys[slot]), slot)
-            for slot in np.nonzero(
-                (job_i32[:, state_mod.JB_STATE] != -1)
-                & (job_i32[:, state_mod.JB_RETRIES] > 0)
-            )[0]
-            if int(job_i32[slot, state_mod.JB_STATE]) in activatable
-        ]
         assigning = self._assigning
         skipped = 0
         out: List[Record] = []
         now = self.clock()
         sub_slots = [int(i) for i in np.nonzero(valid)[0]]
+        credited = {
+            int(sub_types[i]) for i in sub_slots if sub_credits[i] > 0
+        }
         # the round-robin cursor persists in state.sub_rr across calls
         # (and across snapshot/restore): a fresh `rr = 0` every tick made
         # the first credited subscription win every drain, starving the
         # rest — the oracle's _job_rr_cursor is global, so this is also
         # host-oracle parity
         rr = int(np.asarray(s.sub_rr)) % len(sub_slots)
-        for key, slot in sorted(candidates):
+        left = int(sub_credits[valid].clip(min=0).sum())
+        for key in self._parked_keys(credited):
+            if not left:
+                break
             if key in assigning:
                 skipped += 1
                 continue
-            type_id = int(job_i32[slot, state_mod.JB_TYPE])
+            type_id = self._parked[key][0]
             target = None
             for j in range(len(sub_slots)):
                 cand = sub_slots[(rr + j) % len(sub_slots)]
@@ -1333,20 +1367,13 @@ class TpuPartitionEngine:
             if target is None:
                 continue  # no credits for this type; try other jobs' types
             sub_credits[target] -= 1
-            assigning.add(key)
-            activated = self._job_value_from_slot(int(slot))
-            activated.deadline = now + int(sub_timeouts[target])
-            activated.worker = self.interns.string(int(sub_workers[target])) or ""
+            left -= 1
             out.append(
-                Record(
-                    key=key,
-                    value=activated,
-                    metadata=RecordMetadata(
-                        record_type=RecordType.COMMAND,
-                        value_type=ValueType.JOB,
-                        intent=int(JI.ACTIVATE),
-                        request_stream_id=int(sub_keys[target]),
-                    ),
+                self._activate_parked(
+                    key,
+                    now + int(sub_timeouts[target]),
+                    self.interns.string(int(sub_workers[target])) or "",
+                    int(sub_keys[target]),
                 )
             )
         if skipped:
@@ -1363,6 +1390,72 @@ class TpuPartitionEngine:
                 sub_rr=self._place(jnp.asarray(rr, jnp.int32)),
             )
         return out
+
+    def _parked_keys(self, type_ids) -> List[int]:
+        """The parked jobs of the types ``type_ids``, in key order. Where
+        ``_parked`` is not known (``__init__``) it is scanned from the
+        device's job table first: the one place the table's columns cross
+        to the host for the backlog, once per restore."""
+        if self._parked is None:
+            self._parked = self._scan_parked_jobs()
+        return sorted(
+            k for k, held in self._parked.items() if held[0] in type_ids
+        )
+
+    def _scan_parked_jobs(self) -> Dict[int, tuple]:
+        """Every activatable row with retries left and nothing on its way,
+        as key -> (type id, slot)."""
+        self._clock.count("backlog_table_scans", 1)
+        s = self.state
+        job_i32 = np.asarray(s.job_i32)
+        job_keys = np.asarray(s.job_key)
+        slots = np.nonzero(
+            np.isin(job_i32[:, state_mod.JB_STATE], _JOB_ACTIVATABLE_STATES)
+            & (job_i32[:, state_mod.JB_RETRIES] > 0)
+        )[0]
+        assigning = self._assigning
+        return {
+            key: (type_id, slot)
+            for key, type_id, slot in zip(
+                job_keys[slots].tolist(),
+                job_i32[slots, state_mod.JB_TYPE].tolist(),
+                slots.tolist(),
+            )
+            if key not in assigning
+        }
+
+    def _activate_parked(
+        self, key: int, deadline: int, worker: str, subscriber_key: int
+    ) -> Record:
+        """The ACTIVATE command that hands parked job ``key`` out: it
+        leaves ``_parked`` and is on its way (``_assigning``)."""
+        _type_id, held = self._parked.pop(key)
+        self._assigning.add(key)
+        activated = (
+            held.copy() if isinstance(held, JobRecord)
+            else self._job_value_from_slot(held)
+        )
+        activated.deadline = deadline
+        activated.worker = worker
+        return Record(
+            key=key,
+            value=activated,
+            metadata=RecordMetadata(
+                record_type=RecordType.COMMAND,
+                value_type=ValueType.JOB,
+                intent=int(JI.ACTIVATE),
+                request_stream_id=subscriber_key,
+            ),
+        )
+
+    def _job_left(self, key: int, gone: bool) -> None:
+        """A job is no longer parked: it was activated, or (``gone``) it
+        left the device table, and then a pool event of it that is still
+        in flight must not park it either (``_ended``)."""
+        if self._parked:
+            self._parked.pop(key, None)
+        if gone:
+            self._ended.add(key)
 
     def host_deadline_commands(self) -> List[Record]:
         """The embedded oracle's due commands only (same per-family key
@@ -1619,7 +1712,11 @@ class TpuPartitionEngine:
         if snap.get("fmt") != stateser.FORMAT_DEVICE_V1:
             raise ValueError("not a device-engine snapshot")
         self._dirty_device = None  # restored engine: next take is full
-        self._assigning.clear()  # what is on its way is not in a snapshot
+        # what is on its way is not in a snapshot, nor who waits for a
+        # credit: the first sweep scans the restored table (__init__)
+        self._assigning.clear()
+        self._ended.clear()
+        self._parked = None
         # host oracle first: restores the shared repository (workflows) and
         # the control-plane state families
         self._host.restore_state(snap["host"])
@@ -1713,10 +1810,11 @@ class TpuPartitionEngine:
     def _job_value_from_slot(self, slot: int) -> JobRecord:
         """A device job's record, from three ROW reads (sliced on the
         device, a few hundred bytes over the wire: whole-column pulls here
-        cost ~230 MB per job at 2^20 rows), once per backlog activation
-        and per timed-out job. Phase ``job_read`` of whichever cycle asks
-        (a tick's sweep, a subscription's backlog scan): the read and the
-        record built on it."""
+        cost ~230 MB per job at 2^20 rows), once per timed-out job and
+        per backlog activation of a job that a table scan found (a job a
+        wave parked carries its value). Phase ``job_read`` of whichever
+        cycle asks (a tick's sweeps, a subscription's backlog): the read
+        and the record built on it."""
         with self._clock.phase("job_read"):
             self._clock.count("job_row_reads", 1)
             s = self.state
@@ -1730,6 +1828,10 @@ class TpuPartitionEngine:
                 if self.meta and 0 <= wf_slot < len(self.meta.workflows)
                 else None
             )
+            element = (
+                workflow.elements[elem]
+                if workflow and 0 <= elem < len(workflow.elements) else None
+            )
             return JobRecord(
                 type=self.interns.string(int(i32[state_mod.JB_TYPE])) or "",
                 retries=int(i32[state_mod.JB_RETRIES]),
@@ -1740,6 +1842,8 @@ class TpuPartitionEngine:
                     self.meta.varspace.names if self.meta else [],
                     self.interns,
                 ),
+                # as the job's events carry them (_materialize_value)
+                custom_headers=dict(element.job_headers) if element else {},
                 headers=JobHeaders(
                     workflow_instance_key=int(i64[state_mod.JBL_IKEY]),
                     bpmn_process_id=workflow.id if workflow else "",
@@ -1885,6 +1989,8 @@ class TpuPartitionEngine:
         # job keys whose activation record this wave steps: they leave
         # ``_assigning`` when the wave is collected
         assigning_stepped = wave.assigning_stepped
+        # the pool events among them that a device segment steps
+        pool_events = wave.pool_events
         vt_job = int(ValueType.JOB)
         rt_command = int(RecordType.COMMAND)
         rt_event = int(RecordType.EVENT)
@@ -1919,9 +2025,11 @@ class TpuPartitionEngine:
                 intent = int(md.intent)
                 pos, key = entry.position, entry.key
             positions.append(pos)
-            if vt == vt_job and (
-                (rt == rt_command and intent == _JI_ACTIVATE)
-                or (rt == rt_event and intent in _JOB_POOL_EVENTS)
+            pool_event = (
+                vt == vt_job and rt == rt_event and intent in _JOB_POOL_EVENTS
+            )
+            if pool_event or (
+                vt == vt_job and rt == rt_command and intent == _JI_ACTIVATE
             ):
                 assigning_stepped.append(key)
             device_vt = vt in _DEVICE_VALUE_TYPES or (
@@ -1944,6 +2052,8 @@ class TpuPartitionEngine:
                     flush()
                 pending_route[0] = rc
                 pending.append(i)
+                if pool_event:
+                    pool_events.append((key, entry))
                 continue
             if lazy:
                 record = entry[0].row(entry[1])
@@ -1981,6 +2091,8 @@ class TpuPartitionEngine:
                     seg_job_cmds[key] = intent
                 pending_route[0] = rc
                 pending.append(i)
+                if pool_event:
+                    pool_events.append((key, record))
             else:
                 flush()  # earlier device rows execute BEFORE this record
                 if self._device_keys_dirty:
@@ -2241,6 +2353,7 @@ class TpuPartitionEngine:
                 self._collect_device(seg, clock)
                 for i, res in zip(seg.rows, seg.results):
                     wave.per_record[i] = res
+            self._park_unassigned(wave.pool_events, clock)
             results: List[ProcessingResult] = []
             for pos, res in zip(wave.positions, wave.per_record):
                 if res is None:  # poisoned host record: contained, no output
@@ -2254,6 +2367,30 @@ class TpuPartitionEngine:
         self.last_wave_phases = clock
         wave.collected = results
         return results
+
+    def _park_unassigned(self, pool_events: List[tuple], clock) -> None:
+        """The pool events a collected wave stepped, judged on the host
+        (``__init__``, "WHO ASSIGNS A JOB"): the wave's emissions are in,
+        so a job whose ACTIVATE they hold is in ``_assigning`` and one they
+        ended is in ``_ended``; every other one with retries left was let
+        pass for want of a credit and is the sweep's from now on."""
+        ended = self._ended
+        parked = self._parked
+        if pool_events and parked is not None:
+            assigning = self._assigning
+            entered = 0
+            for key, entry in pool_events:
+                parked.pop(key, None)  # judged anew
+                if key in assigning or key in ended:
+                    continue
+                value = _as_record(entry).value
+                if value.retries > 0:
+                    parked[key] = (self.interns.intern(value.type), value)
+                    entered += 1
+            if entered:
+                clock.count("backlog_parked", entered)
+        if ended:
+            ended.intersection_update(self._assigning)
 
     def _device_key_counters(self) -> Tuple[int, int]:
         """The device's next workflow and job keys (a blocking device→host
@@ -3083,16 +3220,18 @@ class TpuPartitionEngine:
             vt = cols["vtype"][r]
             rt = cols["rtype"][r]
             intent = cols["intent"][r]
-            if vt == vt_job and (
-                (rt == rt_cmd and intent == _JI_ACTIVATE)
-                or (
+            if vt == vt_job:
+                if rt == rt_event and intent in _JOB_LEFT_EVENTS:
+                    # activated, or gone from the table: not parked
+                    self._job_left(cols["key"][r], intent != _JI_ACTIVATED)
+                elif (rt == rt_cmd and intent == _JI_ACTIVATE) or (
                     rt == rt_event and intent in _JOB_POOL_EVENTS
                     and cols["retries"][r] > 0
-                )
-            ):
-                # the pool's assignment, or the event it will judge, is on
-                # its way: not the sweep's until it was stepped (__init__)
-                self._assigning.add(cols["key"][r])
+                ):
+                    # the pool's assignment, or the event it will judge, is
+                    # on its way: not the sweep's until it was stepped
+                    # (__init__)
+                    self._assigning.add(cols["key"][r])
             # cross-partition subscription commands are SENDS, not appended
             # records — exactly the oracle's out.sends channel
             # (SubscriptionCommandSender.java:96-108)

@@ -41,8 +41,8 @@ TRACKS = {
 # The job path's phases are cut out of the phase they run in: ``push`` out
 # of ``apply``, ``backlog`` out of ``tick``, and ``job_read`` (a device
 # job's row read back and made a record) out of whichever cycle asks for
-# it (a tick's ``backlog`` and deadline sweep; a subscription's backlog scan
-# runs outside every cycle and is not counted).
+# it (a tick's ``backlog`` and deadline sweep; a subscription's backlog runs
+# outside every cycle and flushes a clock of its own).
 # what PendingWave.host_seconds / device_seconds sum: host work of the wave
 # path, and host time waiting for the device (never a device time); the job
 # path's phases are in neither
