@@ -33,10 +33,10 @@ def op_census(wave_pow: int = 10) -> dict:
     import jax.numpy as jnp
 
     from zeebe_tpu.tpu import batch as rb, kernel, state as state_mod
-    import bench
+    from zeebe_tpu.testing import graphs
 
     wave = 1 << wave_pow
-    graph, meta = bench.build_graph()
+    graph, meta = graphs.build_graph()
     num_vars = max(graph.num_vars, 8)
     graph = _dc.replace(graph, num_vars=num_vars)
     state = state_mod.make_state(
@@ -148,11 +148,11 @@ def main():
     import jax.numpy as jnp
 
     from zeebe_tpu.tpu import drive, hashmap, state as state_mod
-    import bench
+    from zeebe_tpu.testing import graphs
 
     wave = 1 << args.wave
     capacity = 4 * wave
-    graph, meta = bench.build_graph()
+    graph, meta = graphs.build_graph()
     meta.varspace.column("orderId")
     meta.varspace.column("orderValue")
     meta.varspace.column("paid")
@@ -174,7 +174,7 @@ def main():
         sub_valid=state.sub_valid.at[0].set(True),
     )
     queue = drive.make_queue(8 * wave, num_vars)
-    creates = bench.stage_creates(meta, wave, num_vars, meta.interns)
+    creates = graphs.stage_creates(meta, wave, num_vars, meta.interns)
     enqueue_jit = jax.jit(drive.enqueue, donate_argnums=(0,))
     rebuild_jit = jax.jit(state_mod.rebuild_lookup_state, donate_argnums=(0,))
 

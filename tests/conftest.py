@@ -3,7 +3,7 @@
 Tests run on a virtual 8-device CPU mesh, mirroring the reference's
 strategy of running multi-node tests in one JVM (SURVEY.md §4:
 ClusteringRule runs 3 real brokers in-process). The chip is reached only
-by ``chip_smoke.py`` and ``bench.py``; ``tests/test_chip_compile.py``
+by ``chip_smoke.py`` and ``python3 -m zbench``; ``tests/test_chip_compile.py``
 compiles for a described v5e without one. Importing this file forces the
 CPU, so nothing that must see the chip may import ``tests/``.
 """

@@ -70,9 +70,9 @@ def _on(sharding, tree):
 
 
 def _served_shapes():
-    import bench
+    from zeebe_tpu.testing import graphs
 
-    graph, _meta = bench.build_graph()
+    graph, _meta = graphs.build_graph()
     graph = dataclasses.replace(graph, num_vars=NUM_VARS)
     state = jax.eval_shape(
         lambda: state_mod.make_state(

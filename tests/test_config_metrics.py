@@ -60,9 +60,20 @@ replicationFactor = 3
         assert cfg.network.client_port == 26511
         assert cfg.cluster.initial_contact_points == ["a:1", "b:2"]
 
-    def test_unknown_key_rejected(self):
-        with pytest.raises(ValueError, match="unknown config key"):
-            load_config(toml_text="[network]\nbogusKnob = 1\n", env={})
+    @pytest.mark.parametrize(
+        "toml_text, named",
+        [
+            ("[network]\nbogusKnob = 1\n", r"\[network\] 'bogusKnob'"),
+            # removed with the per-partition drain it selected
+            ("[scheduler]\nenabled = false\n", r"\[scheduler\] 'enabled'"),
+        ],
+    )
+    def test_unknown_key_rejected(self, toml_text, named):
+        with pytest.raises(ValueError, match="unknown config key " + named):
+            load_config(toml_text=toml_text, env={})
+
+    def test_removed_env_override_changes_nothing(self):
+        assert load_config(env={"ZEEBE_SCHEDULER_ENABLED": "0"}) == load_config(env={})
 
     def test_unknown_section_rejected(self):
         with pytest.raises(ValueError, match="unknown config section"):

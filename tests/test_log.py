@@ -61,7 +61,7 @@ def test_recovery_after_reopen(tmp_log_dir):
 
 
 def test_append_after_close_reopens_current_segment(tmp_log_dir):
-    """Regression (BENCH_r05 tail): an append arriving after close() —
+    """Regression: an append arriving after close() —
     broker shutdown racing a late drain — crashed with ``AttributeError:
     'NoneType' object has no attribute 'seek'``. The storage must reopen
     the current segment and keep the address sequence intact."""

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import bench
+from zeebe_tpu.testing import graphs
 from zeebe_tpu.tpu import drive, hashmap, state as state_mod
 
 
@@ -49,7 +49,7 @@ class TestForkJoinChurn:
     def test_sustained_fork_join_waves_complete_exactly(self):
         """12 waves of parallel fork-join instances through the drive
         loop: every root must complete (bench config-3 regression)."""
-        graph, meta = bench.build_graph_forkjoin()
+        graph, meta = graphs.build_graph_forkjoin()
         num_vars = max(graph.num_vars, 8)
         graph = dc.replace(graph, num_vars=num_vars)
         wave = 1 << 7
@@ -72,7 +72,7 @@ class TestForkJoinChurn:
             sub_valid=state.sub_valid.at[0].set(True),
         )
         queue = drive.make_queue(4 * wave * max(2, graph.emit_width), num_vars)
-        creates = bench.stage_creates(meta, wave, num_vars, meta.interns)
+        creates = graphs.stage_creates(meta, wave, num_vars, meta.interns)
         enqueue_jit = jax.jit(drive.enqueue, donate_argnums=(0,))
         rebuild_jit = jax.jit(
             state_mod.rebuild_lookup_state, donate_argnums=(0,)

@@ -562,6 +562,24 @@ class TestClusterMesh:
             placed = plan.assignments()
             assert len(placed) == 2
             assert placed[0] != placed[1], "partitions share a device"
+
+            def device_waves():
+                return [
+                    GLOBAL_REGISTRY.counter(
+                        "serving_device_waves_total", device=str(placed[pid])
+                    ).value
+                    for pid in (0, 1)
+                ]
+
+            def shed():
+                return sum(
+                    GLOBAL_REGISTRY.counter(
+                        "gateway_commands_shed", reason=reason
+                    ).value
+                    for reason in ("CONNECTION_INFLIGHT", "QUEUE_DEPTH")
+                )
+
+            waves0, shed0 = device_waves(), shed()
             client = ClusterClient(
                 [broker.client_address], num_partitions=2,
                 request_timeout_ms=120_000,
@@ -573,6 +591,10 @@ class TestClusterMesh:
             for pid in (0, 1):
                 rsp = client.create_instance("cm", partition_id=pid)
                 assert rsp.value.workflow_instance_key > 0
+            # served over the sockets: every placed device received waves
+            # and nominal load shed nothing
+            assert all(b > a for a, b in zip(waves0, device_waves()))
+            assert shed() == shed0
 
             # leadership flap on partition 1: uninstall + reinstall (raft
             # stays leader; the serving install re-runs) — the plan frees

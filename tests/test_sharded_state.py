@@ -430,14 +430,15 @@ class TestRoutedServingParity:
         staged split landed on ONE lane (flagged single-lane for the
         skew gauge), and every residency entry sits on the
         host/device-agreed hash shard of its instance key (shard_of_key
-        parity ON the routed staging plane)."""
+        parity ON the routed staging plane). A routed wave moves strictly
+        fewer collective bytes than a gathered wave of the same span."""
         from zeebe_tpu.runtime import metrics as metrics_mod
 
         observed = []
         real = metrics_mod.observe_sharded_wave
 
         def spy(split, xb, single_lane=False):
-            observed.append((list(int(x) for x in split), single_lane))
+            observed.append((list(int(x) for x in split), single_lane, xb))
             real(split, xb, single_lane=single_lane)
 
         monkeypatch.setattr(metrics_mod, "observe_sharded_wave", spy)
@@ -464,11 +465,12 @@ class TestRoutedServingParity:
                 np.asarray(shard.shard_of_key(jnp.asarray(keys), 8)),
                 np.asarray([resident[int(k)] for k in keys]),
             )
-        routed = [s for s, single in observed if single and sum(s)]
+        routed = [(s, xb) for s, single, xb in observed if single and sum(s)]
         assert len(routed) == engine.routed_waves > 0
-        for fill in routed:
+        for fill, xb in routed:
             assert len(fill) == 8
             assert sum(1 for v in fill if v) == 1, fill
+            assert 0 < xb < engine._shard_exchange_bytes, xb
 
     @pytest.mark.slow
     def test_routed_vs_gathered_bit_identity_small_spans(self, tmp_path):
@@ -634,12 +636,12 @@ class TestRoutedLoweringCensus:
         actually detects the prim)."""
         import dataclasses as dc
 
-        import bench
+        from zeebe_tpu.testing import graphs
         from jax.sharding import Mesh
         from zeebe_tpu.tpu import batch as rb
         from zeebe_tpu.tpu import state as state_mod
 
-        graph, _meta = bench.build_graph()
+        graph, _meta = graphs.build_graph()
         nv = max(graph.num_vars, 8)
         graph = dc.replace(graph, num_vars=nv)
         mesh = Mesh(np.asarray(jax.devices()), (shard.STATE_AXIS,))
@@ -1003,6 +1005,8 @@ class TestShardedClusterFlap:
             assert client.create_instance(
                 "flap", partition_id=0
             ).value.workflow_instance_key > 0
+            # served over the sockets, the wave took the sharded program
+            assert engine.sharded_waves > 0
 
             # leader flap: uninstall frees the WHOLE span, reinstall
             # re-spans and serving continues on the sharded engine

@@ -166,9 +166,8 @@ class TestSpanCompleteness:
         self, tracer
     ):
         """The pipelined scheduler feed must order DEVICE_COLLECT before
-        APPLY, matching the baseline drain (_collect_chunk) — a span's
-        apply->device_collect gap would otherwise contain the apply work
-        and the two drive modes would contradict each other."""
+        APPLY — a span's apply->device_collect gap would otherwise
+        contain the apply work."""
         from types import SimpleNamespace
 
         from zeebe_tpu.runtime.cluster_broker import PartitionServer

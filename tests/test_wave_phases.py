@@ -86,14 +86,22 @@ def _lead(broker):
     return server
 
 
+def _client(broker):
+    from zeebe_tpu.gateway.cluster_client import ClusterClient
+
+    # the first wave compiles ``kernel.step`` inside its dispatch: over the
+    # client's default 10 s when several test workers share the machine
+    return ClusterClient(
+        [broker.client_address], num_partitions=1, request_timeout_ms=120_000
+    )
+
+
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
     """A small served run: one ClusterBroker leading one partition on the
     device engine, 24 instances over the client socket, every wave, drain,
     tick and group commit on the timeline (rate 1.0). Yields what the run
     left behind."""
-    from zeebe_tpu.gateway.cluster_client import ClusterClient
-
     tracer = tracing.install(tracing.RecordTracer(sample_rate=1.0, seed=25))
     broker = _broker(str(tmp_path_factory.mktemp("phases")))
     staged = []
@@ -117,7 +125,7 @@ def served(tmp_path_factory):
         server.engine._stage = spy_stage
         server.log.flush = spy_flush
         before = counters()
-        client = ClusterClient([broker.client_address], num_partitions=1)
+        client = _client(broker)
         try:
             client.deploy_model(MODEL)
             for i in range(24):
@@ -189,7 +197,6 @@ def served_jobs(tmp_path_factory):
     while the credit is free, and the jobs that found none wait for the
     tick's sweep (``backlog``; their values are their events', so no
     ``job_read``) after the credit's return."""
-    from zeebe_tpu.gateway.cluster_client import ClusterClient
     from zeebe_tpu.protocol.enums import ValueType
     from zeebe_tpu.protocol.intents import JobIntent
 
@@ -200,7 +207,7 @@ def served_jobs(tmp_path_factory):
     try:
         server = _lead(broker)
         before = counters()
-        client = ClusterClient([broker.client_address], num_partitions=1)
+        client = _client(broker)
         try:
             client.deploy_model(ORDER_MODEL)
             worker = client.open_job_worker(

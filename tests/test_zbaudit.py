@@ -417,9 +417,9 @@ class TestRecompileGuard:
         """The serving-latency cliff zbaudit's signature guard exists
         for: waves carry a varying VALID count inside a fixed wave shape,
         so stepping different record counts must not recompile."""
-        import bench
+        from zeebe_tpu.testing import graphs
 
-        graph, _meta = bench.build_graph()
+        graph, _meta = graphs.build_graph()
         num_vars = max(graph.num_vars, 8)
         graph = dataclasses.replace(graph, num_vars=num_vars)
         state = state_mod.make_state(

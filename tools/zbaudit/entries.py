@@ -70,7 +70,7 @@ def build_entries(
         shard,
         state as state_mod,
     )
-    import bench
+    from zeebe_tpu.testing import graphs
 
     cfg = budget.get("audit_config", {})
     wave = 1 << int(cfg.get("wave_pow", 10))
@@ -86,7 +86,7 @@ def build_entries(
             return name in names or AUTOTUNE_PREFIX + "*" in names
         return name in names
 
-    graph, _meta = bench.build_graph()
+    graph, _meta = graphs.build_graph()
     num_vars = max(graph.num_vars, 8)
     graph = dataclasses.replace(graph, num_vars=num_vars)
     state_sds = jax.eval_shape(
@@ -200,7 +200,7 @@ def build_entries(
             # the message-correlation graph (config 4): it has messages,
             # so the cross-partition all_to_all exchange branch traces in
             # and the collective-volume pass models the real ICI hop
-            mgraph, _mmeta = bench.build_graph_c4()
+            mgraph, _mmeta = graphs.build_graph_c4()
             mnv = max(mgraph.num_vars, 8)
             mgraph = dataclasses.replace(mgraph, num_vars=mnv)
             mstate = jax.eval_shape(

@@ -23,8 +23,7 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 DEFAULT_ROOTS = (
-    "zeebe_tpu", "tests", "benchmarks", "tools",
-    "bench.py", "__graft_entry__.py",
+    "zeebe_tpu", "tests", "benchmarks", "tools", "__graft_entry__.py",
 )
 BASELINE_PATH = os.path.join("tools", "zblint_baseline.json")
 DOCS_DIR = "docs"

@@ -1,6 +1,6 @@
 """Where a checkout keeps what it caches between runs.
 
-One rule, used by the broker launcher, ``bench.py`` and ``chip_smoke.py``:
+One rule, used by the broker launcher, ``zbench`` and ``chip_smoke.py``:
 if ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its persistent
 compile cache there and no directory is set in code; otherwise the cache
 goes to ``<checkout>/.jax_cache``. The path is part of JAX's cache key, so

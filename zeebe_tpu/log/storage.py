@@ -214,9 +214,9 @@ class SegmentedLogStorage:
     def _ensure_open(self) -> None:
         """Reopen the current segment after ``close()``. An append can
         legally arrive after the storage was closed (broker shutdown races
-        a late drain; seen as ``AttributeError: 'NoneType' ... 'seek'`` in
-        the BENCH_r05 tail) — reopening is cheap and keeps the address
-        sequence intact."""
+        a late drain; seen as ``AttributeError: 'NoneType' ... 'seek'`` at
+        the end of a benchmark run) — reopening is cheap and keeps the
+        address sequence intact."""
         if self._current_file is None:
             self._current_file = open(self._segment_path(self._current_id), "r+b")
             self._current_file.seek(0, os.SEEK_END)

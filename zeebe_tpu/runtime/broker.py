@@ -658,9 +658,10 @@ class Broker:
         return processed
 
     def _run_until_idle_unscheduled(self, max_iterations: int) -> int:
-        """Per-partition baseline drain (the bench A/B reference): each
-        partition's backlog drains to empty in its own waves before the
-        next partition runs."""
+        """Per-partition baseline drain (the reference the shared-wave log
+        is compared with, ``tests/test_scheduler.py::TestSharedWaveParity``):
+        each partition's backlog drains to empty in its own waves before
+        the next partition runs."""
         from zeebe_tpu.runtime.metrics import observe_wave
 
         processed = 0

@@ -183,7 +183,6 @@ class PartitionServer:
         # from the replicated acked positions on any leader)
         self.exporter_director = None
         self.is_leader = False
-        self._processing_scheduled = False
         self._fetch_attempted = False  # one fetch try per parked record
         # wave-scheduler feed state: parked while a workflow fetch is in
         # flight (take() yields nothing; the other partitions keep
@@ -313,10 +312,9 @@ class PartitionServer:
             partition=self.partition_id, term=term,
             replayed_to=self.next_read_position - 1,
         )
-        if self.broker.wave_scheduler is not None:
-            # this partition's committed tail now feeds the broker's
-            # shared waves (the scheduler is the single place waves form)
-            self.broker.wave_scheduler.register(self)
+        # this partition's committed tail now feeds the broker's shared
+        # waves (the scheduler is the single place waves form)
+        self.broker.wave_scheduler.register(self)
         self._install_exporters()
         self.broker.on_partition_leader(self.partition_id, term)
         if self.partition_id == 0:
@@ -343,8 +341,7 @@ class PartitionServer:
             )
         self.is_leader = False
         self.engine = None
-        if self.broker.wave_scheduler is not None:
-            self.broker.wave_scheduler.unregister(self.partition_id)
+        self.broker.wave_scheduler.unregister(self.partition_id)
         if self.broker.device_plan is not None:
             # leadership left: free the mesh slot so the next install
             # (this partition or another) rebalances onto the emptiest
@@ -461,24 +458,18 @@ class PartitionServer:
 
     # -- the processing loop (StreamProcessorController hot loop) ----------
     def _schedule_processing(self) -> None:
-        if not self.is_leader:
-            return
-        if self.broker.wave_scheduler is not None:
-            # shared-wave mode: one drain job per broker packs ALL leader
-            # partitions' committed tails (zeebe_tpu/scheduler/)
+        if self.is_leader:
+            # one drain job per broker packs ALL leader partitions'
+            # committed tails (zeebe_tpu/scheduler/)
             self.broker._schedule_drain()
-            return
-        if self._processing_scheduled:
-            return
-        self._processing_scheduled = True
-        self.broker.actor_control.run(self._process_committed)
 
     # -- wave-scheduler feed surface (scheduler.PartitionFeed) -------------
     # The scheduler packs this partition's committed tail into SHARED
     # waves: take() consumes at the cursor (one-lock committed_view span),
     # dispatch/collect ride the engine's existing double-buffered wave
     # pipeline, and apply stays per partition — the log is bit-identical
-    # to the per-partition drain (tests/test_scheduler.py pins it).
+    # to the in-process broker's partition-by-partition loop
+    # (tests/test_scheduler.py::TestSharedWaveParity pins it).
     @property
     def device_index(self) -> int:
         """The mesh device this partition's engine is placed on (per-device
@@ -678,124 +669,6 @@ class PartitionServer:
                 self.raft.append(commands), "tick commands", self.partition_id
             )
 
-    # committed records drain into the engine in batches: the device
-    # engine's throughput comes from SIMD batches (one kernel dispatch per
-    # segment, not per record — reference: StreamProcessorController is
-    # per-record, the TPU redesign's whole point is that this isn't)
-    _DRAIN_BATCH = 512
-
-    def _process_committed(self) -> None:
-        self._processing_scheduled = False
-        if not self.is_leader or self.engine is None:
-            return
-        reader = self.log.reader(self.next_read_position)
-        batch: list = []
-        pending = None  # dispatched-but-uncollected wave (device engine)
-        parked = False
-        try:
-            for record in reader.read_committed():
-                if self._needs_workflow_fetch(record):
-                    # a DEPLOYMENT earlier in this very drain may provide
-                    # the workflow: process the collected prefix FIRST,
-                    # then re-check before parking (the per-record loop got
-                    # this ordering for free)
-                    if batch:
-                        prev, pending = pending, self._dispatch_chunk(batch)
-                        batch = []
-                        if prev is not None:
-                            self._collect_chunk(prev)
-                    if pending is not None:
-                        self._collect_chunk(pending)
-                        pending = None
-                    if self._needs_workflow_fetch(record):
-                        # park processing; resume once the workflow arrives
-                        # from the system partition (reference WorkflowCache
-                        # async fetch — EventLifecycleContext.async
-                        # restructured as pause/resume)
-                        self.broker.fetch_workflow(
-                            record.value.bpmn_process_id,
-                            record.value.workflow_key,
-                            on_done=self._schedule_processing_after_fetch,
-                        )
-                        parked = True
-                        break
-                # the one-fetch-per-parked-record latch applies to the record
-                # it parked on, not to later records swept into this drain
-                self._fetch_attempted = False
-                batch.append(record)
-                if len(batch) >= self._DRAIN_BATCH:
-                    # the swap happens BEFORE collecting the previous wave,
-                    # so even if that collect raises, the just-dispatched
-                    # wave (whose records the cursor already passed) is
-                    # still collected by the finally below — never lost
-                    prev, pending = pending, self._dispatch_chunk(batch)
-                    batch = []
-                    if prev is not None:
-                        self._collect_chunk(prev)
-            if batch:
-                prev, pending = pending, self._dispatch_chunk(batch)
-                if prev is not None:
-                    self._collect_chunk(prev)
-        finally:
-            # the in-flight wave's responses/appends must land even when a
-            # dispatch or an earlier collect raises — its records are
-            # already consumed into engine state and will not re-drain
-            if pending is not None:
-                self._collect_chunk(pending)
-        if parked:
-            return
-        self.pump_topic_subscriptions()
-
-    def _dispatch_chunk(self, records: list):
-        """Process one drained chunk. Engines with the wave pipeline
-        (``dispatch_wave``/``collect_wave`` — the device engine) only
-        DISPATCH here and return the pending wave; the caller collects the
-        PREVIOUS wave while the device computes this one (host staging/
-        readback of waves N+1/N−1 overlap device compute of wave N — JAX
-        async dispatch chains the state dependency on device). Synchronous
-        engines process + apply inline and return None.
-
-        NOTE on granularity: the chunk is the retry unit. If the engine
-        raises mid-chunk (an engine bug — processing is non-throwing by
-        contract), the whole chunk reprocesses on the next drain, same
-        at-least-once hazard the per-record loop had, with a chunk-sized
-        blast radius.
-        """
-        from zeebe_tpu.runtime.metrics import observe_wave
-
-        tracer = tracing.TRACER
-        if tracer is not None and tracer.by_position:
-            tracer.stamp_positions(
-                self.partition_id, tracing.positions_of(records),
-                tracing.WAVE_DISPATCH, device=self.device_index,
-            )
-        dispatch = getattr(self.engine, "dispatch_wave", None)
-        if dispatch is None:
-            import time as _time
-
-            t0 = _time.perf_counter()
-            result = self.engine.process_batch(records)
-            self.next_read_position = records[-1].position + 1
-            self._apply_chunk(records, result)
-            observe_wave(
-                len(records), self._DRAIN_BATCH,
-                host_seconds=_time.perf_counter() - t0,
-            )
-            return None
-        wave = dispatch(records)
-        # advance at dispatch: the records are consumed into device state
-        self.next_read_position = records[-1].position + 1
-        return wave
-
-    def _collect_chunk(self, wave) -> None:
-        from zeebe_tpu.runtime.metrics import observe_wave
-
-        host_s, device_s = self.collect(wave)
-        observe_wave(
-            len(wave.records), self._DRAIN_BATCH, host_s, device_s,
-            getattr(wave, "phases", None),
-        )
-
     def _apply_chunk(self, records: list, result, clock=None) -> None:
         tracer = tracing.TRACER
         if tracer is not None and tracer.by_position:
@@ -901,13 +774,6 @@ class PartitionServer:
         if value.bpmn_process_id and repo.latest(value.bpmn_process_id) is not None:
             return False
         return True
-
-    def _schedule_processing_after_fetch(self) -> None:
-        # one attempt per parked record: if the fetch produced nothing the
-        # engine now processes the command and rejects it (workflow not
-        # found), instead of fetch-looping forever
-        self._fetch_attempted = True
-        self._schedule_processing()
 
     def snapshot(self) -> Optional[threading.Thread]:
         """Snapshot-while-serving: a brief fenced CAPTURE here on the
@@ -1027,8 +893,7 @@ class PartitionServer:
             self._snapshot_inflight = False
 
     def close(self) -> None:
-        if self.broker.wave_scheduler is not None:
-            self.broker.wave_scheduler.unregister(self.partition_id)
+        self.broker.wave_scheduler.unregister(self.partition_id)
         if self.broker.device_plan is not None:
             self.broker.device_plan.release(self.partition_id)
         if self.exporter_director is not None:
@@ -1145,8 +1010,7 @@ class ClusterBroker(Actor):
 
         # continuous-batching wave scheduler: ONE drain job per broker
         # packs committed records from ALL leader partitions into shared
-        # device waves (cfg.scheduler.enabled=false restores the
-        # per-partition drain — the bench's A/B baseline)
+        # device waves
         from zeebe_tpu.scheduler import (
             AdmissionConfig,
             AdmissionController,
@@ -1154,17 +1018,13 @@ class ClusterBroker(Actor):
         )
 
         sc = cfg.scheduler
-        self.wave_scheduler = (
-            WaveScheduler(
-                wave_size=sc.wave_size,
-                quantum=sc.quantum or None,
-                backpressure_limit=sc.backpressure_limit or None,
-                # like the raft commit watchdog, the slow-wave threshold
-                # is an operator knob independent of [tracing] enabled
-                slow_wave_ms=cfg.tracing.slow_wave_ms,
-            )
-            if sc.enabled
-            else None
+        self.wave_scheduler = WaveScheduler(
+            wave_size=sc.wave_size,
+            quantum=sc.quantum or None,
+            backpressure_limit=sc.backpressure_limit or None,
+            # like the raft commit watchdog, the slow-wave threshold
+            # is an operator knob independent of [tracing] enabled
+            slow_wave_ms=cfg.tracing.slow_wave_ms,
         )
         self._drain_scheduled = False
         self._drain_scheduled_us = 0  # span-clock stamp of that scheduling
@@ -1530,7 +1390,7 @@ class ClusterBroker(Actor):
 
     def _queue_mesh_send(self, source_partition: int, target_partition: int,
                          record: Record) -> bool:
-        if self.wave_scheduler is None or not self.cfg.mesh.exchange:
+        if not self.cfg.mesh.exchange:
             return False
         plan = self.device_plan
         if plan is None:
@@ -1656,11 +1516,11 @@ class ClusterBroker(Actor):
             int(payload.get("term", 0)),
         )
 
-    # -- shared-wave drain (scheduler mode) ---------------------------------
+    # -- shared-wave drain ---------------------------------------------------
     def _schedule_drain(self) -> None:
         """One drain job per burst of commits, broker-wide: every leader
         partition's committed tail packs into the same shared waves."""
-        if self.wave_scheduler is None or self._drain_scheduled:
+        if self._drain_scheduled:
             return
         self._drain_scheduled = True
         # a drain's first phase, ``drain_wait``, starts here, on whichever
@@ -1670,8 +1530,6 @@ class ClusterBroker(Actor):
 
     def _drain_committed(self) -> None:
         self._drain_scheduled = False
-        if self.wave_scheduler is None:
-            return
         clock = tracing.cycle_clock("drain")
         clock.waited("drain_wait", self._drain_scheduled_us)
         try:
@@ -1693,16 +1551,11 @@ class ClusterBroker(Actor):
         observe_phases(clock, "drains")
 
     def _queue_depth(self) -> int:
-        """Admission probe: committed records awaiting the drain (plus
-        dispatched-but-unapplied, in scheduler mode) plus responses
-        awaiting processing. Reads plain ints cross-thread — approximate
-        by design (a watermark, not an invariant)."""
-        depth = len(self._pending_responses)
-        if self.wave_scheduler is not None:
-            return depth + self.wave_scheduler.backlog()
-        for server in list(self.partitions.values()):
-            depth += server.backlog()
-        return depth
+        """Admission probe: committed records awaiting the drain plus
+        dispatched-but-unapplied ones plus responses awaiting processing.
+        Reads plain ints cross-thread — approximate by design (a
+        watermark, not an invariant)."""
+        return len(self._pending_responses) + self.wave_scheduler.backlog()
 
     def _forget_admission(self, conn_key: int) -> None:
         self.admission.forget_connection(conn_key)
@@ -3075,15 +2928,11 @@ class ClusterBroker(Actor):
         """Timer/TTL sweeps on leader partitions (reference periodic actor
         jobs: JobTimeOutStreamProcessor, MessageTimeToLiveChecker). The
         per-partition probe/sweep logic lives in ``PartitionServer.tick``
-        (see its docstring for the async-probe rationale); in shared-wave
-        mode the scheduler drives it through the registered feeds, so the
-        sweep commands enter the same shared waves as client traffic."""
+        (see its docstring for the async-probe rationale); the scheduler
+        drives it through the registered feeds, so the sweep commands
+        enter the same shared waves as client traffic."""
         self._check_span_commit_stalls()
-        if self.wave_scheduler is not None:
-            self.wave_scheduler.tick()
-            return
-        for server in self.partitions.values():
-            server.tick()
+        self.wave_scheduler.tick()
 
     def _check_span_commit_stalls(self) -> None:
         """Commit-latency watchdog over the SAMPLED spans (the raft actor

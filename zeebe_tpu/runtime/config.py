@@ -109,11 +109,8 @@ class EngineCfg:
 class SchedulerCfg:
     """Cross-partition continuous-batching wave scheduler
     (``zeebe_tpu/scheduler/``): committed records from every leader
-    partition on this broker pack into SHARED device waves. ``enabled =
-    false`` restores the per-partition drain (the A/B baseline the bench
-    compares against)."""
+    partition on this broker pack into SHARED device waves."""
 
-    enabled: bool = True
     wave_size: int = 512  # shared-wave record capacity (= drain chunk)
     # deficit-round-robin quantum: records of credit per feed per packing
     # round (0 = wave_size // 8)
@@ -130,8 +127,7 @@ class MeshCfg:
     (round-robin, rebalanced on leadership change), so the wave
     scheduler's drain dispatches different partitions' wave segments to
     DIFFERENT devices within one scheduling round. ``enabled = false``
-    pins every engine to the default device — the single-device A/B
-    baseline ``bench.py --mesh`` compares against. Only the device engine
+    pins every engine to the default device. Only the device engine
     (``[engine] type = "tpu"``) is placed; the host oracle has no device
     state."""
 
@@ -288,11 +284,6 @@ _ENV_OVERRIDES = {
     ),
     "ZEEBE_ENGINE_TYPE": ("engine", "type", str),
     "ZEEBE_METRICS_PORT": ("metrics", "port", int),
-    "ZEEBE_SCHEDULER_ENABLED": (
-        "scheduler",
-        "enabled",
-        lambda v: v.strip().lower() in ("1", "true", "yes"),
-    ),
     "ZEEBE_ADMISSION_ENABLED": (
         "admission",
         "enabled",

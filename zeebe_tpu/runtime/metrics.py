@@ -245,12 +245,12 @@ def event_count(name: str) -> float:
 
 
 # -- serving-plane wave instrumentation --------------------------------------
-# The pipelined batched drain (runtime/broker.run_until_idle waves,
-# cluster_broker.PartitionServer._process_committed chunks) reports each
+# The pipelined batched drain (runtime/broker.run_until_idle waves, the
+# wave scheduler's shared waves in the cluster broker) reports each
 # dispatched wave here: fill + occupancy gauges localize "the pipeline is
 # running empty" vs "the device is the bottleneck" without a profiler, and
-# the host/device second counters give the time split the serving bench
-# prints. Handles are cached — this sits on the drain hot loop.
+# the host/device second counters give the time split ``wave_host_share``
+# reads. Handles are cached — this sits on the drain hot loop.
 _WAVE_HANDLES: dict = {}
 
 

@@ -1,8 +1,7 @@
 """Partition→device placement over the accelerator mesh + frame exchange.
 
 The serving plane historically ran every leader partition's engine on the
-default device: 8 healthy chips (MULTICHIP_r05) and one of them doing all
-the work. :class:`DevicePlan` is the missing map — it assigns each LEADER
+default device: 8 healthy chips and one of them doing all the work. :class:`DevicePlan` is the missing map — it assigns each LEADER
 partition a device (least-loaded with round-robin tie-break, which
 degenerates to plain round-robin for sequential installs), rebalances on
 leadership change (a step-down releases the slot; the next install lands

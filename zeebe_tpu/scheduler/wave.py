@@ -360,7 +360,7 @@ class WaveScheduler:
                 # this segment's records were consumed but never entered
                 # the engine: rewind its cursor (and every not-yet-
                 # dispatched segment's) so they re-drain — then surface
-                # the failure like the per-partition drain would
+                # the failure
                 count_event(
                     "scheduler_dispatch_rewinds",
                     "Wave segments rewound because their dispatch raised",
@@ -429,8 +429,8 @@ class WaveScheduler:
                 )
                 if tracer is not None and seg.trace is not None:
                     # DEVICE_COLLECT is stamped inside feed.collect()
-                    # between device collect and apply, so stage order
-                    # matches the baseline drain
+                    # between device collect and apply, so a span's stages
+                    # stay in order
                     tracer.waves.segment_collected(
                         seg.trace, host_s, device_s
                     )

@@ -347,8 +347,8 @@ def ensure_autotuned(
 ) -> dict:
     """Idempotent boot hook: install per-family dispatch decisions for the
     running build (cache hit or fresh measurement). Called from
-    ``TpuPartitionEngine.__init__`` and bench.py; cheap no-op off-TPU and
-    on every call after the first."""
+    ``TpuPartitionEngine.__init__`` and the engine factory's boot; cheap
+    no-op off-TPU and on every call after the first."""
     if _state["done"] and not force:
         return pops.get_dispatch()
     if pops.env_override() is not None:

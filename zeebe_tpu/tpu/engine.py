@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import os
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -565,8 +564,9 @@ class TpuPartitionEngine:
         # lazy columnar emissions (ROADMAP item 4, device-path slice):
         # plain follow-up rows flow to the log as lazy refs into the
         # readback batch and re-STAGE from its columns — no Record builds
-        # on the hot path. ZB_LAZY_EMISSIONS=0 restores eager rows (A/B)
-        self.lazy_emissions = os.environ.get("ZB_LAZY_EMISSIONS", "1") != "0"
+        # on the hot path. False gives eager rows: the reference the lazy
+        # log is compared with (tests/test_scheduler.py::TestLazyEmissions)
+        self.lazy_emissions = True
         # bumped by _recompile: workflow SLOTS in older emission batches
         # are stale after a redeploy — the staging fast path checks this
         self._meta_epoch = 0
