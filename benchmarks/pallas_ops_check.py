@@ -196,7 +196,10 @@ def main():
     # bucket layout may differ on collisions (round-synchronous XLA claims
     # vs serial); the tables must be FUNCTIONALLY identical: same key set,
     # same key->val mapping under either lookup
-    check("insert key set", np.sort(np.asarray(t_x.keys)), np.sort(np.asarray(t_p.keys)))
+    def keyset(tb):
+        return np.sort(hashmap.host_keys(tb))
+
+    check("insert key set", keyset(t_x), keyset(t_p))
     fx, sx = hashmap.lookup(t_x, keys, valid)
     fp, sp = hashmap.lookup(t_p, keys, valid)
     check("insert mapping found", fx, fp)
@@ -217,7 +220,7 @@ def main():
     dvalid = jnp.asarray(rng.random(B) < 0.5) & valid
     d_x = hashmap.delete(t_x, keys, dvalid)
     d_p = pops.delete(t_p, keys, dvalid)
-    check("delete key set", np.sort(np.asarray(d_x.keys)), np.sort(np.asarray(d_p.keys)))
+    check("delete key set", keyset(d_x), keyset(d_p))
 
     # lookups after deletes must still traverse tombstones identically
     f2_x, s2_x = hashmap.lookup(d_x, keys, valid)
@@ -269,13 +272,12 @@ def main():
     # JAX_PLATFORMS names the TPU alone; then this raises, by design)
     cpu = jax.devices("cpu")[0]
     snap = {
-        "keys": np.asarray(t_p.keys),  # device_get == the snapshot bytes
+        # int64 on disk, as the engine's snapshot writes a map's keys
+        "keys": hashmap.host_keys(t_p),
         "vals": np.asarray(t_p.vals),
     }
     with jax.default_device(cpu):
-        t_cpu = hashmap.HashTable(
-            jnp.asarray(snap["keys"]), jnp.asarray(snap["vals"])
-        )
+        t_cpu = hashmap.from_host(snap["keys"], snap["vals"])
         f_c, s_c = hashmap.lookup(t_cpu, jnp.asarray(np.asarray(probe_keys)),
                                   jnp.ones((B,), bool))
         check("tpu->cpu restore found", np.asarray(f_x), np.asarray(f_c))

@@ -189,7 +189,9 @@ def _family_call(family, rows, sharding):
     table, lane_table = S((rows, K), jnp.int32), S((rows,), jnp.int32)
     vals, lane_vals = S((B, K), jnp.int32), S((B,), jnp.int32)
     keys = S((B,), jnp.int64)
-    hash_table = hashmap.HashTable(S((rows,), jnp.int64), S((rows,), jnp.int32))
+    hash_table = hashmap.HashTable(
+        S((rows,), jnp.int32), S((rows,), jnp.int32), S((rows,), jnp.int32)
+    )
     calls = {
         "row_update": (pops.masked_row_update, (table, slots, active, vals)),
         "row_max": (pops.masked_row_max, (table, slots, active, vals)),
@@ -197,7 +199,7 @@ def _family_call(family, rows, sharding):
         "lane": (pops.masked_lane_update, (lane_table, slots, active, lane_vals)),
         "vec64": (
             pops.masked_vec64_update,
-            (S((rows,), jnp.int64), slots, active, S((B,), jnp.int64)),
+            (S((rows, 2), jnp.int32), slots, active, S((B,), jnp.int64)),
         ),
         "lookup": (pops.lookup, (hash_table, keys, active)),
         "insert": (pops.insert, (hash_table, keys, lane_vals, active)),

@@ -23,6 +23,7 @@ from zeebe_tpu.protocol.metadata import RecordMetadata
 from zeebe_tpu.protocol.records import JobRecord, Record
 from zeebe_tpu.runtime import Broker, ControlledClock
 from zeebe_tpu.runtime.metrics import event_count
+from zeebe_tpu.tpu import state as state_mod
 from zeebe_tpu.tpu.engine import (
     PROBE_DEADLINES,
     PROBE_JOB_BACKLOG,
@@ -38,7 +39,7 @@ def _engine(n_jobs, sub_specs, job_type="work"):
     s = eng.state
     tid = eng.interns.intern(job_type)
     job_i32 = np.asarray(s.job_i32).copy()
-    job_i64 = np.asarray(s.job_i64).copy()
+    job_i64 = state_mod.host_i64(s.job_i64).copy()
     for i in range(n_jobs):
         job_i32[i] = (int(JI.CREATED), 0, 0, tid, 3, 0)
         job_i64[i] = (100 + 5 * i, -1, -1, -1)
@@ -57,7 +58,8 @@ def _engine(n_jobs, sub_specs, job_type="work"):
         sub_valid[slot] = True
     eng.state = dataclasses.replace(
         s,
-        job_i32=jnp.asarray(job_i32), job_i64=jnp.asarray(job_i64),
+        job_i32=jnp.asarray(job_i32),
+        job_i64=jnp.asarray(state_mod.host_planes(job_i64)),
         sub_key=jnp.asarray(sub_key), sub_type=jnp.asarray(sub_type),
         sub_worker=jnp.asarray(sub_worker),
         sub_credits=jnp.asarray(sub_credits),

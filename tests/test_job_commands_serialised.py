@@ -156,7 +156,7 @@ class Pair:
         assert engine.host_records_by_kind.get((int(ValueType.JOB), -1), 0) == 0
         s = engine.state
         i32 = np.asarray(s.job_i32)
-        i64 = np.asarray(s.job_i64)
+        i64 = state_mod.host_i64(s.job_i64)
         rows = {
             int(i64[slot, state_mod.JBL_KEY]): (
                 int(i32[slot, state_mod.JB_STATE]),

@@ -25,6 +25,7 @@ from zeebe_tpu.runtime.metrics import GLOBAL_REGISTRY, event_count
 from zeebe_tpu.scheduler import PartitionFeed, WaveScheduler
 from zeebe_tpu.scheduler.placement import DevicePlan
 from zeebe_tpu.tpu import shard
+from zeebe_tpu.tpu import hashmap
 from zeebe_tpu.tpu import state as state_mod
 
 SEED = 0x5A4DED
@@ -804,13 +805,11 @@ class TestShardedSnapshotRestore:
             src_a = norm_a if f.name in self.DERIVED else ea.state
             src_b = norm_b if f.name in self.DERIVED else eb.state
             a, b = getattr(src_a, f.name), getattr(src_b, f.name)
-            if hasattr(a, "keys"):
-                np.testing.assert_array_equal(
-                    np.asarray(a.keys), np.asarray(b.keys), err_msg=f.name
-                )
-                np.testing.assert_array_equal(
-                    np.asarray(a.vals), np.asarray(b.vals), err_msg=f.name
-                )
+            if isinstance(a, hashmap.HashTable):
+                for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+                    np.testing.assert_array_equal(
+                        np.asarray(la), np.asarray(lb), err_msg=f.name
+                    )
             else:
                 np.testing.assert_array_equal(
                     np.asarray(a), np.asarray(b), err_msg=f.name

@@ -26,7 +26,9 @@ from zeebe_tpu.models.transform.transformer import transform_model
 from zeebe_tpu.protocol.enums import RecordType, ValueType
 from zeebe_tpu.protocol.intents import WorkflowInstanceIntent as WI
 from zeebe_tpu.tpu import batch as rb
-from zeebe_tpu.tpu import drive, graph as graph_mod, shard, state as state_mod
+from zeebe_tpu.tpu import (
+    drive, graph as graph_mod, hashmap, shard, state as state_mod,
+)
 from zeebe_tpu.tpu.conditions import VT_NUM
 
 N_DEV = 8
@@ -128,7 +130,7 @@ class TestPartitionedKeyspace:
         queue = enq(queue, creates)
         run = shard.build_sharded_drive(mesh, BATCH, synthetic_workers=True)
         state, queue, totals = run(graph, state, queue, jnp.asarray(0, jnp.int64))
-        keys = np.asarray(state.ei_i64[:, :, 0])  # [P, cap] allocated keys
+        keys = state_mod.host_i64(state.ei_i64, 0)  # [P, cap] allocated keys
         for p in range(N_DEV):
             used = keys[p][keys[p] >= 0]
             # every key this shard ever allocated carries its partition id
@@ -276,11 +278,13 @@ class TestShardedDrive:
             for f in dataclasses.fields(ref):
                 a = getattr(ref, f.name)
                 b = getattr(sharded_shard, f.name)
-                if hasattr(a, "keys"):
-                    np.testing.assert_array_equal(
-                        np.asarray(a.keys), np.asarray(b.keys),
-                        err_msg=f"{f.name}.keys partition {p}",
-                    )
+                if isinstance(a, hashmap.HashTable):
+                    for w in ("keys_lo", "keys_hi"):
+                        np.testing.assert_array_equal(
+                            np.asarray(getattr(a, w)),
+                            np.asarray(getattr(b, w)),
+                            err_msg=f"{f.name}.{w} partition {p}",
+                        )
                 else:
                     np.testing.assert_array_equal(
                         np.asarray(a), np.asarray(b),
@@ -469,7 +473,7 @@ class TestShardedMessageCorrelation:
         # every instance waits at its receive task; subs live on their
         # hash partitions
         assert int(np.asarray(totals["completed_roots"]).sum()) == 0
-        live_subs = int((np.asarray(st.msub_ckey) >= 0).sum())
+        live_subs = int((state_mod.host_i64(st.msub_ckey, 0) >= 0).sum())
         assert live_subs == N_DEV * n_per
 
         # publish each key AT its owner partition (hash-consistent staging,
@@ -492,5 +496,5 @@ class TestShardedMessageCorrelation:
         assert not bool(np.asarray(totals["overflow"]).any())
         # every instance correlated and completed; stores drained
         assert int(np.asarray(totals["completed_roots"]).sum()) == N_DEV * n_per
-        assert int((np.asarray(st.msub_ckey) >= 0).sum()) == 0
-        assert int((np.asarray(st.msg_key) >= 0).sum()) == 0
+        assert int((state_mod.host_i64(st.msub_ckey, 0) >= 0).sum()) == 0
+        assert int((state_mod.host_i64(st.msg_key, 0) >= 0).sum()) == 0

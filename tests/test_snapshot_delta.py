@@ -30,6 +30,7 @@ from zeebe_tpu.models.bpmn.builder import Bpmn
 from zeebe_tpu.runtime import Broker, ControlledClock
 from zeebe_tpu.runtime.metrics import event_count
 from zeebe_tpu.testing.chaos import DiskFaults
+from zeebe_tpu.tpu import state as state_mod
 
 
 def order_process_model():
@@ -312,7 +313,7 @@ def _device_engine(n_jobs=4, capacity=256):
     s = eng.state
     tid = eng.interns.intern("work")
     job_i32 = np.asarray(s.job_i32).copy()
-    job_i64 = np.asarray(s.job_i64).copy()
+    job_i64 = state_mod.host_i64(s.job_i64).copy()
     for i in range(n_jobs):
         job_i32[i] = (int(JI.CREATED), 0, 0, tid, 3, 0)
         job_i64[i] = (100 + 5 * i, -1, -1, -1)
@@ -327,7 +328,8 @@ def _device_engine(n_jobs=4, capacity=256):
     sub_credits[0], sub_timeout[0], sub_valid[0] = 10, 1000, True
     eng.state = dataclasses.replace(
         s,
-        job_i32=jnp.asarray(job_i32), job_i64=jnp.asarray(job_i64),
+        job_i32=jnp.asarray(job_i32),
+        job_i64=jnp.asarray(state_mod.host_planes(job_i64)),
         sub_key=jnp.asarray(sub_key), sub_type=jnp.asarray(sub_type),
         sub_worker=jnp.asarray(sub_worker),
         sub_credits=jnp.asarray(sub_credits),
@@ -728,14 +730,14 @@ class TestLargeResidentStateSweep:
         n = self.N - 8  # a few free slots so backlog ticks stay cheap
         rows = np.arange(n)
         ei_i32 = np.asarray(s.ei_i32).copy()
-        ei_i64 = np.asarray(s.ei_i64).copy()
+        ei_i64 = state_mod.host_i64(s.ei_i64).copy()
         ei_i32[:n, 0] = 3            # elem
         ei_i32[:n, 1] = 2            # lifecycle state
         ei_i64[:n, 0] = 100 + 5 * rows   # key
         ei_i64[:n, 1] = 100 + 5 * rows   # workflowInstanceKey
         tid = eng.interns.intern("work")
         job_i32 = np.asarray(s.job_i32).copy()
-        job_i64 = np.asarray(s.job_i64).copy()
+        job_i64 = state_mod.host_i64(s.job_i64).copy()
         job_i32[:n, 0] = int(JI.CREATED)
         job_i32[:n, 3] = tid
         job_i32[:n, 4] = 3
@@ -750,8 +752,10 @@ class TestLargeResidentStateSweep:
         sub_credits[0], sub_timeout[0], sub_valid[0] = 64, 1000, True
         eng.state = dataclasses.replace(
             s,
-            ei_i32=jnp.asarray(ei_i32), ei_i64=jnp.asarray(ei_i64),
-            job_i32=jnp.asarray(job_i32), job_i64=jnp.asarray(job_i64),
+            ei_i32=jnp.asarray(ei_i32),
+            ei_i64=jnp.asarray(state_mod.host_planes(ei_i64)),
+            job_i32=jnp.asarray(job_i32),
+            job_i64=jnp.asarray(state_mod.host_planes(job_i64)),
             sub_key=jnp.asarray(sub_key), sub_type=jnp.asarray(sub_type),
             sub_credits=jnp.asarray(sub_credits),
             sub_timeout=jnp.asarray(sub_timeout),

@@ -579,6 +579,8 @@ class TestTpuEngineRecovery:
         engine2.restore_state(snap)
         import dataclasses as dc
 
+        import jax
+
         from zeebe_tpu.tpu import state as state_mod
 
         # ei/job lookup structures are DERIVED state (re-built from live
@@ -599,12 +601,10 @@ class TestTpuEngineRecovery:
                 a, b = getattr(engine.state, f.name), getattr(engine2.state, f.name)
             if f.name.startswith("sub_"):
                 continue  # transient worker subscriptions drop on restore
-            if hasattr(a, "keys"):
-                np.testing.assert_array_equal(np.asarray(a.keys), np.asarray(b.keys))
-                np.testing.assert_array_equal(np.asarray(a.vals), np.asarray(b.vals))
-            else:
+            # a leaf, or a hash map's three
+            for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
                 np.testing.assert_array_equal(
-                    np.asarray(a), np.asarray(b), err_msg=f.name
+                    np.asarray(la), np.asarray(lb), err_msg=f.name
                 )
         assert engine2.interns._by_id == engine.interns._by_id
         assert engine2.meta.varspace.names == engine.meta.varspace.names

@@ -88,6 +88,104 @@ class TestDtypeFlow:
         assert result.report["dtype"]["ratchet_hints"]
 
 
+class TestTableI64:
+    """``table-i64``: no 64-bit integer array of table size in a served
+    program — as an input, an output, or anywhere inside."""
+
+    TABLE, WAVE, FLOOR = 4096, 64, 1024
+
+    @staticmethod
+    def _hits(fn, *args):
+        from tools.zbaudit.passes import oversized_i64
+
+        return oversized_i64(jax.jit(fn).trace(*args).jaxpr, TestTableI64.FLOOR)
+
+    def _i64(self, *shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int64)
+
+    def _i32(self, *shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def test_int64_table_input_and_output_fire(self):
+        hits = self._hits(lambda t: t, self._i64(self.TABLE))
+        assert any(h.startswith("input: int64") for h in hits)
+        assert any(h.startswith("output: int64") for h in hits)
+
+    def test_table_sized_int64_intermediate_fires(self):
+        """Planes in, planes out, but int64 of the whole table between."""
+        def relaid(planes):
+            keys = jax.lax.bitcast_convert_type(planes, jnp.int64)  # [T]
+            return jax.lax.bitcast_convert_type(keys + 5, jnp.int32)
+
+        hits = self._hits(relaid, self._i32(self.TABLE, 2))
+        assert hits and not any(h.startswith(("input", "output")) for h in hits)
+        assert any("bitcast_convert_type: int64[4096]" in h for h in hits)
+
+    def test_int64_inside_a_loop_body_fires(self):
+        def looped(planes):
+            def body(i, p):
+                k = jax.lax.bitcast_convert_type(p, jnp.int64) + 1
+                return jax.lax.bitcast_convert_type(k, jnp.int32)
+
+            return jax.lax.fori_loop(0, 3, body, planes)
+
+        assert self._hits(looped, self._i32(self.TABLE, 2))
+
+    def test_uint64_counts_too(self):
+        hits = self._hits(
+            lambda t: t + jnp.uint64(1),
+            jax.ShapeDtypeStruct((self.TABLE,), jnp.uint64),
+        )
+        assert any("uint64" in h for h in hits)
+
+    def test_wave_sized_int64_of_gathered_plane_rows_is_quiet(self):
+        """What the step does: plane rows gathered, int64 of the wave."""
+        def step(planes, slots, keys):
+            rows = planes[slots]                                  # [B, 2]
+            k = jax.lax.bitcast_convert_type(rows, jnp.int64)     # [B]
+            new = jax.lax.bitcast_convert_type(k + keys, jnp.int32)
+            return planes.at[slots].set(new), k
+
+        assert self._hits(
+            step, self._i32(self.TABLE, 2), self._i32(self.WAVE),
+            self._i64(self.WAVE),
+        ) == []
+
+    def test_the_served_programs_hold_no_table_sized_int64(self):
+        """The pass itself, on the live tree at the budget's shape: the
+        step (every specialisation on), the tick and the due probe."""
+        result = audit(passes=["table-i64"], entries=[])
+        assert result.findings == []
+        per = result.report["table-i64"]
+        assert per["config"]["capacity"] >= 1 << 20
+        assert per["floor_elements"] == per["config"]["capacity"] // 8
+        for name in ("kernel.step", "kernel.tick", "engine.due_probe"):
+            assert per[name] == 0
+
+    def test_a_table_scan_through_int64_would_fire(self, monkeypatch):
+        """The guard cannot rot: let one whole-column predicate go back
+        through int64 and the pass names every program that runs it."""
+        from zeebe_tpu.tpu import state as state_mod
+
+        def col_neg_via_int64(planes, col=0):
+            words = planes[:, 2 * col : 2 * col + 2]
+            return jax.lax.bitcast_convert_type(words, jnp.int64) < 0
+
+        monkeypatch.setattr(state_mod, "col_neg", col_neg_via_int64)
+        monkeypatch.setattr(kernel, "col_neg", col_neg_via_int64)
+        # jit keeps traces by signature: neither reuse a clean one here
+        # nor leave this one behind
+        jax.clear_caches()
+        try:
+            result = audit(passes=["table-i64"], entries=[])
+        finally:
+            jax.clear_caches()
+        assert rules_of(result.findings) == {"table-i64"}
+        assert {f.message.split(":")[0] for f in result.findings} == {
+            "kernel.step", "kernel.tick", "engine.due_probe"
+        }
+
+
 class TestBoundary:
     def test_undonated_state_arg_fires(self):
         def step(state, now):
@@ -371,14 +469,15 @@ def _timer_state(capacity=64, num_vars=8, due=3):
         capacity=capacity, num_vars=num_vars, job_capacity=capacity,
         sub_capacity=8,
     )
-    timer_key = np.asarray(state.timer_key).copy()
-    timer_due = np.asarray(state.timer_due).copy()
+    timer_key = state_mod.host_i64(state.timer_key, 0).copy()
+    timer_due = state_mod.host_i64(state.timer_due, 0).copy()
     for i in range(due):
         timer_key[i] = 100 + 7 * i
         timer_due[i] = 10
     return dataclasses.replace(
         state,
-        timer_key=jnp.asarray(timer_key), timer_due=jnp.asarray(timer_due),
+        timer_key=jnp.asarray(state_mod.host_planes(timer_key, column=True)),
+        timer_due=jnp.asarray(state_mod.host_planes(timer_due, column=True)),
     )
 
 

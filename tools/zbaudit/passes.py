@@ -224,6 +224,113 @@ def pass_dtype(audited: List[AuditedEntry], budget: dict, report: dict):
     return findings
 
 
+# -- table-i64 ----------------------------------------------------------------
+
+_INT64 = ("int64", "uint64")
+
+
+def oversized_i64(jaxpr, floor: int) -> List[str]:
+    """Every 64-bit integer array of at least ``floor`` elements among a
+    traced program's inputs, outputs and equation results (nested
+    programs included), as ``"<where>: <dtype>[shape]"``."""
+    def big(v):
+        aval = getattr(v, "aval", None)
+        dt = str(getattr(aval, "dtype", ""))
+        if dt not in _INT64:
+            return None
+        size = 1
+        for d in aval.shape:
+            size *= int(d)
+        return f"{dt}{list(aval.shape)}" if size >= floor else None
+
+    inner = getattr(jaxpr, "jaxpr", jaxpr)
+    hits = []
+    for where, vs in (("input", inner.invars), ("output", inner.outvars)):
+        hits += [f"{where}: {b}" for b in map(big, vs) if b]
+    for eqn in iter_eqns(jaxpr):
+        hits += [
+            f"{eqn.primitive.name}: {b}" for b in map(big, eqn.outvars) if b
+        ]
+    return hits
+
+
+def pass_table_i64(audited: List[AuditedEntry], budget: dict, report: dict):
+    """No table-sized 64-bit array in the served programs. A TPU has no
+    64-bit integers: an ``s64`` array is two ``u32`` arrays, split whole
+    where it enters a program and combined whole where it leaves, so one
+    table-sized ``int64`` leaf or intermediate costs passes over the whole
+    table for a wave of a few hundred records (PERF.md, PR 30). The state
+    holds its 64-bit columns as 32-bit planes (``tpu/state.py``); this pass
+    traces ``kernel.step``, ``kernel.tick`` and ``engine.due_probe`` at the
+    served shape (budget ``dtype.table_i64``: the tables dwarf the wave
+    there, which the census config's do not) and fails on any 64-bit
+    integer array with at least as many elements as the smallest table
+    that holds a 64-bit column."""
+    cfg = budget.get("dtype", {}).get("table_i64")
+    if not cfg:
+        return []
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from zeebe_tpu import tpu as _tpu  # noqa: F401  (enables x64)
+    from zeebe_tpu.testing import graphs
+    from zeebe_tpu.tpu import (
+        batch as rb, engine as engine_mod, kernel, state as state_mod,
+    )
+
+    num_vars = int(cfg.get("num_vars", 16))
+    # every specialisation of the step switched on, so that no branch of it
+    # goes untraced: timers, messages, boundaries, joins, multi-instance
+    graph, _meta = graphs.build_graph_c4()
+    graph = dataclasses.replace(
+        graph, num_vars=max(graph.num_vars, num_vars),
+        has_conditions=True, has_parallel_joins=True, has_timers=True,
+        has_mappings=True, has_messages=True, has_boundaries=True,
+        has_multi_instance=True,
+    )
+    num_vars = graph.num_vars
+    state = jax.eval_shape(
+        lambda: state_mod.make_state(
+            capacity=int(cfg["capacity"]), num_vars=num_vars, sub_capacity=16
+        )
+    )
+    batch = jax.eval_shape(lambda: rb.empty(int(cfg["wave"]), num_vars))
+    now = jax.ShapeDtypeStruct((), jnp.int64)
+    floor = min(
+        getattr(state, name).shape[0]
+        for name in state_mod.I64_TABLES + state_mod.I64_COLUMNS
+    )
+    programs = {
+        "kernel.step": lambda: kernel.step_jit.trace(graph, state, batch, now),
+        "kernel.tick": lambda: kernel.tick_jit.trace(state, now),
+        "engine.due_probe": lambda: engine_mod._due_probe_jit.trace(state, now),
+    }
+    by_name = {a.name: a for a in audited}
+    findings: List[Finding] = []
+    per: Dict[str, object] = {"floor_elements": floor, "config": dict(cfg)}
+    for name, trace in programs.items():
+        hits = oversized_i64(trace().jaxpr, floor)
+        per[name] = len(hits)
+        if not hits:
+            continue
+        a = by_name.get(name)
+        message = (
+            f"{len(hits)} table-sized 64-bit integer array(s) (>= {floor} "
+            f"elements) in the program at capacity {cfg['capacity']}, wave "
+            f"{cfg['wave']}: {sorted(set(hits))[:6]} — hold the column as "
+            "32-bit planes (tpu/state.py) and make int64 of the wave's rows"
+        )
+        findings.append(
+            a.finding("table-i64", message) if a is not None else Finding(
+                "table-i64", "zeebe_tpu/tpu/kernel.py", 1, f"{name}: {message}"
+            )
+        )
+    report["table-i64"] = per
+    return findings
+
+
 # -- boundary ----------------------------------------------------------------
 
 _TRANSFER_PRIMS = ("device_put", "copy")
@@ -460,6 +567,7 @@ def pass_census(audited: List[AuditedEntry], budget: dict, report: dict):
 PASSES = {
     "hbm-budget": pass_hbm,
     "dtype-flow": pass_dtype,
+    "table-i64": pass_table_i64,
     "boundary": pass_boundary,
     "collective-volume": pass_collective,
     "signature-guard": pass_signature,
@@ -470,4 +578,5 @@ PASSES = {
 # census_gate shim run the op-census family without paying the full build
 PASS_ENTRIES = {
     "op-census": {"kernel.step"},
+    "table-i64": {"kernel.step", "kernel.tick", "engine.due_probe"},
 }

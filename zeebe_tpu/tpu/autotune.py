@@ -146,7 +146,10 @@ def _time(fn: Callable[[], object]) -> float:
 def _operands(rng: np.random.Generator):
     tbl = jnp.asarray(rng.integers(0, 100, (_T, _K)), jnp.int32)
     t1 = jnp.asarray(rng.integers(0, 100, (_T,)), jnp.int32)
-    t64 = jnp.asarray(rng.integers(0, 1 << 40, (_T,)), jnp.int64)
+    # a 64-bit column as the state holds it: [T, 2] i32 (lo, hi) planes
+    t64 = jnp.asarray(
+        rng.integers(0, 1 << 40, (_T, 1), dtype=np.int64).view(np.int32)
+    )
     slots = jnp.asarray(rng.integers(0, _T, (_B,)), jnp.int32)
     active = jnp.asarray(rng.random(_B) < 0.7)
     vals = jnp.asarray(rng.integers(0, 1000, (_B, _K)), jnp.int32)
@@ -209,7 +212,7 @@ def _benches() -> Dict[str, Callable[[], object]]:
             table, ok = pops.insert(
                 table, keys + i, jnp.arange(_B, dtype=jnp.int32), active
             )
-        return table.keys
+        return table.keys_lo, table.keys_hi
 
     def delete():
         table, _ = hashmap.insert(
@@ -218,7 +221,7 @@ def _benches() -> Dict[str, Callable[[], object]]:
         )
         for i in range(_CHAIN):
             table = pops.delete(table, keys + i, active)
-        return table.keys
+        return table.keys_lo, table.keys_hi
 
     def gather(t=tbl, r=t1):
         # representative phase-B shape: several row reads off 2D tables

@@ -131,13 +131,19 @@ def _due_probe_kernel(
     (the whole job table) every tick for nothing. [M, S] broadcast over
     the small subscription table — still one fused reduction, no host
     round trip."""
+    # 64-bit columns are 32-bit planes (tpu/state.py): compared as words
+    sm = state_mod
     job_due = jnp.any(
         (state.job_state == int(JI.ACTIVATED))
-        & (state.job_deadline >= 0)
-        & (state.job_deadline <= now)
+        & ~sm.col_neg(state.job_i64, sm.JBL_DEADLINE)
+        & sm.col_le(state.job_i64, sm.JBL_DEADLINE, now)
     )
-    timer_due = jnp.any((state.timer_key >= 0) & (state.timer_due <= now))
-    msg_due = jnp.any((state.msg_key >= 0) & (state.msg_deadline <= now))
+    timer_due = jnp.any(
+        ~sm.col_neg(state.timer_key) & sm.col_le(state.timer_due, 0, now)
+    )
+    msg_due = jnp.any(
+        ~sm.col_neg(state.msg_key) & sm.col_le(state.msg_deadline, 0, now)
+    )
     assignable = (
         (state.job_state == int(JI.CREATED))
         | (state.job_state == int(JI.TIMED_OUT))
@@ -718,9 +724,12 @@ class TpuPartitionEngine:
             nid = self.interns.intern(name)
             return (nid << 35) | (cvt << 32) | (cbits & 0xFFFFFFFF)
 
-        msub_ckey = np.asarray(s.msub_ckey).copy()
+        # 64-bit columns: pulled planes viewed as int64, filled, and put
+        # back as planes (state_mod.host_i64 / host_planes)
+        h64, planes = state_mod.host_i64, state_mod.host_planes
+        msub_ckey = h64(s.msub_ckey, 0).copy()
         msub_i32 = np.asarray(s.msub_i32).copy()
-        msub_i64 = np.asarray(s.msub_i64).copy()
+        msub_i64 = h64(s.msub_i64).copy()
         mkeys, mslots = [], []
         free = list(np.nonzero(msub_ckey < 0)[0])
         if len(host.message_subscriptions) > len(free):
@@ -744,10 +753,10 @@ class TpuPartitionEngine:
             mslots.append(slot)
         host.message_subscriptions = []
 
-        msg_key = np.asarray(s.msg_key).copy()
-        msg_ckey = np.asarray(s.msg_ckey).copy()
+        msg_key = h64(s.msg_key, 0).copy()
+        msg_ckey = h64(s.msg_ckey, 0).copy()
         msg_i32 = np.asarray(s.msg_i32).copy()
-        msg_deadline = np.asarray(s.msg_deadline).copy()
+        msg_deadline = h64(s.msg_deadline, 0).copy()
         msg_pay = np.asarray(s.msg_pay).copy()
         gkeys, gslots = [], []
         gfree = list(np.nonzero(msg_key < 0)[0])
@@ -782,13 +791,13 @@ class TpuPartitionEngine:
 
         state = dataclasses.replace(
             self.state,
-            msub_ckey=jnp.asarray(msub_ckey),
+            msub_ckey=jnp.asarray(planes(msub_ckey, column=True)),
             msub_i32=jnp.asarray(msub_i32),
-            msub_i64=jnp.asarray(msub_i64),
-            msg_key=jnp.asarray(msg_key),
-            msg_ckey=jnp.asarray(msg_ckey),
+            msub_i64=jnp.asarray(planes(msub_i64)),
+            msg_key=jnp.asarray(planes(msg_key, column=True)),
+            msg_ckey=jnp.asarray(planes(msg_ckey, column=True)),
             msg_i32=jnp.asarray(msg_i32),
-            msg_deadline=jnp.asarray(msg_deadline),
+            msg_deadline=jnp.asarray(planes(msg_deadline, column=True)),
             msg_pay=jnp.asarray(msg_pay),
         )
         if mkeys:
@@ -821,9 +830,10 @@ class TpuPartitionEngine:
         names = self.meta.varspace.names if self.meta else []
         corr_value = self._corr_string
 
-        msub_ckey = np.asarray(s.msub_ckey)
+        h64 = state_mod.host_i64
+        msub_ckey = h64(s.msub_ckey, 0)
         msub_i32 = np.asarray(s.msub_i32)
-        msub_i64 = np.asarray(s.msub_i64)
+        msub_i64 = h64(s.msub_i64)
         for slot in np.nonzero(msub_ckey >= 0)[0]:
             slot = int(slot)
             self._host.message_subscriptions.append(
@@ -837,9 +847,9 @@ class TpuPartitionEngine:
                     activity_instance_key=int(msub_i64[slot, 1]),
                 )
             )
-        msg_key = np.asarray(s.msg_key)
+        msg_key = h64(s.msg_key, 0)
         msg_i32 = np.asarray(s.msg_i32)
-        msg_deadline = np.asarray(s.msg_deadline)
+        msg_deadline = h64(s.msg_deadline, 0)
         msg_pay = np.asarray(s.msg_pay)
         for slot in np.nonzero(msg_key >= 0)[0]:
             slot = int(slot)
@@ -862,11 +872,11 @@ class TpuPartitionEngine:
             s,
             msub_ckey=jnp.full_like(s.msub_ckey, -1),
             msub_i64=jnp.full_like(s.msub_i64, -1),
-            msub_map=hm.make(s.msub_map.keys.shape[0]),
+            msub_map=hm.make(s.msub_map.size),
             msg_key=jnp.full_like(s.msg_key, -1),
             msg_ckey=jnp.full_like(s.msg_ckey, -1),
             msg_deadline=jnp.full_like(s.msg_deadline, -1),
-            msg_map=hm.make(s.msg_map.keys.shape[0]),
+            msg_map=hm.make(s.msg_map.size),
         ))
 
     # -- instance demotion: rare imperative ops take the host path ---------
@@ -875,7 +885,7 @@ class TpuPartitionEngine:
         when absent (completed, unknown, or host-side)."""
         if key < 0:
             return -1
-        keys = np.asarray(self.state.ei_i64[:, 0])
+        keys = state_mod.host_i64(self.state.ei_i64, state_mod.EIL_KEY)
         states = np.asarray(self.state.ei_i32[:, state_mod.EI_STATE])
         hits = np.nonzero((keys == key) & (states != -1))[0]
         return int(hits[0]) if len(hits) else -1
@@ -904,8 +914,9 @@ class TpuPartitionEngine:
         self._resident.pop(int(root_key), None)
         self._residency_invalid[int(root_key)] = self._dispatch_seq
         s = self.state
+        h64 = state_mod.host_i64  # 64-bit columns: planes viewed as int64
         ei_i32 = np.asarray(s.ei_i32)
-        ei_i64 = np.asarray(s.ei_i64)
+        ei_i64 = h64(s.ei_i64)
         ei_pay = np.asarray(s.ei_pay)
         states = ei_i32[:, state_mod.EI_STATE]
         live = states != -1
@@ -974,7 +985,7 @@ class TpuPartitionEngine:
         tree_keys = {int(ei_i64[sl, 0]) for sl in tree}
 
         # migrate this tree's jobs
-        job_i64 = np.asarray(s.job_i64)
+        job_i64 = h64(s.job_i64)
         job_i32 = np.asarray(s.job_i32)
         job_slots = [
             int(sl)
@@ -1004,8 +1015,9 @@ class TpuPartitionEngine:
         # migrate this tree's timers
         from zeebe_tpu.engine.interpreter import TimerState
 
-        timer_keys = np.asarray(s.timer_key)
-        timer_aik = np.asarray(s.timer_aik)
+        timer_keys = h64(s.timer_key, 0)
+        timer_aik = h64(s.timer_aik, 0)
+        timer_due = h64(s.timer_due, 0)
         timer_slots = [
             int(sl)
             for sl in np.nonzero(timer_keys >= 0)[0]
@@ -1015,14 +1027,14 @@ class TpuPartitionEngine:
             tkey = int(timer_keys[sl])
             wf_slot = int(np.asarray(s.timer_wf)[sl])
             self._host.timers[tkey] = TimerState(
-                due_date=int(np.asarray(s.timer_due)[sl]),
+                due_date=int(timer_due[sl]),
                 activity_instance_key=int(timer_aik[sl]),
                 record=TimerRecord(
                     activity_instance_key=int(timer_aik[sl]),
                     workflow_instance_key=int(
-                        np.asarray(s.timer_instance_key)[sl]
+                        h64(s.timer_instance_key[sl], 0)
                     ),
-                    due_date=int(np.asarray(s.timer_due)[sl]),
+                    due_date=int(timer_due[sl]),
                     handler_element_id=self.meta.element_id(
                         wf_slot, int(np.asarray(s.timer_elem)[sl])
                     ) if self.meta else "",
@@ -1035,7 +1047,7 @@ class TpuPartitionEngine:
         # per-flow arrival map carries the merged payload for every arrived
         # position — exact for termination (which discards it) and for
         # joins that complete after demotion with the merged document.
-        join_keys = np.asarray(s.join_key)
+        join_keys = h64(s.join_key, 0)
         join_arr = np.asarray(s.join_arrived)
         join_pay_np = np.asarray(s.join_pay)
         join_slots = [
@@ -1067,7 +1079,8 @@ class TpuPartitionEngine:
         new_state = dataclasses.replace(
             s,
             ei_i32=s.ei_i32.at[ei_idx, state_mod.EI_STATE].set(-1),
-            ei_i64=s.ei_i64.at[ei_idx, 0].set(-1),
+            # key column cleared: both its words
+            ei_i64=s.ei_i64.at[ei_idx, 0:2].set(-1),
             ei_map=hashmap.delete(
                 s.ei_map, ei_del_keys, jnp.ones(ei_del_keys.shape, bool)
             ),
@@ -1081,7 +1094,9 @@ class TpuPartitionEngine:
             new_state = dataclasses.replace(
                 new_state,
                 job_i32=new_state.job_i32.at[j_idx, state_mod.JB_STATE].set(-1),
-                job_i64=new_state.job_i64.at[j_idx, state_mod.JBL_KEY].set(-1),
+                job_i64=new_state.job_i64.at[
+                    j_idx, 2 * state_mod.JBL_KEY : 2 * state_mod.JBL_KEY + 2
+                ].set(-1),
                 job_map=hashmap.delete(
                     new_state.job_map, j_keys, jnp.ones(j_keys.shape, bool)
                 ),
@@ -1408,7 +1423,7 @@ class TpuPartitionEngine:
         self._clock.count("backlog_table_scans", 1)
         s = self.state
         job_i32 = np.asarray(s.job_i32)
-        job_keys = np.asarray(s.job_key)
+        job_keys = state_mod.host_i64(s.job_i64, state_mod.JBL_KEY)
         slots = np.nonzero(
             np.isin(job_i32[:, state_mod.JB_STATE], _JOB_ACTIVATABLE_STATES)
             & (job_i32[:, state_mod.JB_RETRIES] > 0)
@@ -1493,9 +1508,10 @@ class TpuPartitionEngine:
     def _device_job_deadlines(self) -> List[Record]:
         now = self.clock()
         s = self.state
-        keys = np.asarray(s.job_key)
+        job_i64 = state_mod.host_i64(s.job_i64)
+        keys = job_i64[:, state_mod.JBL_KEY]
         states = np.asarray(s.job_state)
-        deadlines = np.asarray(s.job_deadline)
+        deadlines = job_i64[:, state_mod.JBL_DEADLINE]
         due = (states == int(JI.ACTIVATED)) & (deadlines >= 0) & (deadlines <= now)
         out = []
         for slot in np.nonzero(due)[0][np.argsort(keys[np.nonzero(due)[0]])]:
@@ -1524,15 +1540,16 @@ class TpuPartitionEngine:
     def _device_timer_deadlines(self) -> List[Record]:
         now = self.clock()
         s = self.state
-        keys = np.asarray(s.timer_key)
-        due = (keys >= 0) & (np.asarray(s.timer_due) <= now)
+        h64 = state_mod.host_i64
+        keys = h64(s.timer_key, 0)
+        dues = h64(s.timer_due, 0)
+        due = (keys >= 0) & (dues <= now)
         slots = np.nonzero(due)[0]
         if not len(slots):
             return []
         # one pull per column, not one per due timer
-        instance_keys = np.asarray(s.timer_instance_key)
-        aiks = np.asarray(s.timer_aik)
-        dues = np.asarray(s.timer_due)
+        instance_keys = h64(s.timer_instance_key, 0)
+        aiks = h64(s.timer_aik, 0)
         wfs = np.asarray(s.timer_wf)
         elems = np.asarray(s.timer_elem)
         out = []
@@ -1570,8 +1587,8 @@ class TpuPartitionEngine:
 
         now = self.clock()
         s = self.state
-        keys = np.asarray(s.msg_key)
-        due = (keys >= 0) & (np.asarray(s.msg_deadline) <= now)
+        keys = state_mod.host_i64(s.msg_key, 0)
+        due = (keys >= 0) & (state_mod.host_i64(s.msg_deadline, 0) <= now)
         slots = np.nonzero(due)[0]
         names = self.meta.varspace.names if self.meta else []
         msg_i32 = np.asarray(s.msg_i32)
@@ -1661,6 +1678,7 @@ class TpuPartitionEngine:
 
     def snapshot_state(self, families=None) -> dict:
         from zeebe_tpu.log import stateser
+        from zeebe_tpu.tpu import hashmap
 
         dirty_dev = None
         if families is not None:
@@ -1668,24 +1686,32 @@ class TpuPartitionEngine:
         arrays: Dict[str, Optional[np.ndarray]] = {}
         read: List[str] = []
 
-        def put(name: str, value, skip: bool) -> None:
+        def put(name: str, value, skip: bool, to_host=np.asarray) -> None:
             if skip:
                 # clean family: the caller reuses the previous manifest's
                 # segment — NO device→host transfer, no encode, no hash
                 arrays[name] = None
             else:
-                arrays[name] = np.asarray(value)
+                arrays[name] = to_host(value)
                 read.append(name)
 
+        # the format on disk is older than the plane layout and does not
+        # change with it: 64-bit tables, columns and hash-map keys are
+        # written as the int64 arrays they always were (host views of the
+        # pulled planes; restore_state converts back)
         for f in dataclasses.fields(self.state):
             skip = (
                 dirty_dev is not None
                 and stateser.device_array_family(f.name) not in dirty_dev
             )
             v = getattr(self.state, f.name)
-            if hasattr(v, "keys") and hasattr(v, "vals"):  # HashTable
-                put(f.name + ".keys", v.keys, skip)
+            if isinstance(v, hashmap.HashTable):
+                put(f.name + ".keys", v, skip, hashmap.host_keys)
                 put(f.name + ".vals", v.vals, skip)
+            elif f.name in state_mod.I64_TABLES:
+                put(f.name, v, skip, state_mod.host_i64)
+            elif f.name in state_mod.I64_COLUMNS:
+                put(f.name, v, skip, lambda p: state_mod.host_i64(p, 0))
             else:
                 put(f.name, v, skip)
         self.last_snapshot_readback = read
@@ -1746,9 +1772,17 @@ class TpuPartitionEngine:
         pre_round4_arrays = False
         for f in dataclasses.fields(self.state):
             if f.name + ".keys" in arrays:
-                kwargs[f.name] = hashmap.HashTable(
-                    keys=jnp.asarray(arrays[f.name + ".keys"]),
-                    vals=jnp.asarray(arrays[f.name + ".vals"]),
+                kwargs[f.name] = hashmap.from_host(
+                    arrays[f.name + ".keys"], arrays[f.name + ".vals"]
+                )
+            elif f.name in arrays and f.name in state_mod.I64_TABLES:
+                # int64 on disk, planes on the device
+                kwargs[f.name] = jnp.asarray(
+                    state_mod.host_planes(arrays[f.name])
+                )
+            elif f.name in arrays and f.name in state_mod.I64_COLUMNS:
+                kwargs[f.name] = jnp.asarray(
+                    state_mod.host_planes(arrays[f.name], column=True)
                 )
             elif f.name == "ei_i32" and arrays[f.name].shape[1] == 5:
                 # pre-round-4 snapshot: pad the pending-boundary column
@@ -1821,6 +1855,7 @@ class TpuPartitionEngine:
             i32, i64, pay = jax.device_get(
                 (s.job_i32[slot], s.job_i64[slot], s.job_pay[slot])
             )
+            i64 = state_mod.host_i64(i64)
             wf_slot = int(i32[state_mod.JB_WF])
             elem = int(i32[state_mod.JB_ELEM])
             workflow = (
@@ -2124,14 +2159,13 @@ class TpuPartitionEngine:
                     # the device job table by job key then.
                     owner = record.value.headers.workflow_instance_key
                     if owner < 0 and record.key >= 0:
+                        job_i64 = state_mod.host_i64(self.state.job_i64)
                         slots = np.nonzero(
-                            np.asarray(self.state.job_key) == record.key
+                            job_i64[:, state_mod.JBL_KEY] == record.key
                         )[0]
                         if len(slots):
                             owner = int(
-                                np.asarray(self.state.job_instance_key)[
-                                    int(slots[0])
-                                ]
+                                job_i64[int(slots[0]), state_mod.JBL_IKEY]
                             )
                     self._demote_instance(owner)
                 deployed_before = len(self.repository.by_key)
@@ -2840,7 +2874,7 @@ class TpuPartitionEngine:
         timer creates are rare control records)."""
         if key < 0:
             return -1
-        keys = np.asarray(self.state.ei_i64[:, 0])
+        keys = state_mod.host_i64(self.state.ei_i64, state_mod.EIL_KEY)
         hits = np.nonzero(keys == key)[0]
         if not len(hits):
             return -1

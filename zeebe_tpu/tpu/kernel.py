@@ -57,6 +57,7 @@ from zeebe_tpu.tpu.conditions import (
 from zeebe_tpu.tpu.graph import DeviceGraph
 from zeebe_tpu.tpu.state import (
     EngineState,
+    col_eq, col_hi, col_le, col_lo, col_neg,
     corr_composite,
     pack_payload, unpack_payload,
     EI_ELEM, EI_STATE, EI_WF, EI_SCOPE, EI_TOKENS, EI_PENDING_BD,
@@ -81,6 +82,14 @@ VT_WISUB = int(ValueType.WORKFLOW_INSTANCE_SUBSCRIPTION)
 _KEY_STEP = keyspace.STEP_SIZE
 
 
+def _later(n):
+    """[n, n] bool: column j comes after row i (the strict upper triangle;
+    its transpose is "comes before"). From an i32 iota: ``jnp.triu`` builds
+    its own from an int64 one under x64, wave x wave elements of it."""
+    i = jnp.arange(n, dtype=jnp.int32)
+    return i[:, None] < i[None, :]
+
+
 def _mxu_cumsum_i32(x):
     """Inclusive scan of small-int vectors via triangular matmuls on the
     MXU. XLA's TPU cumsum lowering (reduce-window) serializes badly at
@@ -96,8 +105,8 @@ def _mxu_cumsum_i32(x):
     rows = n // tile
     hi = jax.lax.Precision.HIGHEST
     xf = x.astype(jnp.float32).reshape(rows, tile)
-    upper = jnp.triu(jnp.ones((tile, tile), jnp.float32))
-    lower_strict = jnp.tril(jnp.ones((rows, rows), jnp.float32), k=-1)
+    upper = (~_later(tile).T).astype(jnp.float32)  # j >= i
+    lower_strict = _later(rows).T.astype(jnp.float32)  # j < i
     within = jnp.matmul(xf, upper, precision=hi)  # [rows, tile] row-wise scan
     row_tot = within[:, -1]                       # [rows]
     row_off = jnp.matmul(lower_strict, row_tot, precision=hi)
@@ -135,7 +144,7 @@ def _last_writer(slots, mask, size):
         later_same = (
             (slots[:, None] == slots[None, :])
             & mask[None, :]
-            & jnp.triu(jnp.ones((n, n), bool), 1)
+            & _later(n)
         )
         return mask & ~jnp.any(later_same, axis=1)
     rank = jnp.arange(n, dtype=jnp.int32)
@@ -158,7 +167,7 @@ def _first_per_key(keys, mask):
         earlier_same = (
             (keys[:, None] == keys[None, :])
             & mask[None, :]
-            & jnp.tril(jnp.ones((b, b), bool), -1)
+            & _later(b).T
         )
         return ~jnp.any(earlier_same, axis=1)
     idx = jnp.arange(b, dtype=jnp.int64)
@@ -171,18 +180,25 @@ def _first_per_key(keys, mask):
     return jnp.zeros((b,), bool).at[idx_sorted].set(first_sorted)
 
 
+def _col64(rows, col=0):
+    """One 64-bit column of gathered plane rows: ``[B, 2C]`` i32 → ``[B]``
+    i64. The state's tables hold 64-bit columns as 32-bit planes
+    (``tpu/state.py``); int64 is made here, of a wave's rows only."""
+    return pops.planes_to_i64(rows[:, 2 * col : 2 * col + 2])[:, 0]
+
+
 def _indexed_lookup_multi(lookups):
     """N parallel key → (found, slot) resolutions via the direct-mapped
     indexes with hashmap fallback; both paths verify against the table's
     own key column, so stale index/map entries (deleted rows, reused
     slots) resolve to not-found without any per-round index maintenance.
 
-    Each lookup is ``(index, key_col, fallback_map, keys, want, cap)``;
-    returns ``[(found, slot), ...]`` in input order. The index probes and
+    Each lookup is ``(index, key_col, fallback_map, keys, want, cap)``,
+    ``key_col`` a ``(plane table, 64-bit column)`` pair; returns
+    ``[(found, slot), ...]`` in input order. The index probes and
     the two key-column verifies run through ``pops.fused_gather_rows``,
-    so the N lookups share one gather per stage (the indexes are all i32,
-    the key columns all i64 — each stage's tables concatenate) instead of
-    issuing 3 gathers apiece."""
+    so the N lookups share one gather per stage instead of issuing 3
+    gathers apiece."""
     # keys are stride-5 (keyspace: one residue class per entity family),
     # so indexing on key // 5 packs them densely — the collision-free
     # window is icap * 5 consecutive keys, not icap (a parallel-split /
@@ -201,10 +217,15 @@ def _indexed_lookup_multi(lookups):
     cand_clips = [
         jnp.clip(cand, 0, lk[5] - 1) for cand, lk in zip(cands, lookups)
     ]
-    key_cols = [kc for _i, kc, *_ in lookups]
-    kc_hit = pops.fused_gather_rows(
-        key_cols, [pops.GatherOp(i, cc) for i, cc in enumerate(cand_clips)]
-    )
+    key_tables = [kc[0] for _i, kc, *_ in lookups]
+
+    def key_reads(slots):
+        rows = pops.fused_gather_rows(
+            key_tables, [pops.GatherOp(i, sl) for i, sl in enumerate(slots)]
+        )
+        return [_col64(r, lk[1][1]) for r, lk in zip(rows, lookups)]
+
+    kc_hit = key_reads(cand_clips)
     hits = [
         lk[4] & (cand >= 0) & (kc == lk[3])
         for lk, cand, kc in zip(lookups, cands, kc_hit)
@@ -221,9 +242,7 @@ def _indexed_lookup_multi(lookups):
         jnp.clip(fb_slot, 0, lk[5] - 1)
         for (_f, fb_slot), lk in zip(fbs, lookups)
     ]
-    kc_fb = pops.fused_gather_rows(
-        key_cols, [pops.GatherOp(i, fc) for i, fc in enumerate(fb_clips)]
-    )
+    kc_fb = key_reads(fb_clips)
     out = []
     for lk, hit, miss, (fb_found, _s), fb_clip, kc, cand_clip in zip(
         lookups, hits, misses, fbs, fb_clips, kc_fb, cand_clips
@@ -353,7 +372,7 @@ def step_kernel(
     v = state.num_vars
     e_w = graph.emit_width
     n_cap = state.capacity
-    m_cap = state.job_key.shape[0]
+    m_cap = state.job_i32.shape[0]
     j_cap = state.join_key.shape[0]
     t_cap = state.timer_key.shape[0]
     s_cap = state.sub_key.shape[0]
@@ -432,8 +451,9 @@ def step_kernel(
     )
     with jax.named_scope("zb_lookups"):
         (ei3_found, ei3_slot), (jb_found, jb_slot) = _indexed_lookup_multi([
-            (state.ei_index, state.ei_key, state.ei_map, keys3, want3, n_cap),
-            (state.job_index, state.job_key, state.job_map,
+            (state.ei_index, (state.ei_i64, EIL_KEY), state.ei_map,
+             keys3, want3, n_cap),
+            (state.job_index, (state.job_i64, JBL_KEY), state.job_map,
              batch.key, job_cmd & (batch.key >= 0), m_cap),
         ])
     ei_found, ei_slot = ei3_found[:b], ei3_slot[:b]
@@ -512,9 +532,15 @@ def step_kernel(
      sc_pay_rows, aik_pay_rows, ei_pay_rows,
      jb_i32_rows, jb_i64_rows, jb_pay_rows,
      tm_elem_rows, tm_wf_rows) = g[:13]
+    # the 64-bit tables gave plane rows: int64 of the wave's rows only
+    aik_i64_rows = pops.planes_to_i64(aik_i64_rows)
+    ei_i64_rows = pops.planes_to_i64(ei_i64_rows)
+    jb_i64_rows = pops.planes_to_i64(jb_i64_rows)
     if graph.has_messages:
         (mmsg_i32_rows, mmsg_key_rows, mmsg_pay_rows,
          msub_i32_rows, msub_i64_rows) = g[13:]
+        mmsg_key_rows = _col64(mmsg_key_rows)
+        msub_i64_rows = pops.planes_to_i64(msub_i64_rows)
     inst_state = jnp.where(ei_found, ei_rows[:, EI_STATE], -1)
     scope_state = jnp.where(sc_found, sc_rows[:, EI_STATE], -1)
 
@@ -525,12 +551,16 @@ def step_kernel(
     inst_scope_slot = aik_rows[:, EI_SCOPE]
     with jax.named_scope("zb_gather"):
         sp_key_g, is_key_g = pops.fused_gather_rows(
-            [state.ei_key],
+            [state.ei_i64],
             [pops.GatherOp(0, jnp.clip(scope_parent, 0, n_cap - 1)),
              pops.GatherOp(0, jnp.clip(inst_scope_slot, 0, n_cap - 1))],
         )
-    scope_parent_key = jnp.where(scope_parent >= 0, sp_key_g, -1)
-    inst_scope_key = jnp.where(inst_scope_slot >= 0, is_key_g, -1)
+    scope_parent_key = jnp.where(
+        scope_parent >= 0, _col64(sp_key_g, EIL_KEY), -1
+    )
+    inst_scope_key = jnp.where(
+        inst_scope_slot >= 0, _col64(is_key_g, EIL_KEY), -1
+    )
 
     # ---------------- B. routing + guards ----------------
     m_create = wi_cmd & (it == int(WI.CREATE)) & (batch.wf >= 0)
@@ -967,7 +997,7 @@ def step_kernel(
         )
         leader = jnp.zeros((b,), bool).at[order].set(first_occ) & missing
         # allocate join slots for leaders
-        join_free = _first_true_indices(state.join_key < 0, b)
+        join_free = _first_true_indices(col_neg(state.join_key), b)
         l_rank = _excl_cumsum(leader.astype(jnp.int32))
         l_slot = join_free[jnp.clip(l_rank, 0, b - 1)]
         join_overflow = jnp.any(leader & (l_slot >= j_cap))
@@ -1009,7 +1039,7 @@ def step_kernel(
             state.join_pay, arr_slot, arrival, b_pay_join, win3
         )
         # completion: all incoming arrived; completer = last arrival in batch
-        arr_count = jnp.sum(arrived, axis=1).astype(jnp.int32)
+        arr_count = jnp.sum(arrived, axis=1, dtype=jnp.int32)
         complete_slot = (join_nin_arr > 0) & (arr_count >= join_nin_arr)
         my_complete = m_pmerge & jn_found2 & complete_slot[arr_slot]
         completer = _last_writer(arr_slot, my_complete, j_cap)
@@ -1666,11 +1696,10 @@ def step_kernel(
         t_iota = jnp.arange(t_cap, dtype=jnp.int32)
         # disarm scan: this instance's armed timers by activityInstanceKey
         # (oracle _disarm_boundary_events' self.timers scan)
-        cancel_mask = (
-            m_disarm_bd[:, None]
-            & (state.timer_key >= 0)[None, :]
-            & (state.timer_aik[None, :] == batch.key[:, None])
-        )
+        timer_armed_on = ~col_neg(state.timer_key)[None, :] & col_eq(
+            state.timer_aik, 0, batch.key
+        )  # [B, TM]: the armed timers of each row's activity instance
+        cancel_mask = m_disarm_bd[:, None] & timer_armed_on
         for bslot in range(bdw):
             arm_b = m_arm & (bslot < bd_n)
             b_elem = graph.bd_elem[wf_c, el_c, bslot]
@@ -1730,9 +1759,9 @@ def step_kernel(
             es = put(
                 es, c_found,
                 valid=True, rtype=RT_CMD, vtype=VT_TIMER, intent=int(TI.CANCEL),
-                key=c_key, elem=c_elem,
-                aux_key=batch.key, deadline=c_due,
-                instance_key=c_ik,
+                key=_col64(c_key), elem=c_elem,
+                aux_key=batch.key, deadline=_col64(c_due),
+                instance_key=_col64(c_ik),
             )
             cancel_mask = cancel_mask & (t_iota[None, :] != c_clipd[:, None])
             # disarm: message-boundary subscription closes (sends)
@@ -1823,11 +1852,7 @@ def step_kernel(
         # oracle writes these cancels between the step output and
         # TERMINATED; a timer both disarmed and terminate-scanned cancels
         # TWICE, exactly like the oracle's two passes over self.timers
-        tc_mask = (
-            m_cancel_timers[:, None]
-            & (state.timer_key >= 0)[None, :]
-            & (state.timer_aik[None, :] == batch.key[:, None])
-        )
+        tc_mask = m_cancel_timers[:, None] & timer_armed_on
         for t in range(bdw):
             tc_idx = jnp.min(
                 jnp.where(tc_mask, t_iota[None, :], t_cap), axis=1
@@ -1845,9 +1870,9 @@ def step_kernel(
             es3 = put(
                 es3, tc_found,
                 valid=True, rtype=RT_CMD, vtype=VT_TIMER, intent=int(TI.CANCEL),
-                key=tc_key, elem=tc_elem,
-                aux_key=batch.key, deadline=tc_due,
-                instance_key=tc_ik,
+                key=_col64(tc_key), elem=tc_elem,
+                aux_key=batch.key, deadline=_col64(tc_due),
+                instance_key=_col64(tc_ik),
             )
             tc_mask = tc_mask & (t_iota[None, :] != tc_clipd[:, None])
 
@@ -1979,8 +2004,9 @@ def step_kernel(
     # the semantics bit-for-bit. Op order matches the old op-major chain;
     # the only cross-op row sharing between records is through commutative
     # "add" ops (token counters), so the mega-pass's chunk-major execution
-    # is observationally identical.
-    ei_i64_pl = pops.i64_to_planes(state.ei_i64)
+    # is observationally identical. The 64-bit tables are planes at rest
+    # (tpu/state.py): they enter the commit as they are, and what a wave
+    # writes into them is converted at wave size (TableOp vals).
     ei_k32 = state.ei_i32.shape[1]
     T_EI32, T_EI64, T_EIPAY, T_EIFREE, T_EIIDX = range(5)
     ei_ops = []
@@ -2115,8 +2141,8 @@ def step_kernel(
     ei64_slot = jnp.where(jobkey_m, aik_clip, ei_clip)
     v2 = pops.vec64_to_planes(jobkey_v)
     neg2 = pops.vec64_to_planes(jnp.full((b,), -1, jnp.int64))
-    ei64_vals = jnp.zeros((b, ei_i64_pl.shape[1]), jnp.int32)
-    ei64_mask = jnp.zeros((b, ei_i64_pl.shape[1]), bool)
+    ei64_vals = jnp.zeros((b, state.ei_i64.shape[1]), jnp.int32)
+    ei64_mask = jnp.zeros((b, state.ei_i64.shape[1]), bool)
     ei64_vals = ei64_vals.at[:, 2 * EIL_JOB_KEY].set(v2[:, 0])
     ei64_vals = ei64_vals.at[:, 2 * EIL_JOB_KEY + 1].set(v2[:, 1])
     ei64_mask = ei64_mask.at[:, 2 * EIL_JOB_KEY].set(jobkey_m)
@@ -2202,18 +2228,16 @@ def step_kernel(
             _last_writer(aik_clip, corr_inst_ok, n_cap), b_pay,
         ))
 
-    ei_i32_arr, ei_i64_pl, ei_pay, free_ei_arr, ei_index_arr = (
+    ei_i32_arr, ei_i64_arr, ei_pay, free_ei_arr, ei_index_arr = (
         pops.fused_table_commit(
-            [state.ei_i32, ei_i64_pl, state.ei_pay, state.free_ei,
+            [state.ei_i32, state.ei_i64, state.ei_pay, state.free_ei,
              state.ei_index],
             ei_ops,
         )
     )
-    ei_i64_arr = pops.planes_to_i64(ei_i64_pl)
 
     # ---------------- job table (fused commit) ----------------
     T_J32, T_J64, T_JPAY, T_JFREE, T_JIDX = range(5)
-    job_i64_pl = pops.i64_to_planes(state.job_i64)
     job_k32 = state.job_i32.shape[1]
     job_ops = []
     # job ring pop indices + the ring read hoisted into the round-9a
@@ -2274,8 +2298,8 @@ def step_kernel(
 
     jd2 = pops.vec64_to_planes(batch.deadline)
     jneg2 = pops.vec64_to_planes(jnp.full((b,), -1, jnp.int64))
-    j64_vals = jnp.zeros((b, job_i64_pl.shape[1]), jnp.int32)
-    j64_mask = jnp.zeros((b, job_i64_pl.shape[1]), bool)
+    j64_vals = jnp.zeros((b, state.job_i64.shape[1]), jnp.int32)
+    j64_mask = jnp.zeros((b, state.job_i64.shape[1]), bool)
     j64_vals = j64_vals.at[:, 2 * JBL_DEADLINE].set(jd2[:, 0])
     j64_vals = j64_vals.at[:, 2 * JBL_DEADLINE + 1].set(jd2[:, 1])
     j64_mask = j64_mask.at[:, 2 * JBL_DEADLINE].set(jact_ok)
@@ -2311,14 +2335,13 @@ def step_kernel(
     ))
     free_job_push_new = state.free_job_push + jnp.sum(job_push_m, dtype=jnp.int64)
 
-    job_i32_arr, job_i64_pl, job_pay_arr, free_job_arr, job_index_arr = (
+    job_i32_arr, job_i64_arr, job_pay_arr, free_job_arr, job_index_arr = (
         pops.fused_table_commit(
-            [state.job_i32, job_i64_pl, state.job_pay, state.free_job,
+            [state.job_i32, state.job_i64, state.job_pay, state.free_job,
              state.job_index],
             job_ops,
         )
     )
-    job_i64_arr = pops.planes_to_i64(job_i64_pl)
 
     # ---------------- join cleanup ----------------
     if graph.has_parallel_joins:
@@ -2343,12 +2366,12 @@ def step_kernel(
 
     # ---------------- timer table ----------------
     if graph.has_timers:
-        # fused commit over the timer bookkeeping columns (i64 columns as
-        # [TM, 2] i32 planes, elem/wf as 1D lane tables): the 8 insert /
-        # remove writes ride one mega-pass; the hashmap insert/delete stay
-        # their own probe kernels
+        # fused commit over the timer bookkeeping columns (the 64-bit
+        # columns are [TM, 2] i32 planes, elem/wf 1D lane tables): the 8
+        # insert / remove writes ride one mega-pass; the hashmap
+        # insert/delete stay their own probe kernels
         t_ins = m_tcreate
-        tfree = _first_true_indices(state.timer_key < 0, b)
+        tfree = _first_true_indices(col_neg(state.timer_key), b)
         t_rank = _excl_cumsum(t_ins.astype(jnp.int32))
         t_slot = tfree[jnp.clip(t_rank, 0, b - 1)]
         timer_overflow = jnp.any(t_ins & (t_slot >= t_cap))
@@ -2372,20 +2395,12 @@ def step_kernel(
             pops.TableOp(T_TK, "set", tm_clip, t_rm, tneg_pl),
             pops.TableOp(T_TD, "set", tm_clip, t_rm, tneg_pl),
         ]
-        tk_pl, td_pl, ta_pl, tik_pl, timer_elem_arr, timer_wf_arr = (
-            pops.fused_table_commit(
-                [pops.i64_to_planes(state.timer_key[:, None]),
-                 pops.i64_to_planes(state.timer_due[:, None]),
-                 pops.i64_to_planes(state.timer_aik[:, None]),
-                 pops.i64_to_planes(state.timer_instance_key[:, None]),
-                 state.timer_elem, state.timer_wf],
-                timer_ops,
-            )
+        (timer_key_arr, timer_due_arr, timer_aik_arr, timer_ik_arr,
+         timer_elem_arr, timer_wf_arr) = pops.fused_table_commit(
+            [state.timer_key, state.timer_due, state.timer_aik,
+             state.timer_instance_key, state.timer_elem, state.timer_wf],
+            timer_ops,
         )
-        timer_key_arr = pops.planes_to_i64(tk_pl)[:, 0]
-        timer_due_arr = pops.planes_to_i64(td_pl)[:, 0]
-        timer_aik_arr = pops.planes_to_i64(ta_pl)[:, 0]
-        timer_ik_arr = pops.planes_to_i64(tik_pl)[:, 0]
         timer_map, _t_ok = pops.insert(state.timer_map, key0, t_slot, t_ins)
         timer_map = pops.delete(timer_map, batch.key, t_rm)
     else:
@@ -2402,7 +2417,7 @@ def step_kernel(
     if graph.has_messages:
         neg64 = jnp.full((b,), -1, jnp.int64)
         # subscription inserts (OPEN) / removals (CLOSE)
-        msfree = _first_true_indices(state.msub_ckey < 0, b)
+        msfree = _first_true_indices(col_neg(state.msub_ckey), b)
         ms_rank = _excl_cumsum(open_ok.astype(jnp.int32))
         ms_slot_new = msfree[jnp.clip(ms_rank, 0, b - 1)]
         msub_overflow = jnp.any(open_ok & (ms_slot_new >= ms_cap))
@@ -2415,9 +2430,8 @@ def step_kernel(
                 [batch.type_id, batch.retries, batch.worker, batch.wf], axis=-1
             ),
         )
-        msub_i64_pl = pops.i64_to_planes(state.msub_i64)
-        msub_i64_pl = pops.masked_row_update(
-            msub_i64_pl, ms_slot_new, open_ok,
+        msub_i64_arr = pops.masked_row_update(
+            state.msub_i64, ms_slot_new, open_ok,
             pops.i64_to_planes(
                 jnp.stack([batch.instance_key, batch.aux_key], axis=-1)
             ),
@@ -2429,10 +2443,9 @@ def step_kernel(
             msub_ckey_arr, msub_clip, close_ok, neg64
         )
         msub_map_arr = pops.delete(msub_map_arr, ckey, close_ok)
-        msub_i64_arr = pops.planes_to_i64(msub_i64_pl)
 
         # stored messages (PUBLISH with TTL) / deletions
-        mgfree = _first_true_indices(state.msg_key < 0, b)
+        mgfree = _first_true_indices(col_neg(state.msg_key), b)
         mg_rank = _excl_cumsum(pub_store.astype(jnp.int32))
         mg_slot_new = mgfree[jnp.clip(mg_rank, 0, b - 1)]
         msg_overflow = jnp.any(pub_store & (mg_slot_new >= mg_cap))
@@ -2621,31 +2634,54 @@ step_jit = jit_registry.register_jit(
 )
 
 
+# rows of one tick's command batch: the due rows beyond it stay due and
+# ride the next tick (``count`` says how many were due in all)
+TICK_LIMIT = 4096
+
+
 def tick_kernel(state: EngineState, now) -> Tuple[RecordBatch, jax.Array]:
     """Due-timer and job-deadline scan → TIME_OUT / TRIGGER command batch
     (reference JobTimeOutStreamProcessor + the oracle's check_*_deadlines;
-    ordered by key like the oracle's sorted iteration)."""
+    ordered by key like the oracle's sorted iteration). The scan and the
+    sort run on the tables' 32-bit planes; int64 is made of the batch's
+    rows only, at most ``TICK_LIMIT`` of them."""
     t_cap = state.timer_key.shape[0]
-    m_cap = state.job_key.shape[0]
-    v = state.num_vars
-    size = t_cap + m_cap
+    m_cap = state.job_i32.shape[0]
+    size = min(t_cap + m_cap, TICK_LIMIT)
 
-    timer_due = (state.timer_key >= 0) & (state.timer_due <= now)
+    timer_due = ~col_neg(state.timer_key) & col_le(state.timer_due, 0, now)
     job_due = (
         (state.job_state == int(JI.ACTIVATED))
-        & (state.job_deadline >= 0)
-        & (state.job_deadline <= now)
+        & ~col_neg(state.job_i64, JBL_DEADLINE)
+        & col_le(state.job_i64, JBL_DEADLINE, now)
     )
-    keys = jnp.concatenate([state.timer_key, state.job_key])
     due = jnp.concatenate([timer_due, job_due])
-    order = jnp.argsort(jnp.where(due, keys, jnp.int64(2**62)), stable=True)
+    # ascending by key = by (high word, low word unsigned); rows not due
+    # sort behind every key, in table order
+    key_hi = jnp.concatenate(
+        [col_hi(state.timer_key), col_hi(state.job_i64, JBL_KEY)]
+    )
+    key_lo = jnp.concatenate(
+        [col_lo(state.timer_key), col_lo(state.job_i64, JBL_KEY)]
+    ).astype(jnp.uint32)
+    _, _, order = jax.lax.sort(
+        (
+            jnp.where(due, key_hi, jnp.iinfo(jnp.int32).max),
+            jnp.where(due, key_lo, 0),
+            jnp.arange(t_cap + m_cap, dtype=jnp.int32),
+        ),
+        num_keys=3,
+    )
+    order = order[:size]
     count = jnp.sum(due, dtype=jnp.int32)
 
-    is_timer = jnp.concatenate(
-        [jnp.ones((t_cap,), bool), jnp.zeros((m_cap,), bool)]
-    )[order]
+    is_timer = order < t_cap
     tidx = jnp.clip(order, 0, t_cap - 1)
     jidx = jnp.clip(order - t_cap, 0, m_cap - 1)
+    job_i64_rows = pops.planes_to_i64(state.job_i64[jidx])
+
+    def timer_col(planes):
+        return _col64(planes[tidx])
 
     sel = jnp.arange(size, dtype=jnp.int32) < count
     tick_jb_vt, tick_jb_sid, tick_jb_num = unpack_payload(state.job_pay[jidx])
@@ -2658,11 +2694,14 @@ def tick_kernel(state: EngineState, now) -> Tuple[RecordBatch, jax.Array]:
         intent=jnp.where(
             is_timer, jnp.int32(int(TI.TRIGGER)), jnp.int32(int(JI.TIME_OUT))
         ),
-        key=keys[order],
+        key=jnp.where(
+            is_timer, timer_col(state.timer_key), job_i64_rows[:, JBL_KEY]
+        ),
         elem=jnp.where(is_timer, state.timer_elem[tidx], state.job_elem[jidx]),
         wf=jnp.where(is_timer, state.timer_wf[tidx], state.job_wf[jidx]),
         instance_key=jnp.where(
-            is_timer, state.timer_instance_key[tidx], state.job_instance_key[jidx]
+            is_timer, timer_col(state.timer_instance_key),
+            job_i64_rows[:, JBL_IKEY],
         ),
         scope_key=jnp.full((size,), -1, jnp.int64),
         v_vt=jnp.where(is_timer[:, None], 0, tick_jb_vt).astype(jnp.int8),
@@ -2670,12 +2709,14 @@ def tick_kernel(state: EngineState, now) -> Tuple[RecordBatch, jax.Array]:
         v_str=jnp.where(is_timer[:, None], 0, tick_jb_sid),
         req=jnp.full((size,), -1, jnp.int64),
         req_stream=jnp.full((size,), -1, jnp.int32),
-        aux_key=jnp.where(is_timer, state.timer_aik[tidx], state.job_aik[jidx]),
+        aux_key=jnp.where(
+            is_timer, timer_col(state.timer_aik), job_i64_rows[:, JBL_AIK]
+        ),
         aux2_key=jnp.full((size,), -1, jnp.int64),
         type_id=jnp.where(is_timer, 0, state.job_type[jidx]),
         retries=jnp.where(is_timer, 0, state.job_retries[jidx]),
         deadline=jnp.where(
-            is_timer, state.timer_due[tidx], state.job_deadline[jidx]
+            is_timer, timer_col(state.timer_due), job_i64_rows[:, JBL_DEADLINE]
         ),
         worker=jnp.where(is_timer, 0, state.job_worker[jidx]),
         src=jnp.full((size,), -1, jnp.int32),

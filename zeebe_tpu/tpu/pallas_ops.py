@@ -23,8 +23,9 @@ Addressing rules (load-bearing, measured):
 - per-record control scalars (slots, flags, hashes, key halves) MUST live
   in SMEM — extracting a scalar from a VMEM vector costs ~300x;
 - the batch is grid-chunked so each chunk's scalars fit SMEM;
-- int64 never enters a kernel: i64 arrays are bitcast to (lo, hi) i32
-  planes at the boundary (TPU i64 is emulated anyway).
+- int64 never enters a kernel, and no table is int64 anywhere: the
+  state holds its 64-bit columns as (lo, hi) i32 plane pairs
+  (``tpu/state.py``), and only wave-sized values are ever made int64.
 
 Every kernel holds its WHOLE table in VMEM for the pass, so a family takes
 the pallas form only for tables whose windows fit (``_fits_vmem``, a static
@@ -559,8 +560,13 @@ def masked_lane_accum(table1d, slots, active, deltas):
 
 
 # ---------------------------------------------------------------------------
-# int64 plane helpers (TPU i64 is emulated; tables convert to i32 planes at
-# the pallas boundary and back — a cheap layout bitcast, not element math)
+# int64 plane helpers, for WAVE-sized values only. A TPU has no 64-bit
+# integers: XLA holds an s64 array as two separate u32 arrays, so these
+# bitcasts are an interleave / de-interleave of the whole operand (and an
+# s64 program parameter or result is split / combined whole on top). At wave
+# size that is nothing; at table size it was 4 ms of a 9 ms step (PERF.md,
+# PR 30) — so the state's tables ARE planes, [rows, 2C] i32, and nothing
+# table-sized passes through here.
 # ---------------------------------------------------------------------------
 
 
@@ -583,9 +589,11 @@ def vec64_to_planes(x: jax.Array) -> jax.Array:
     return lax.bitcast_convert_type(x, jnp.int32)
 
 
-def masked_vec64_update(table1d, slots, active, vals64):
-    """1D i64 table scatter: ``table[slot[i]] = vals64[i]`` via planes."""
-    t = table1d.shape[0]
+def masked_vec64_update(planes, slots, active, vals64):
+    """One 64-bit column's scatter: ``column[slot[i]] = vals64[i]``, the
+    column a ``[T, 2]`` i32 plane table (lo, hi), the values ``[B]`` i64."""
+    t = planes.shape[0]
+    vals = vec64_to_planes(vals64)
     if not (
         _use_pallas("vec64")
         and _fits_vmem(
@@ -593,15 +601,13 @@ def masked_vec64_update(table1d, slots, active, vals64):
         )
     ):
         idx = jnp.where(active, slots, t)
-        return table1d.at[idx].set(vals64.astype(table1d.dtype), mode="drop")
-    planes = i64_to_planes(table1d[:, None])
+        return planes.at[idx].set(vals, mode="drop")
     # force the inner row update onto the pallas path: this call must be
     # exactly what the autotune's "vec64" pallas arm measured — letting it
     # re-consult the independent "row_update" decision could install a
-    # planes-conversion + XLA-scatter hybrid neither A/B arm ever timed
+    # hybrid neither A/B arm ever timed
     with forced("pallas"):
-        out = masked_row_update(planes, slots, active, vec64_to_planes(vals64))
-    return planes_to_i64(out)[:, 0]
+        return masked_row_update(planes, slots, active, vals)
 
 
 # ---------------------------------------------------------------------------
@@ -662,7 +668,7 @@ def _apply_op_unfused(tbl: jax.Array, op: TableOp) -> jax.Array:
 def fused_table_commit(
     tables: Sequence[jax.Array], ops: Sequence[TableOp]
 ) -> List[jax.Array]:
-    """Apply ``ops`` to ``tables`` (all i32; i64 state enters as planes) as
+    """Apply ``ops`` to ``tables`` (all i32; 64-bit state is planes) as
     ONE pallas serial pass — or, when the fused family lost the autotune
     A/B, the tables together do not fit VMEM, or off-TPU, as the
     equivalent unfused op chain (each op then decides on its own table).
@@ -891,19 +897,12 @@ def _gather_unfused(
     return results  # type: ignore[return-value]
 
 
-def _gather_is_lane(t: jax.Array) -> bool:
-    """1D non-i64 tables fold to [T/128, 128] and are read by lane."""
-    return t.ndim == 1 and t.dtype != jnp.int64
-
-
 def _gather_norm_shape(t: jax.Array) -> Tuple[int, int]:
-    """Shape of a table's i32 normal form inside ``fused_gather_rows``."""
-    wide = 2 if t.dtype == jnp.int64 else 1
+    """Shape of a table's i32 normal form inside ``fused_gather_rows``:
+    2D as it is, 1D folded to [T/128, 128] and read by lane."""
     if t.ndim == 2:
-        return (t.shape[0], t.shape[1] * wide)
-    if _gather_is_lane(t):
-        return (t.shape[0] // LANES, LANES)
-    return (t.shape[0], 2)
+        return tuple(t.shape)
+    return (t.shape[0] // LANES, LANES)
 
 
 def fused_gather_rows(
@@ -913,10 +912,12 @@ def fused_gather_rows(
 ) -> List[jax.Array]:
     """``[tables[op.table][op.slots] for op in ops]`` as ONE pallas serial
     pass — or, off the pallas path, as one concatenated XLA gather per
-    table group. Tables may be i32/i64/f32/i8/bool, 1D or 2D; i64 crosses
-    the pallas boundary as (lo, hi) i32 planes, f32 as a bitcast, i8/bool
-    widened to i32 — all exact round-trips. Every result is elementwise
-    equal to direct indexing on both paths.
+    table group. Tables may be i32/f32/i8/bool, 1D or 2D; f32 crosses the
+    pallas boundary as a bitcast, i8/bool widened to i32 — exact
+    round-trips. (The state has no int64 table: a 64-bit column is i32
+    planes, ``tpu/state.py``. One passed here all the same is read by the
+    XLA form, not converted whole for a wave's rows.) Every result is
+    elementwise equal to direct indexing on both paths.
 
     ``family`` selects the dispatch row ("gather" for the phase-B/C state
     reads, "emit" for the output-queue compaction takes) so the autotuner
@@ -931,6 +932,7 @@ def fused_gather_rows(
         all(op.slots.shape[0] == b for op in ops)
         and all(t.ndim in (1, 2) for t in tables)
         and all(t.shape[0] % LANES == 0 for t in tables if t.ndim == 1)
+        and all(t.dtype.itemsize <= 4 for t in tables)
         and use_pallas(family)
     )
     if fusable:
@@ -940,7 +942,7 @@ def fused_gather_rows(
         blocks = [
             (c, norm_shapes[op.table][1])
             for op in ops
-            if not _gather_is_lane(tables[op.table])
+            if tables[op.table].ndim == 2
         ]
         fusable = _fits_vmem(family, norm_shapes, blocks)
     if not fusable:
@@ -949,17 +951,13 @@ def fused_gather_rows(
     ntab = len(tables)
     n_ops = len(ops)
 
-    # normalize every table to i32 — 2D stays [T, K'] (i64 → planes, f32 →
-    # bitcast, i8 → widened), 1D folds to [T/128, 128] for lane extraction
-    # except 1D i64, which becomes a [T, 2] plane-row table
+    # normalize every table to i32 — 2D stays [T, K] (f32 → bitcast, i8 →
+    # widened), 1D folds to [T/128, 128] for lane extraction
     norm: List[jax.Array] = []
     decode: List[Tuple[str, object]] = []  # per-table (mode, dtype)
     for t in tables:
         if t.ndim == 2:
-            if t.dtype == jnp.int64:
-                norm.append(i64_to_planes(t))
-                decode.append(("planes", t.dtype))
-            elif t.dtype == jnp.float32:
+            if t.dtype == jnp.float32:
                 norm.append(lax.bitcast_convert_type(t, jnp.int32))
                 decode.append(("bitcast", t.dtype))
             elif t.dtype == jnp.int32:
@@ -969,10 +967,7 @@ def fused_gather_rows(
                 norm.append(t.astype(jnp.int32))
                 decode.append(("widen", t.dtype))
         else:
-            if t.dtype == jnp.int64:
-                norm.append(i64_to_planes(t[:, None]))
-                decode.append(("planes1d", t.dtype))
-            elif t.dtype == jnp.float32:
+            if t.dtype == jnp.float32:
                 norm.append(
                     lax.bitcast_convert_type(t, jnp.int32).reshape(
                         t.shape[0] // LANES, LANES
@@ -1041,11 +1036,7 @@ def fused_gather_rows(
     for j, op in enumerate(ops):
         mode, dt = decode[op.table]
         o = out[j]
-        if mode == "planes":
-            results.append(planes_to_i64(o))
-        elif mode == "planes1d":
-            results.append(planes_to_i64(o)[:, 0])
-        elif mode == "bitcast":
+        if mode == "bitcast":
             results.append(lax.bitcast_convert_type(o, dt))
         elif mode == "widen":
             results.append(o.astype(dt))
@@ -1063,15 +1054,7 @@ def fused_gather_rows(
 # ---------------------------------------------------------------------------
 
 
-def _split_keys(keys64: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    planes = lax.bitcast_convert_type(keys64, jnp.int32)  # [..., 2] LE
-    return planes[..., 0], planes[..., 1]
-
-
-def _join_keys(lo: jax.Array, hi: jax.Array) -> jax.Array:
-    return lax.bitcast_convert_type(
-        jnp.stack([lo, hi], axis=-1), jnp.int64
-    )
+_split_keys = hashmap.split_keys  # [B] i64 or [B, 2] planes -> (lo, hi)
 
 
 def _hash_i32(lo, hi, table_size):
@@ -1088,18 +1071,17 @@ def _hash_i32(lo, hi, table_size):
 
 
 def _fold_table(table: HashTable):
-    t = table.keys.shape[0]
-    lo, hi = _split_keys(table.keys)
+    t = table.size
     return (
-        lo.reshape(t // LANES, LANES),
-        hi.reshape(t // LANES, LANES),
+        table.keys_lo.reshape(t // LANES, LANES),
+        table.keys_hi.reshape(t // LANES, LANES),
         table.vals.reshape(t // LANES, LANES),
     )
 
 
 def lookup(table: HashTable, keys: jax.Array, valid: jax.Array):
     """Batched probe; identical results to hashmap.lookup."""
-    t = table.keys.shape[0]
+    t = table.size
     b = keys.shape[0]
     if not (
         t % LANES == 0
@@ -1110,8 +1092,7 @@ def lookup(table: HashTable, keys: jax.Array, valid: jax.Array):
     c = _chunk(b)
     lo, hi = _split_keys(keys)
     h0 = _hash_i32(lo, hi, t)
-    tlo, thi, _tv = _fold_table(table)
-    tvals = table.vals.reshape(t // LANES, LANES)
+    tlo, thi, tvals = _fold_table(table)
 
     def kernel(h0_ref, lo_ref, hi_ref, valid_ref, tlo_ref, thi_ref, tv_ref,
                found_ref, slot_ref):
@@ -1175,7 +1156,7 @@ def lookup(table: HashTable, keys: jax.Array, valid: jax.Array):
 def insert(table: HashTable, keys: jax.Array, vals: jax.Array, valid: jax.Array):
     """Batched insert of unique keys (hashmap.insert semantics; bucket
     layout may differ on collisions — see module docstring)."""
-    t = table.keys.shape[0]
+    t = table.size
     b = keys.shape[0]
     if not (
         t % LANES == 0
@@ -1259,13 +1240,15 @@ def insert(table: HashTable, keys: jax.Array, vals: jax.Array, valid: jax.Array)
         aliases={5: 0, 6: 1, 7: 2},
     )(h0, lo, hi, vals.astype(jnp.int32), valid.astype(jnp.int32),
       tlo, thi, tvals)
-    new_keys = _join_keys(tlo2.reshape(t), thi2.reshape(t))
-    return HashTable(new_keys, tv2.reshape(t)), ok.astype(bool)
+    return (
+        HashTable(tlo2.reshape(t), thi2.reshape(t), tv2.reshape(t)),
+        ok.astype(bool),
+    )
 
 
 def delete(table: HashTable, keys: jax.Array, valid: jax.Array) -> HashTable:
     """Batched delete (tombstones); identical to hashmap.delete."""
-    t = table.keys.shape[0]
+    t = table.size
     b = keys.shape[0]
     if not (
         t % LANES == 0
@@ -1276,7 +1259,7 @@ def delete(table: HashTable, keys: jax.Array, valid: jax.Array) -> HashTable:
     c = _chunk(b)
     lo, hi = _split_keys(keys)
     h0 = _hash_i32(lo, hi, t)
-    tlo, thi, tvals = _fold_table(table)
+    tlo, thi, _ = _fold_table(table)
 
     def kernel(h0_ref, lo_ref, hi_ref, valid_ref, tlo_in, thi_in,
                tlo_ref, thi_ref):
@@ -1337,8 +1320,7 @@ def delete(table: HashTable, keys: jax.Array, valid: jax.Array) -> HashTable:
         out_shape=(shape2d, shape2d),
         aliases={4: 0, 5: 1},
     )(h0, lo, hi, valid.astype(jnp.int32), tlo, thi)
-    new_keys = _join_keys(tlo2.reshape(t), thi2.reshape(t))
-    return HashTable(new_keys, tvals.reshape(t))
+    return HashTable(tlo2.reshape(t), thi2.reshape(t), table.vals)
 
 
 # ----------------------------------------------------------------------
@@ -1386,7 +1368,10 @@ def selfcheck() -> None:
     valid = jnp.asarray(rng.random(b) < 0.8)
     t_x, ok_x = hashmap.insert(table, keys, vals, valid)
     t_p, ok_p = insert(table, keys, vals, valid)
-    _eq("insert keyset", np.sort(np.asarray(t_x.keys)), np.sort(np.asarray(t_p.keys)))
+    def _keyset(tb):
+        return np.sort(hashmap.host_keys(tb))
+
+    _eq("insert keyset", _keyset(t_x), _keyset(t_p))
     _eq("insert ok", ok_x, ok_p)
     fx, sx = hashmap.lookup(t_p, keys, valid)
     fp, sp = lookup(t_p, keys, valid)
@@ -1395,7 +1380,7 @@ def selfcheck() -> None:
         np.where(np.asarray(fp), np.asarray(sp), -1))
     d_x = hashmap.delete(t_x, keys, valid)
     d_p = delete(t_p, keys, valid)
-    _eq("delete keyset", np.sort(np.asarray(d_x.keys)), np.sort(np.asarray(d_p.keys)))
+    _eq("delete keyset", _keyset(d_x), _keyset(d_p))
 
     tbl = jnp.asarray(rng.integers(0, 100, (t, k)), jnp.int32)
     slots = jnp.asarray(rng.choice(t, b, replace=False), jnp.int32)

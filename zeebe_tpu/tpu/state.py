@@ -18,8 +18,28 @@ each state family is a fixed-capacity SoA table plus an HBM hash index
 Capacities are static (jit shapes); the host engine grows tables by
 re-padding when occupancy crosses a threshold.
 
+64-bit columns are 32-bit planes. A TPU has no 64-bit integers: XLA holds
+an ``s64`` array as two ``u32`` arrays, splits it whole where it enters a
+program and combines it whole where it leaves, whether the program touched
+one row or none. So no leaf of table size is ``int64``. A table of C 64-bit
+columns is ONE ``[rows, 2C] int32`` array, column c's low word at ``[:,
+2c]`` and its high word at ``[:, 2c + 1]`` (``ei_i64``, ``job_i64``,
+``msub_i64``); a single 64-bit column is the same with C = 1 (``[rows, 2]``:
+``timer_key``, ``msg_deadline``, ...); a hash map's keys are two ``[T]
+int32`` leaves (``hashmap.HashTable``). The chip lays a narrow 2D array
+out with its ROWS minor (``s32[N, 6]{0,1:T(8,128)}``), so on the device
+these are planes, at the bytes the int64 form took. Keys stay full 64-bit
+values: the step gathers plane rows and makes ``int64`` of the ``[wave,
+C]`` result (``pallas_ops.planes_to_i64``), and writes plane rows back.
+Host code reads and builds them with ``host_i64`` / ``host_planes`` below,
+and the snapshot on disk holds ``int64`` arrays as it always did
+(``I64_TABLES`` / ``I64_COLUMNS`` name what is converted at that boundary;
+``hashmap.host_keys`` / ``from_host`` convert a map's keys).
+Only the scalar counters (``next_*_key``, ``free_*_pop`` / ``_push``) and
+the 16-row worker-subscription table are ``int64``.
+
 Write-path note: the step kernel commits each table GROUP (ei_i32 +
-ei_i64-as-planes + ei_pay + free ring + index; likewise jobs and timers)
+ei_i64 + ei_pay + free ring + index; likewise jobs and timers)
 through ONE fused pallas mega-pass (``pallas_ops.fused_table_commit``) on
 builds where the boot autotune picked fusion — the packed same-dtype
 layout below is what makes those groups commit as whole-row writes.
@@ -32,6 +52,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from zeebe_tpu.engine import keyspace
 from zeebe_tpu.tpu import hashmap
@@ -44,6 +65,8 @@ EI_ELEM, EI_STATE, EI_WF, EI_SCOPE, EI_TOKENS = 0, 1, 2, 3, 4
 # BOUNDARY_EVENT_OCCURRED fires when this instance's ELEMENT_TERMINATED
 # processes (-1 none) — the oracle's _pending_boundary dict as a column
 EI_PENDING_BD = 5
+# 64-bit column numbers: column c of a plane table is words [:, 2c] (low)
+# and [:, 2c + 1] (high)
 EIL_KEY, EIL_IKEY, EIL_JOB_KEY = 0, 1, 2
 JB_STATE, JB_ELEM, JB_WF, JB_TYPE, JB_RETRIES, JB_WORKER = 0, 1, 2, 3, 4, 5
 JBL_KEY, JBL_IKEY, JBL_AIK, JBL_DEADLINE = 0, 1, 2, 3
@@ -73,6 +96,95 @@ def corr_composite(name_id, corr_vt, corr_bits):
         | (corr_vt.astype(_jnp.int64) << 32)
         | corr_bits.astype(_jnp.uint32).astype(_jnp.int64)
     )
+
+# the 64-bit leaves, by what the snapshot on disk holds for each: a [rows, C]
+# int64 table, or a [rows] int64 column (their plane form is [rows, 2C] /
+# [rows, 2] int32 — see the module docstring)
+I64_TABLES = ("ei_i64", "job_i64", "msub_i64")
+I64_COLUMNS = (
+    "join_key", "timer_key", "timer_due", "timer_aik", "timer_instance_key",
+    "msub_ckey", "msg_key", "msg_ckey", "msg_deadline",
+)
+
+
+# ---------------------------------------------------------------------------
+# 64-bit values as 32-bit planes
+# ---------------------------------------------------------------------------
+# Host side (numpy; off the wave's path): the ONE way host code reads and
+# builds 64-bit state.
+
+
+def host_i64(planes, col=None) -> np.ndarray:
+    """A pulled plane array as int64: ``[..., 2C] int32`` -> ``[..., C]``,
+    or with ``col`` the one column ``[...]`` (only its two words cross the
+    wire when ``planes`` is still on the device). A view, no arithmetic."""
+    if col is not None:
+        planes = planes[..., 2 * col : 2 * col + 2]
+    out = np.ascontiguousarray(np.asarray(planes), np.int32).view(np.int64)
+    return out[..., 0] if col is not None else out
+
+
+def host_planes(vals64, column: bool = False) -> np.ndarray:
+    """The inverse: ``[..., C] int64`` -> ``[..., 2C] int32``; with
+    ``column`` a ``[...]`` int64 column -> its ``[..., 2]`` planes."""
+    a = np.ascontiguousarray(np.asarray(vals64), np.int64)
+    if column:
+        a = a[..., None]
+    return a.view(np.int32)
+
+
+# Device side, table-sized and 32-bit throughout (the scans of the tick, the
+# due probe and the rebuild; the step's few whole-column predicates).
+
+
+def col_lo(planes, col=0):
+    return planes[:, 2 * col]
+
+
+def col_hi(planes, col=0):
+    return planes[:, 2 * col + 1]
+
+
+def col_planes(planes, col=0):
+    """[rows, 2] planes of one column of a plane table."""
+    return planes[:, 2 * col : 2 * col + 2]
+
+
+def col_neg(planes, col=0):
+    """``value < 0`` per row (free rows hold -1; a key is never negative)."""
+    return col_hi(planes, col) < 0
+
+
+def col_le(planes, col, x):
+    """``value <= x`` per row, ``x`` an int64 scalar: signed on the high
+    words, unsigned on the low."""
+    xw = jax.lax.bitcast_convert_type(jnp.asarray(x, jnp.int64), jnp.int32)
+    hi, lo = col_hi(planes, col), col_lo(planes, col).astype(jnp.uint32)
+    return (hi < xw[1]) | ((hi == xw[1]) & (lo <= xw[0].astype(jnp.uint32)))
+
+
+def col_eq(planes, col, vals64):
+    """``[B, rows]``: row r's value == ``vals64[b]`` (``[B]`` int64)."""
+    vw = jax.lax.bitcast_convert_type(vals64.astype(jnp.int64), jnp.int32)
+    return (col_lo(planes, col)[None, :] == vw[:, 0][:, None]) & (
+        col_hi(planes, col)[None, :] == vw[:, 1][:, None]
+    )
+
+
+def index_bucket(planes, col, icap: int):
+    """``(key // 5) & (icap - 1)`` per row — the direct-mapped index's
+    bucket (see ``ei_index``) — of a table's own non-negative key column,
+    by long division on 16-bit digits: no 64-bit arithmetic at table size.
+    Equal to the int64 expression the step uses on a wave's keys."""
+    assert icap & (icap - 1) == 0 and icap <= 1 << 32
+    u = jnp.uint32
+    lo = col_lo(planes, col).astype(u)
+    r = col_hi(planes, col).astype(u) % u(5)
+    t1 = (r << u(16)) | (lo >> u(16))
+    t2 = ((t1 % u(5)) << u(16)) | (lo & u(0xFFFF))
+    low32 = ((t1 // u(5)) << u(16)) | (t2 // u(5))
+    return (low32 & u(icap - 1)).astype(jnp.int32)
+
 
 _STATE_FIELDS = [
     "ei_i32", "ei_i64", "ei_pay", "ei_map", "ei_index",
@@ -131,9 +243,9 @@ class EngineState:
     # element instances [N] (ElementInstanceIndex analogue), packed:
     # ei_i32 cols = (elem, lifecycle state[-1 free], wf slot, scope slot,
     # token count, pending boundary elem[-1 none]);
-    # ei_i64 cols = (key[-1 free], workflowInstanceKey, jobKey)
+    # ei_i64 64-bit cols = (key[-1 free], workflowInstanceKey, jobKey)
     ei_i32: jax.Array          # [N, 6] i32
-    ei_i64: jax.Array          # [N, 3] i64
+    ei_i64: jax.Array          # [N, 2*3] i32 planes
     ei_pay: jax.Array          # [N, 3V] i32 packed payload (vt | sid | f32 bits)
     ei_map: hashmap.HashTable  # key → slot (FALLBACK; see ei_index)
     # Direct-mapped key → slot accelerator: keys are allocated
@@ -158,7 +270,7 @@ class EngineState:
     # retries, worker); job_i64 cols = (key[-1 free], instanceKey, aik,
     # deadline)
     job_i32: jax.Array         # [M, 6] i32
-    job_i64: jax.Array         # [M, 4] i64
+    job_i64: jax.Array         # [M, 2*4] i32 planes
     job_pay: jax.Array         # [M, 3V] i32 packed payload
     job_map: hashmap.HashTable  # fallback (see ei_index)
     job_index: jax.Array       # [8M] i32 slot, -1 empty
@@ -167,7 +279,7 @@ class EngineState:
     free_job_push: jax.Array   # i64
 
     # parallel joins [J]
-    join_key: jax.Array        # i64 composite (scope_key<<8 | gateway), -1 free
+    join_key: jax.Array        # [J, 2] planes: composite (scope_key<<10 | gateway), -1 free
     join_nin: jax.Array        # i32
     join_arrived: jax.Array    # [J, F_in] bool
     join_pay: jax.Array        # [J, 3V] i32 packed merged payload
@@ -175,10 +287,10 @@ class EngineState:
     join_map: hashmap.HashTable
 
     # timers [TM]
-    timer_key: jax.Array       # i64, -1 free
-    timer_due: jax.Array       # i64
-    timer_aik: jax.Array       # i64
-    timer_instance_key: jax.Array  # i64
+    timer_key: jax.Array       # [TM, 2] planes, -1 free
+    timer_due: jax.Array       # [TM, 2] planes
+    timer_aik: jax.Array       # [TM, 2] planes
+    timer_instance_key: jax.Array  # [TM, 2] planes
     timer_elem: jax.Array      # i32 handler element
     timer_wf: jax.Array        # i32
     timer_map: hashmap.HashTable
@@ -188,16 +300,16 @@ class EngineState:
     # the oracle's StoredSubscription list). One open subscription per
     # (name, correlation) composite; a second OPEN on a live composite is
     # a loud overflow (kernel stat), not silent data loss.
-    msub_ckey: jax.Array       # [MS] i64 corr_composite, -1 free
+    msub_ckey: jax.Array       # [MS, 2] planes: corr_composite, -1 free
     msub_i32: jax.Array        # [MS, 4] (name, cvt, cbits, wi partition)
-    msub_i64: jax.Array        # [MS, 2] (workflowInstanceKey, activityInstanceKey)
+    msub_i64: jax.Array        # [MS, 2*2] planes (workflowInstanceKey, activityInstanceKey)
     msub_map: hashmap.HashTable  # composite → slot
 
     # stored messages with TTL [MG] (oracle StoredMessage dict)
-    msg_key: jax.Array         # [MG] i64 message key, -1 free
-    msg_ckey: jax.Array        # [MG] i64 corr_composite
+    msg_key: jax.Array         # [MG, 2] planes: message key, -1 free
+    msg_ckey: jax.Array        # [MG, 2] planes: corr_composite
     msg_i32: jax.Array         # [MG, 4] (name, cvt, cbits, interned msg id)
-    msg_deadline: jax.Array    # [MG] i64 expiry timestamp
+    msg_deadline: jax.Array    # [MG, 2] planes: expiry timestamp
     msg_pay: jax.Array         # [MG, 3V] packed payload
     msg_map: hashmap.HashTable  # composite → slot
 
@@ -220,12 +332,6 @@ class EngineState:
     # unpacked read views (lazy column slices — free inside jit; host code
     # and the kernel's read paths keep the original field names)
     @property
-    def ei_key(self): return self.ei_i64[:, EIL_KEY]
-    @property
-    def ei_instance_key(self): return self.ei_i64[:, EIL_IKEY]
-    @property
-    def ei_job_key(self): return self.ei_i64[:, EIL_JOB_KEY]
-    @property
     def ei_elem(self): return self.ei_i32[:, EI_ELEM]
     @property
     def ei_state(self): return self.ei_i32[:, EI_STATE]
@@ -235,14 +341,6 @@ class EngineState:
     def ei_scope_slot(self): return self.ei_i32[:, EI_SCOPE]
     @property
     def ei_tokens(self): return self.ei_i32[:, EI_TOKENS]
-    @property
-    def job_key(self): return self.job_i64[:, JBL_KEY]
-    @property
-    def job_instance_key(self): return self.job_i64[:, JBL_IKEY]
-    @property
-    def job_aik(self): return self.job_i64[:, JBL_AIK]
-    @property
-    def job_deadline(self): return self.job_i64[:, JBL_DEADLINE]
     @property
     def job_state(self): return self.job_i32[:, JB_STATE]
     @property
@@ -292,10 +390,14 @@ def make_state(
     v = num_vars
     i64, i32 = jnp.int64, jnp.int32
 
+    def free64(rows, cols=1):
+        # -1 in every 64-bit column: both words -1
+        return jnp.full((rows, 2 * cols), -1, i32)
+
     return EngineState(
         # ei_i32: elem=0, state=-1, wf=0, scope=-1, tokens=0, pending_bd=-1
         ei_i32=jnp.tile(jnp.array([[0, -1, 0, -1, 0, -1]], i32), (n, 1)),
-        ei_i64=jnp.full((n, 3), -1, i64),
+        ei_i64=free64(n, 3),
         ei_pay=jnp.zeros((n, 3 * v), i32),
         ei_map=hashmap.make(_pow2(8 * n)),
         ei_index=jnp.full((_pow2(8 * n),), -1, i32),
@@ -304,34 +406,34 @@ def make_state(
         free_ei_push=jnp.asarray(n, i64),
         # job_i32: state=-1, elem/wf/type/retries/worker=0
         job_i32=jnp.tile(jnp.array([[-1, 0, 0, 0, 0, 0]], i32), (m, 1)),
-        job_i64=jnp.full((m, 4), -1, i64),
+        job_i64=free64(m, 4),
         job_pay=jnp.zeros((m, 3 * v), i32),
         job_map=hashmap.make(_pow2(8 * m)),
         job_index=jnp.full((_pow2(8 * m),), -1, i32),
         free_job=jnp.arange(m, dtype=i32),
         free_job_pop=jnp.zeros((), i64),
         free_job_push=jnp.asarray(m, i64),
-        join_key=jnp.full((j,), -1, i64),
+        join_key=free64(j),
         join_nin=jnp.zeros((j,), i32),
         join_arrived=jnp.zeros((j, max_join_in), bool),
         join_pay=jnp.zeros((j, 3 * v), i32),
         join_pos_stamp=jnp.full((j, v), -1, i32),
         join_map=hashmap.make(_pow2(4 * j)),
-        timer_key=jnp.full((tm,), -1, i64),
-        timer_due=jnp.full((tm,), -1, i64),
-        timer_aik=jnp.full((tm,), -1, i64),
-        timer_instance_key=jnp.full((tm,), -1, i64),
+        timer_key=free64(tm),
+        timer_due=free64(tm),
+        timer_aik=free64(tm),
+        timer_instance_key=free64(tm),
         timer_elem=jnp.zeros((tm,), i32),
         timer_wf=jnp.zeros((tm,), i32),
         timer_map=hashmap.make(_pow2(4 * tm)),
-        msub_ckey=jnp.full((ms,), -1, i64),
+        msub_ckey=free64(ms),
         msub_i32=jnp.zeros((ms, 4), i32),
-        msub_i64=jnp.full((ms, 2), -1, i64),
+        msub_i64=free64(ms, 2),
         msub_map=hashmap.make(_pow2(4 * ms)),
-        msg_key=jnp.full((mg,), -1, i64),
-        msg_ckey=jnp.full((mg,), -1, i64),
+        msg_key=free64(mg),
+        msg_ckey=free64(mg),
         msg_i32=jnp.zeros((mg, 4), i32),
-        msg_deadline=jnp.full((mg,), -1, i64),
+        msg_deadline=free64(mg),
         msg_pay=jnp.zeros((mg, 3 * v), i32),
         msg_map=hashmap.make(_pow2(4 * mg)),
         sub_key=jnp.full((sub_capacity,), -1, i64),
@@ -370,20 +472,21 @@ def rebuild_lookup_state(state: EngineState) -> EngineState:
     job_live = state.job_state >= 0
     ei_idx = (
         _jnp.full((icap,), -1, _jnp.int32)
-        .at[_jnp.where(ei_live, (state.ei_key // 5) & (icap - 1), icap).astype(_jnp.int32)]
+        .at[_jnp.where(ei_live, index_bucket(state.ei_i64, EIL_KEY, icap), icap)]
         .set(_jnp.arange(n, dtype=_jnp.int32), mode="drop")
     )
     job_idx = (
         _jnp.full((jcap,), -1, _jnp.int32)
-        .at[_jnp.where(job_live, (state.job_key // 5) & (jcap - 1), jcap).astype(_jnp.int32)]
+        .at[_jnp.where(job_live, index_bucket(state.job_i64, JBL_KEY, jcap), jcap)]
         .set(_jnp.arange(m, dtype=_jnp.int32), mode="drop")
     )
+    # the maps take a table's own key column as plane rows
     ei_map, _ = hashmap.rebuild_from(
-        state.ei_map.keys.shape[0], state.ei_key,
+        state.ei_map.size, col_planes(state.ei_i64, EIL_KEY),
         _jnp.arange(n, dtype=_jnp.int32), ei_live,
     )
     job_map, _ = hashmap.rebuild_from(
-        state.job_map.keys.shape[0], state.job_key,
+        state.job_map.size, col_planes(state.job_i64, JBL_KEY),
         _jnp.arange(m, dtype=_jnp.int32), job_live,
     )
     ei_free_mask = ~ei_live
@@ -406,20 +509,20 @@ def rebuild_lookup_state(state: EngineState) -> EngineState:
         return _jnp.arange(a.shape[0], dtype=_jnp.int32)
 
     join_map, _ = hashmap.rebuild_from(
-        state.join_map.keys.shape[0], state.join_key,
-        _iota(state.join_key), state.join_key >= 0,
+        state.join_map.size, state.join_key,
+        _iota(state.join_key), ~col_neg(state.join_key),
     )
     timer_map, _ = hashmap.rebuild_from(
-        state.timer_map.keys.shape[0], state.timer_key,
-        _iota(state.timer_key), state.timer_key >= 0,
+        state.timer_map.size, state.timer_key,
+        _iota(state.timer_key), ~col_neg(state.timer_key),
     )
     msub_map, _ = hashmap.rebuild_from(
-        state.msub_map.keys.shape[0], state.msub_ckey,
-        _iota(state.msub_ckey), state.msub_ckey >= 0,
+        state.msub_map.size, state.msub_ckey,
+        _iota(state.msub_ckey), ~col_neg(state.msub_ckey),
     )
     msg_map, _ = hashmap.rebuild_from(
-        state.msg_map.keys.shape[0], state.msg_ckey,
-        _iota(state.msg_ckey), state.msg_key >= 0,
+        state.msg_map.size, state.msg_ckey,
+        _iota(state.msg_ckey), ~col_neg(state.msg_key),
     )
     return _dc.replace(
         state, ei_index=ei_idx, job_index=job_idx,
