@@ -83,6 +83,30 @@ class TestDevicePlan:
         with pytest.raises(RuntimeError, match="every device is excluded"):
             plan.assign(0)
 
+    @pytest.mark.parametrize("devices, used", [(1, 1), (0, 4)])
+    def test_mesh_devices_caps_a_brokers_plan(self, tmp_path, devices, used):
+        """``[mesh] devices = 1`` (zbench/configs/mixed-4p.json) places a
+        broker's four leaders on ONE device although more are visible;
+        the default (0) spreads them over four."""
+        from zeebe_tpu.runtime.cluster_broker import ClusterBroker
+        from zeebe_tpu.runtime.config import load_config
+
+        assert len(jax.devices()) >= 4
+        cfg = load_config(toml_text=(
+            "[network]\nclientPort = 0\nmanagementPort = 0\n"
+            "subscriptionPort = 0\n[metrics]\nport = 0\nenabled = false\n"
+            f"[cluster]\npartitions = 4\n[mesh]\ndevices = {devices}\n"
+        ))
+        broker = ClusterBroker(cfg, str(tmp_path / "b0"))
+        try:
+            placed = [broker.planned_device(pid) for pid in range(4)]
+        finally:
+            broker.close()
+        assert len({device for device, _ in placed}) == used
+        assert sorted({idx for _, idx in placed}) == list(range(used))
+        if used == 1:
+            assert placed[0][0] == jax.devices()[0]
+
     def test_load_gauges_published(self):
         plan = DevicePlan(devices=list("ab"))
         plan.assign(0)

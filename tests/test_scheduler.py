@@ -152,6 +152,99 @@ class TestWavePacking:
         assert feed.dispatched, "nothing dispatched"
         assert max(len(seg) for seg in feed.dispatched) <= 64
 
+    @pytest.mark.parametrize(
+        "sizes, pipelined, wave_size, waves, segments, largest, ahead",
+        [
+            # four feeds share one wave: four launches before the first
+            # collect, each finds the earlier ones queued: 0 + 1 + 2 + 3
+            ((16, 8, 24, 12), True, 512, 1, 4, 24, 6),
+            # one feed, one wave: its own records, nothing ahead of it
+            ((16,), True, 512, 1, 1, 16, 0),
+            # one feed, two waves: the second is dispatched before the
+            # first is collected (the drain's double buffer): 0 + 1
+            ((1024,), True, 512, 2, 2, 1024, 1),
+            # a synchronous engine applies inline: nothing is ever queued
+            ((16, 8, 24, 12), False, 512, 1, 4, 24, 0),
+        ],
+        ids=["four-feeds", "one-feed", "two-waves-in-flight", "synchronous"],
+    )
+    def test_segment_counters(
+        self, sizes, pipelined, wave_size, waves, segments, largest, ahead
+    ):
+        """``serving_segments_total``, ``serving_segment_records_max_total``
+        and ``serving_launch_ahead_total`` by name, as ``zbench``'s
+        ``counter_ratio`` reads them, beside the counters that were there
+        (``scheduler_wave_sources_total`` / ``scheduler_shared_waves_total``
+        is the segments a wave)."""
+        names = (
+            "scheduler_shared_waves_total", "scheduler_wave_sources_total",
+            "serving_segments_total", "serving_segment_records_max_total",
+            "serving_launch_ahead_total", "serving_wave_records_total",
+        )
+        ws = WaveScheduler(wave_size=wave_size)
+        for pid, n in enumerate(sizes):
+            ws.register(FakeFeed(pid, n, pipelined=pipelined))
+        before = {n: event_count(n) for n in names}
+        assert ws.drain() == sum(sizes)
+        got = {n: event_count(n) - before[n] for n in names}
+        assert got == {
+            "scheduler_shared_waves_total": waves,
+            "scheduler_wave_sources_total": segments,
+            "serving_segments_total": segments,
+            "serving_segment_records_max_total": largest,
+            "serving_launch_ahead_total": ahead,
+            "serving_wave_records_total": sum(sizes),
+        }
+        assert ws._uncollected == 0
+
+    def test_failed_dispatch_counts_no_segment_and_leaves_nothing_queued(self):
+        """A segment whose dispatch raised was never launched: it is no
+        segment dispatched and nothing of its wave stays counted as queued."""
+        ws = WaveScheduler(wave_size=64)
+        ok = FakeFeed(0, 8, pipelined=True)
+        bad = FakeFeed(1, 8, fail_dispatch=True)
+        ws.register(ok)
+        ws.register(bad)
+        before = event_count("serving_segments_total")
+        with pytest.raises(RuntimeError, match="engine exploded"):
+            ws.drain()
+        assert event_count("serving_segments_total") - before == 1
+        assert ws._uncollected == 0
+
+    def test_timeline_shows_every_segment_of_a_shared_wave(self):
+        """The per-wave timeline of a four-partition wave: four segments,
+        each with its partition, its device, its records and its own
+        seconds blocked on the device."""
+        from zeebe_tpu import tracing
+
+        class PlacedFeed(FakeFeed):
+            device_index = 0
+
+            def collect(self, pending):
+                super().collect(pending)
+                return 0.001, (10 + self.partition_id) / 1000
+
+        tracer = tracing.install(tracing.RecordTracer(sample_rate=1.0, seed=3))
+        try:
+            ws = WaveScheduler(wave_size=512)
+            for pid, n in enumerate((16, 8, 24, 12)):
+                ws.register(PlacedFeed(pid, n, pipelined=True))
+            ws.drain()
+            waves = [w for w in tracer.waves.snapshot() if "segments" in w]
+        finally:
+            tracing.install(None)
+        assert len(waves) == 1 and waves[0]["records"] == 60
+        segs = sorted(waves[0]["segments"], key=lambda seg: seg["partition"])
+        assert [
+            (seg["partition"], seg["device"], seg["records"], seg["device_s"])
+            for seg in segs
+        ] == [(0, 0, 16, 0.010), (1, 0, 8, 0.011), (2, 0, 24, 0.012),
+              (3, 0, 12, 0.013)]
+        # all four were dispatched before the first was collected
+        assert max(seg["t_dispatch_us"] for seg in segs) <= min(
+            seg["t_collect_us"] for seg in segs
+        )
+
     def test_dispatch_failure_rewinds_and_collects_inflight(self):
         """A raising dispatch rewinds that segment's cursor (records
         re-drain) and still collects the previously dispatched wave."""
@@ -465,6 +558,116 @@ class TestClusterScheduler:
         finally:
             if client is not None:
                 client.close()
+            broker.close()
+
+    def test_a_drain_job_is_one_wave_and_leaves_nothing_behind(self, tmp_path):
+        """Waves of 8 records and bursts of creates on two partitions: far
+        more is committed than one wave holds. Each drain job runs ONE
+        wave and hands the actor back to its mailbox (the commands that
+        arrived meanwhile), and what the wave had no room for is drained
+        by the job it schedules: every instance completes, and no drain
+        job ever held more than one wave."""
+        from zeebe_tpu.gateway.cluster_client import ClusterClient
+        from zeebe_tpu.models.bpmn.builder import Bpmn
+        from zeebe_tpu.protocol.enums import RecordType, ValueType
+        from zeebe_tpu.protocol.intents import WorkflowInstanceIntent as WI
+
+        def tweak(cfg):
+            cfg.scheduler.wave_size = 8
+
+        broker = _boot_cluster_broker(tmp_path, partitions=2, cfg_tweak=tweak)
+        client = None
+        try:
+            client = ClusterClient(
+                [broker.client_address], num_partitions=2,
+                request_timeout_ms=60_000,
+            )
+            client.deploy_model(
+                Bpmn.create_process("burst-process")
+                .start_event("s").end_event("e").done()
+            )
+            names = ("serving_waves_total", "serving_drains_total")
+            before = {n: event_count(n) for n in names}
+            errors = []
+
+            def burst(pid):
+                try:
+                    for _ in range(12):
+                        client.create_instance("burst-process", partition_id=pid)
+                except Exception as e:  # noqa: BLE001
+                    errors.append(e)
+
+            threads = [
+                threading.Thread(target=burst, args=(i % 2,), daemon=True)
+                for i in range(8)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+            assert not errors, errors
+
+            def completed(pid):
+                return sum(
+                    1 for r in broker.partitions[pid].log.reader(0).read_committed()
+                    if r.metadata.value_type == ValueType.WORKFLOW_INSTANCE
+                    and r.metadata.record_type == RecordType.EVENT
+                    and int(r.metadata.intent) == int(WI.ELEMENT_COMPLETED)
+                    and r.key == r.value.workflow_instance_key
+                )
+
+            deadline = time.monotonic() + 60
+            while time.monotonic() < deadline and (
+                completed(0) < 48 or completed(1) < 48
+            ):
+                time.sleep(0.05)
+            assert (completed(0), completed(1)) == (48, 48)
+            got = {n: event_count(n) - before[n] for n in names}
+            # 96 instances of several records each, 8 a wave
+            assert got["serving_waves_total"] >= 48
+            assert got["serving_drains_total"] >= got["serving_waves_total"]
+        finally:
+            if client is not None:
+                client.close()
+            broker.close()
+
+    @pytest.mark.parametrize("leaders", [1, 2])
+    def test_next_drain_job_stands_behind_the_commands_of_its_wave(
+        self, tmp_path, leaders
+    ):
+        """A commit seen in the middle of a wave asks for the next drain
+        job; a command that arrives after it, still during the wave, must
+        not wait for that job's wave too: the job is enqueued when the
+        running one ends, whatever the number of leader partitions."""
+        broker = _boot_cluster_broker(tmp_path, partitions=0)
+        try:
+            jobs = []
+
+            class Mailbox:
+                def run(self, fn):
+                    jobs.append(fn)
+
+            class CommittingFeed(FakeFeed):
+                def dispatch(self, records):
+                    # in the middle of the wave: a raft thread sees a
+                    # commit, then a client command reaches the actor
+                    broker._schedule_drain()
+                    broker.actor_control.run("command")
+                    return super().dispatch(records)
+
+            broker.actor_control = Mailbox()
+            broker.wave_scheduler.register(CommittingFeed(0, 4))
+            for pid in range(1, leaders):
+                broker.wave_scheduler.register(FakeFeed(pid, 4))
+            broker._schedule_drain()
+            assert jobs == [broker._drain_committed]
+            jobs.pop()()
+            assert jobs == ["command", broker._drain_committed]
+            assert broker._drain_scheduled
+            # the job it asked for finds nothing, and asks for no other
+            jobs.pop()()
+            assert jobs == ["command"] and not broker._drain_scheduled
+        finally:
             broker.close()
 
     def test_overload_sheds_retryably(self, tmp_path):

@@ -1026,8 +1026,10 @@ class ClusterBroker(Actor):
             # is an operator knob independent of [tracing] enabled
             slow_wave_ms=cfg.tracing.slow_wave_ms,
         )
-        self._drain_scheduled = False
-        self._drain_scheduled_us = 0  # span-clock stamp of that scheduling
+        self._drain_scheduled = False  # a drain job is enqueued or running
+        # span-clock stamp of the first commit seen since the last drain
+        # job started (0: none)
+        self._drain_asked_us = 0
         # mesh-sharded serving plane: leader partitions place across the
         # visible devices (scheduler/placement.DevicePlan) so different
         # partitions' wave segments compute on DIFFERENT devices within
@@ -1518,37 +1520,60 @@ class ClusterBroker(Actor):
 
     # -- shared-wave drain ---------------------------------------------------
     def _schedule_drain(self) -> None:
-        """One drain job per burst of commits, broker-wide: every leader
-        partition's committed tail packs into the same shared waves."""
+        """A commit was seen (on any thread): one drain job broker-wide,
+        every leader partition's committed tail packs into the same shared
+        waves. While a job is enqueued or running it only leaves its stamp:
+        that job takes the records, or enqueues the next job at its end."""
+        if not self._drain_asked_us:
+            # a drain's first phase, ``drain_wait``, starts here, on
+            # whichever thread saw the commit, and ends where the broker
+            # actor runs the job
+            self._drain_asked_us = tracing.now_us()
         if self._drain_scheduled:
             return
         self._drain_scheduled = True
-        # a drain's first phase, ``drain_wait``, starts here, on whichever
-        # thread saw the commit, and ends where the broker actor runs it
-        self._drain_scheduled_us = tracing.now_us()
         self.actor_control.run(self._drain_committed)
 
     def _drain_committed(self) -> None:
-        self._drain_scheduled = False
+        """One drain job is ONE shared wave, and the next job goes to the
+        END of the mailbox when this one is over: the client commands,
+        credit returns and ticks that arrived while the wave ran are this
+        actor's jobs too and stand ahead of it. A drain that ran until
+        every feed was dry kept them waiting for as long as the partitions
+        kept committing (four leader partitions, PR 31: 2.7 waves a job, a
+        command 92 ms in the mailbox at the median, the rate spread 8.7 %
+        over six seeds)."""
+        asked_us, self._drain_asked_us = self._drain_asked_us, 0
         clock = tracing.cycle_clock("drain")
-        clock.waited("drain_wait", self._drain_scheduled_us)
+        clock.waited("drain_wait", asked_us)
+        drained = 0
         try:
-            self.wave_scheduler.drain()
-        finally:
+            try:
+                drained = self.wave_scheduler.drain(max_records=1)
+            finally:
+                with clock.phase("pump"):
+                    # the round's cross-partition frames ride ONE
+                    # collective over the mesh (route_send queued them
+                    # during the wave's applies)
+                    self._flush_mesh_exchange()
             with clock.phase("pump"):
-                # the round's cross-partition frames ride ONE collective
-                # over the mesh (route_send queued them during the waves'
-                # applies)
-                self._flush_mesh_exchange()
-        with clock.phase("pump"):
-            for server in list(self.partitions.values()):
-                if server.is_leader:
-                    # parked-record fetches start only once every in-flight
-                    # wave collected (a DEPLOYMENT inside the drain may
-                    # have provided the workflow)
-                    server.maybe_start_fetch()
-                    server.pump_topic_subscriptions()
-        observe_phases(clock, "drains")
+                for server in list(self.partitions.values()):
+                    if server.is_leader:
+                        # parked-record fetches start only once the wave
+                        # collected (a DEPLOYMENT inside it may have
+                        # provided the workflow)
+                        server.maybe_start_fetch()
+                        server.pump_topic_subscriptions()
+            observe_phases(clock, "drains")
+        finally:
+            # the flag falls BEFORE the look at what is left: a commit that
+            # lands in between schedules the next job itself
+            self._drain_scheduled = False
+            if self._drain_asked_us or (drained and any(
+                not server._parked and server.backlog()
+                for server in list(self.partitions.values())
+            )):
+                self._schedule_drain()
 
     def _queue_depth(self) -> int:
         """Admission probe: committed records awaiting the drain plus

@@ -519,6 +519,23 @@ def _sched_handles() -> dict:
                 "Sum of contributing partitions over all shared waves "
                 "(mean = this / scheduler_shared_waves_total)",
             ),
+            segments=g.counter(
+                "serving_segments_total",
+                "Wave segments dispatched (one per partition and shared "
+                "wave)",
+            ),
+            segment_max=g.counter(
+                "serving_segment_records_max_total",
+                "Per shared wave the records of its largest segment, summed "
+                "(over serving_wave_records_total: 1/partitions when they "
+                "share every wave evenly, 1.0 when one owns each wave)",
+            ),
+            launch_ahead=g.counter(
+                "serving_launch_ahead_total",
+                "At each segment's launch, the earlier launched segments "
+                "not yet collected, over all waves in flight, summed (over "
+                "serving_segments_total: the depth of the device's queue)",
+            ),
         )
     return _SCHED_HANDLES
 
@@ -530,14 +547,24 @@ def observe_shared_wave(
     host_seconds: float = 0.0,
     device_seconds: float = 0.0,
     phases=None,
+    segments: int = 0,
+    segment_max: int = 0,
+    launch_ahead: int = 0,
 ) -> None:
     """Record one SHARED drain wave (scheduler path): the plain wave
-    series (fill/occupancy/time split) plus the traffic-mix gauges."""
+    series (fill/occupancy/time split) plus the traffic-mix gauges.
+    ``segments`` counts the segments whose dispatch returned,
+    ``segment_max`` is the record count of the wave's largest segment,
+    ``launch_ahead`` the sum over its segments of the launched segments
+    that were not yet collected when each was launched."""
     observe_wave(records, capacity, host_seconds, device_seconds, phases)
     h = _sched_handles()
     h["shared_waves"].inc()
     h["sources"].set(sources)
     h["sources_total"].inc(sources)
+    h["segments"].inc(segments)
+    h["segment_max"].inc(segment_max)
+    h["launch_ahead"].inc(launch_ahead)
 
 
 # -- mesh serving instrumentation --------------------------------------------

@@ -1,12 +1,12 @@
 """Continuous-batching device-wave scheduler (Orca-style).
 
-The serving plane's wave metrics (PR 4) exposed the structural gap: each
-partition drained its OWN committed tail into its own wave, so under
-sparse or skewed traffic wave fill collapsed and every partition paid a
-full device round-trip for a handful of records. On a TPU, batch
-occupancy is the difference between rated and realized throughput — the
-"millions of users" regime is heavy AGGREGATE traffic from many small
-tenants, which must pack as tightly as one synthetic firehose.
+A partition that drains its OWN committed tail into its own wave pays a
+full device round-trip for a handful of records under sparse or skewed
+traffic (the drain this scheduler replaced did; it is gone since PR 29).
+On a TPU, batch occupancy is the difference between rated and realized
+throughput — the "millions of users" regime is heavy AGGREGATE traffic
+from many small tenants, which must pack as tightly as one synthetic
+firehose.
 
 :class:`WaveScheduler` is the single place waves are formed. It keeps a
 per-partition cursor into each partition's committed tail (the one-lock
@@ -16,8 +16,11 @@ from ALL leader partitions on a broker into SHARED waves up to
 partition's engine (the existing ``dispatch_wave``/``collect_wave``
 double-buffered pipeline), and de-multiplexes results back to the owning
 partition's apply/append/response path. Per-partition processing order is
-cursor order, so every partition's log stays bit-identical to what the
-unscheduled per-partition drain produces.
+cursor order, so a partition's log does not depend on which other
+partitions shared its waves. All of a wave's segments are launched before
+the first is collected (``_dispatch_segments`` then ``_collect``): with
+several leader partitions the device has steps queued while the host
+stages the next segment (``serving_launch_ahead_total`` counts how many).
 
 Packing policy is deficit round-robin (DRR) fairness: each feed earns
 ``quantum`` record credits per packing round and spends them against its
@@ -141,7 +144,8 @@ class SharedWave:
     """A wave packed from several partitions' committed tails."""
 
     __slots__ = ("segments", "total", "host_seconds", "device_seconds",
-                 "dispatched", "trace", "wave_id", "phases")
+                 "dispatched", "trace", "wave_id", "phases", "launched",
+                 "launch_ahead")
 
     def __init__(self, phases: tracing.PhaseClock):
         self.segments: List[WaveSegment] = []
@@ -149,6 +153,10 @@ class SharedWave:
         self.host_seconds = 0.0
         self.device_seconds = 0.0
         self.dispatched = False
+        self.launched = 0  # segments whose dispatch returned
+        # over those segments: the scheduler's launched-and-uncollected
+        # segments (of every wave in flight) at the moment each launched
+        self.launch_ahead = 0
         self.trace = None  # wave-timeline event (tracing on)
         self.wave_id = -1  # global wave sequence number (tracing on)
         # the wave's phases (tracing/phases.py): ``pack`` is stamped here,
@@ -195,6 +203,9 @@ class WaveScheduler:
         self._feeds: Dict[int, _FeedState] = {}
         self._order: List[int] = []  # sorted pids (deterministic packing)
         self._rr = 0  # rotating start index into _order
+        # segments handed to an engine and not yet collected, over every
+        # wave in flight: what a new launch finds queued ahead of it
+        self._uncollected = 0
         # the clock of packs that found nothing (every drain ends on one):
         # the next wave carries their time as part of its ``pack``
         self._pack_clock: Optional[tracing.PhaseClock] = None
@@ -382,6 +393,10 @@ class WaveScheduler:
                 wave.total = sum(s.count for s in wave.segments)
                 raise
             seg.pending = pending
+            wave.launched += 1
+            if pending is not None:
+                wave.launch_ahead += self._uncollected
+                self._uncollected += 1
             # snapshot the engine's per-shard fill NOW: the attribute is
             # mutable "last dispatched" state, and by collect time a later
             # segment's dispatch has overwritten it
@@ -439,6 +454,7 @@ class WaveScheduler:
                 # responses; re-raised after the loop
                 error = e
             finally:
+                self._uncollected -= 1
                 if state is not None:
                     state.inflight = max(0, state.inflight - seg.count)
         if tracer is not None and wave.trace is not None:
@@ -447,6 +463,9 @@ class WaveScheduler:
         observe_shared_wave(
             wave.total, self.wave_size, len(wave.segments),
             wave.host_seconds, wave.device_seconds, wave.phases,
+            segments=wave.launched,
+            segment_max=max(seg.count for seg in wave.segments),
+            launch_ahead=wave.launch_ahead,
         )
         devices = set()
         for seg in wave.segments:
