@@ -79,16 +79,9 @@ def _served_shapes():
             capacity=CAPACITY, num_vars=NUM_VARS, sub_capacity=16
         )
     )
-    # the wave as the engine ships it: six family matrices, whose column
-    # views the step program takes itself (``rb.column_views``)
-    batch = rb.StagedBatch(
-        i64=jax.ShapeDtypeStruct((WAVE, len(rb.I64_COLS)), jnp.int64),
-        i32=jax.ShapeDtypeStruct((WAVE, len(rb.I32_COLS)), jnp.int32),
-        bools=jax.ShapeDtypeStruct((WAVE, len(rb.BOOL_COLS)), jnp.bool_),
-        v_vt=jax.ShapeDtypeStruct((WAVE, NUM_VARS), jnp.int8),
-        v_num=jax.ShapeDtypeStruct((WAVE, NUM_VARS), jnp.float32),
-        v_str=jax.ShapeDtypeStruct((WAVE, NUM_VARS), jnp.int32),
-    )
+    # the wave as the engine ships it: the packed pair, whose column views
+    # the step program takes itself (``rb.column_views``)
+    batch = rb.pair_shapes(WAVE, NUM_VARS)
     return graph, state, batch
 
 

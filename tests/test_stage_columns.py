@@ -1,5 +1,5 @@
 """Wave staging by columns (``TpuPartitionEngine._stage``): the host fills
-six family matrices with array operations, ships them as one
+the packed pair's two matrices with array operations, ships them as one
 ``rb.StagedBatch`` and the step program takes the column views itself.
 
 Pinned here against the row-by-row rule the column fill replaced (kept
@@ -10,10 +10,11 @@ below as the reference, one Python write per column and row):
   without a workflow slot, junk in unset payload lanes, materialized
   Records between the refs, an empty (warm) wave and the routed laned
   layout;
-- ``rb.column_views`` of the staged pytree gives the per-column arrays the
+- ``rb.column_views`` of the staged pair gives the per-column arrays the
   engine used to slice eagerly, flat and laned, inside and outside ``jit``;
-- a served wave hands ``kernel.step_jit`` six array leaves and two numpy
-  scalars — nothing the host would have to launch a device op for.
+- a served wave hands ``kernel.step_jit`` two array leaves, both put by
+  ``_put_staged``, and two numpy scalars — nothing the host would have to
+  launch a device op for.
 """
 
 import dataclasses
@@ -38,6 +39,7 @@ from zeebe_tpu.protocol.records import (
 )
 from zeebe_tpu.tpu import TpuPartitionEngine
 from zeebe_tpu.tpu import batch as rb
+from zeebe_tpu.tpu import engine as engine_mod
 from zeebe_tpu.tpu import kernel
 
 SEED = 0x57A6ED
@@ -225,7 +227,7 @@ def _reference_columns(engine, records, size: int) -> dict:
     fill."""
     cols = {
         name: [default] * size
-        for name, default in engine._COL_DEFAULTS.items()
+        for name, default in engine_mod._COL_DEFAULTS.items()
     }
     cols["v_vt"] = np.zeros((size, NUM_VARS), np.int8)
     cols["v_num"] = np.zeros((size, NUM_VARS), np.float32)
@@ -269,7 +271,8 @@ class TestColumnFillParity:
         pad_to = 128 if case == "zero_rows" else 0  # warm() stages [] padded
         staged = flat_engine._stage(records, pad_to=pad_to)
         assert isinstance(staged, rb.StagedBatch)
-        size = staged.i64.shape[0]
+        assert len(jax.tree_util.tree_leaves(staged)) == 2
+        size = staged.size
         assert size == max(64, pad_to) and size >= len(records)
         want = _reference_columns(flat_engine, records, size)
         _assert_columns_equal(rb.column_views(jax.device_get(staged)), want)
@@ -281,7 +284,9 @@ class TestColumnFillParity:
         rng = np.random.default_rng([SEED, 100 + CASES.index(case)])
         records = _wave(case, rng)
         staged = laned_engine._stage(records, lane_owner=1)
-        assert staged.i32.shape == (2, LANE_SLOTS, len(rb.I32_COLS))
+        w32, w8 = rb.packed_widths(NUM_VARS)
+        assert staged.i32.shape == (2, LANE_SLOTS, w32)
+        assert staged.i8.shape == (2, LANE_SLOTS, w8)
         views = rb.column_views(jax.device_get(staged))
         _assert_columns_equal(
             views, _reference_columns(laned_engine, records, LANE_SLOTS),
@@ -314,36 +319,45 @@ class TestColumnViews:
     """``rb.column_views`` is the old per-column slicing, moved: the same
     arrays whether the program takes them (under ``jit``) or the host."""
 
-    def _staged(self, lead=()):
+    def _columns(self, lead=()):
         rng = np.random.default_rng([SEED, 300 + len(lead)])
         shape = lead + (16,)
-        return rb.StagedBatch(
-            i64=rng.integers(-5, 1 << 40, shape + (len(rb.I64_COLS),)),
-            i32=rng.integers(
-                -5, 1 << 20, shape + (len(rb.I32_COLS),), dtype=np.int32
-            ),
-            bools=rng.random(shape + (len(rb.BOOL_COLS),)) < 0.5,
+        want = {
+            n: rng.integers(-5, 1 << 40, shape, dtype=np.int64)
+            for n in rb.I64_COLS
+        }
+        want.update({
+            n: rng.integers(-5, 1 << 20, shape, dtype=np.int32)
+            for n in rb.I32_COLS
+        })
+        want.update({n: rng.random(shape) < 0.5 for n in rb.BOOL_COLS})
+        want.update(
             v_vt=rng.integers(0, 6, shape + (NUM_VARS,), dtype=np.int8),
             v_num=rng.random(shape + (NUM_VARS,)).astype(np.float32),
             v_str=rng.integers(0, 99, shape + (NUM_VARS,), dtype=np.int32),
         )
+        return want
 
     @pytest.mark.parametrize("lead", ((), (4,)), ids=("flat", "laned"))
     def test_views_equal_the_per_column_slices(self, lead):
-        staged = self._staged(lead)
-        want = {n: staged.i64[..., j] for j, n in enumerate(rb.I64_COLS)}
-        want.update({n: staged.i32[..., j] for j, n in enumerate(rb.I32_COLS)})
-        want.update(
-            {n: staged.bools[..., j] for j, n in enumerate(rb.BOOL_COLS)}
-        )
-        want.update(v_vt=staged.v_vt, v_num=staged.v_num, v_str=staged.v_str)
+        want = self._columns(lead)
         assert set(want) == {f.name for f in dataclasses.fields(rb.RecordBatch)}
+        # the pair, filled the way staging fills it: through its host views
+        staged = rb.host_pair(16, NUM_VARS, lead)
+        views = rb.column_views(staged)
+        for name, column in want.items():
+            getattr(views, name)[...] = column
         on_device = jax.device_put(staged)
-        assert len(jax.tree_util.tree_leaves(on_device)) == 6
+        assert len(jax.tree_util.tree_leaves(on_device)) == 2
         _assert_columns_equal(jax.jit(rb.column_views)(on_device), want)
         _assert_columns_equal(rb.column_views(on_device), want)
         _assert_columns_equal(rb.column_views(staged), want)
+        _assert_columns_equal(rb.column_views(jax.device_get(on_device)), want)
         assert rb.column_views(staged).valid.shape == lead + (16,)
+        # and back: packing the device columns gives the staged matrices
+        packed = jax.jit(rb.pack)(rb.column_views(on_device))
+        np.testing.assert_array_equal(np.asarray(packed.i32), staged.i32)
+        np.testing.assert_array_equal(np.asarray(packed.i8), staged.i8)
 
     def test_a_record_batch_passes_through(self):
         batch = rb.empty(8, NUM_VARS)
@@ -351,23 +365,35 @@ class TestColumnViews:
 
 
 class TestLaunchArguments:
-    def test_served_waves_hand_the_step_six_leaves_and_numpy_scalars(
+    def test_served_waves_hand_the_step_the_pair_and_numpy_scalars(
         self, tmp_path, monkeypatch
     ):
         """The engine calls ``kernel.step_jit`` BY ATTRIBUTE (the hook
         ``zbench/faults.py``'s ``state_unchanged`` control wraps) with the
-        staged pytree and host scalars: every device array of the call was
-        put by ``_put_staged``, and nothing is left for an eager op."""
+        staged pair and host scalars: every device array of the call was
+        put by ``_put_staged``, two of them, and nothing is left for an
+        eager op. What comes back is the emission's pair and one stats
+        vector, and no 64-bit array crosses either way."""
         from zeebe_tpu.gateway import JobWorker, ZeebeClient
         from zeebe_tpu.runtime import Broker, ControlledClock
         inner = kernel.step_jit
         calls = []
+        put = []
 
         def step_jit(graph, state, batch, now, **kw):
-            calls.append((batch, now, kw))
-            return inner(graph, state, batch, now, **kw)
+            result = inner(graph, state, batch, now, **kw)
+            calls.append((batch, now, kw, result[1:]))
+            return result
+
+        inner_put = TpuPartitionEngine._put_staged
+
+        def put_staged(self, staged, target):
+            placed = inner_put(self, staged, target)
+            put.extend(jax.tree_util.tree_leaves(placed))
+            return placed
 
         monkeypatch.setattr(kernel, "step_jit", step_jit)
+        monkeypatch.setattr(TpuPartitionEngine, "_put_staged", put_staged)
         clock = ControlledClock(start_ms=1_000_000)
         repo = WorkflowRepository()
         broker = Broker(
@@ -392,12 +418,17 @@ class TestLaunchArguments:
         finally:
             broker.close()
         assert len(calls) >= 4
-        for batch, now, kw in calls:
+        assert len(put) == 2 * len(calls)
+        for batch, now, kw, (emission, stats) in calls:
             assert isinstance(batch, rb.StagedBatch)
             leaves = jax.tree_util.tree_leaves(batch)
-            assert len(leaves) == 6
+            assert len(leaves) == 2
             assert all(isinstance(a, jax.Array) for a in leaves)
+            assert all(any(a is p for p in put) for a in leaves)
             assert type(now) is np.int64
             assert set(kw) == {"partition_id"}
             assert type(kw["partition_id"]) is np.int32
-
+            assert isinstance(emission, rb.StagedBatch)
+            assert stats.shape == (len(kernel.STATS),)
+            crossing = leaves + jax.tree_util.tree_leaves((emission, stats))
+            assert {str(a.dtype) for a in crossing} == {"int32", "int8"}

@@ -242,6 +242,70 @@ class TestBoundary:
         result = audit(passes=["boundary"], entries=[entry], budget={})
         assert result.findings == []
 
+    # -- wave_io: what a step call moves besides its state ------------------
+
+    @staticmethod
+    def _step_like(extra_out=0, wide=False):
+        """(graph, state, wave pair, now) -> (state, pair, stats[, extra])."""
+        def step(graph, state, wave, now):
+            out = (wave[0] + graph[0], wave[1])
+            stats = jnp.stack([jnp.sum(wave[0], dtype=jnp.int32)] * 2)
+            extra = tuple(
+                wave[0][:, i].astype(jnp.int64 if wide else jnp.int32)
+                for i in range(extra_out)
+            )
+            return (state + now.astype(jnp.float32), out, stats) + extra
+
+        wave = (
+            jax.ShapeDtypeStruct((16, 5), jnp.int32),
+            jax.ShapeDtypeStruct((16, 3), jnp.int8),
+        )
+        graph = (jax.ShapeDtypeStruct((5,), jnp.int32),) * 3
+        return audit_program(
+            "fixture.step", step, graph, f32(64), wave, NOW,
+            state_args=(1,), donate_argnums=(1,),
+        )
+
+    _IO_BUDGET = {"boundary": {"wave_io": {"fixture.step": {
+        "resident_args": [0], "arrays_in": 2, "arrays_out": 3,
+    }}}}
+
+    def test_pair_in_and_pair_plus_stats_out_is_quiet(self):
+        entry = self._step_like()
+        result = audit(
+            passes=["boundary"], entries=[entry], budget=self._IO_BUDGET
+        )
+        assert result.findings == []
+        assert result.report["boundary"]["fixture.step"]["wave_io"] == {
+            "arrays_in": 2, "scalars_in": 1, "arrays_out": 3, "wide": [],
+        }
+
+    @pytest.mark.parametrize("wide", [False, True], ids=["i32", "i64"])
+    def test_a_further_result_fires(self, wide):
+        entry = self._step_like(extra_out=1, wide=wide)
+        result = audit(
+            passes=["boundary"], entries=[entry], budget=self._IO_BUDGET
+        )
+        assert rules_of(result.findings) == {"boundary-wave-io"}
+        message = result.findings[0].message
+        assert "arrays_out 4 > 3" in message
+        assert ("64-bit arrays cross" in message) == wide
+
+    def test_the_served_step_programs_are_inside_the_budget(self):
+        """``kernel.step`` as lowered for the audit takes the packed pair
+        and returns the pair and one stats vector, none 64 bits wide."""
+        from tools.zbaudit import load_budget
+        from tools.zbaudit.passes import wave_io
+
+        budget = load_budget()
+        assert set(budget["boundary"]["wave_io"]) >= {"kernel.step"}
+        result = audit(passes=["boundary", "op-census"], budget=budget)
+        assert "boundary-wave-io" not in rules_of(result.findings)
+        step = next(e for e in result.entries if e.name == "kernel.step")
+        assert wave_io(step, resident_args=(0,)) == {
+            "arrays_in": 2, "scalars_in": 1, "arrays_out": 3, "wide": [],
+        }
+
 
 class TestCollectiveVolume:
     @staticmethod

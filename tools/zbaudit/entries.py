@@ -95,7 +95,8 @@ def build_entries(
             sub_capacity=8,
         )
     )
-    batch_sds = jax.eval_shape(lambda: rb.empty(wave, num_vars))
+    # a wave as the engine ships it: the packed pair
+    batch_sds = rb.pair_shapes(wave, num_vars)
     now_sds = jax.ShapeDtypeStruct((), jnp.int64)
     census_cfg = {
         "capacity": 2 * wave, "wave": wave, "num_vars": num_vars,
@@ -258,25 +259,19 @@ def build_entries(
             }
             if wanted("shard.state_step_routed"):
                 rstep = shard.build_state_step_routed(smesh, state_sds)
-                lanes_sds = jax.tree.map(
-                    lambda a: jax.ShapeDtypeStruct(
-                        (nparts,) + tuple(a.shape), a.dtype
-                    ),
-                    jax.eval_shape(lambda: rb.empty(shard_wave, num_vars)),
-                )
                 add(
                     "shard.state_step_routed", rstep,
-                    graph, state_sds, lanes_sds, now_sds, pid_sds,
+                    graph, state_sds,
+                    rb.pair_shapes(shard_wave, num_vars, (nparts,)),
+                    now_sds, pid_sds,
                     config=routed_cfg,
                 )
             if wanted("shard.state_step_fallback"):
                 fstep = shard.build_state_step_fallback(smesh, state_sds)
-                fbatch_sds = jax.eval_shape(
-                    lambda: rb.empty(shard_wave, num_vars)
-                )
                 add(
                     "shard.state_step_fallback", fstep,
-                    graph, state_sds, fbatch_sds, now_sds, pid_sds,
+                    graph, state_sds, rb.pair_shapes(shard_wave, num_vars),
+                    now_sds, pid_sds,
                     config=routed_cfg,
                 )
 

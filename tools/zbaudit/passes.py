@@ -296,7 +296,8 @@ def pass_table_i64(audited: List[AuditedEntry], budget: dict, report: dict):
             capacity=int(cfg["capacity"]), num_vars=num_vars, sub_capacity=16
         )
     )
-    batch = jax.eval_shape(lambda: rb.empty(int(cfg["wave"]), num_vars))
+    # the wave as served: the packed pair
+    batch = rb.pair_shapes(int(cfg["wave"]), num_vars)
     now = jax.ShapeDtypeStruct((), jnp.int64)
     floor = min(
         getattr(state, name).shape[0]
@@ -336,12 +337,47 @@ def pass_table_i64(audited: List[AuditedEntry], budget: dict, report: dict):
 _TRANSFER_PRIMS = ("device_put", "copy")
 
 
+def wave_io(a: AuditedEntry, resident_args=()) -> dict:
+    """What crosses the host-device boundary on every call of a step
+    program besides its state: the array parameters (rank >= 1) that are
+    neither state (``state_args``) nor resident (``resident_args``: the
+    graph), the scalar ones, and the results beyond the state's own
+    leaves; ``wide`` names the 64-bit arrays among the arrays counted."""
+    import jax
+
+    args, _kwargs = a.lowered.args_info
+    kept = set(a.entry.state_args) | set(resident_args)
+    state_leaves = sum(
+        len(jax.tree.leaves(args[i])) for i in a.entry.state_args
+    )
+    params = [
+        leaf for i, arg in enumerate(args) if i not in kept
+        for leaf in jax.tree.leaves(arg)
+    ]
+    arrays_in = [p for p in params if len(p.shape)]
+    results = jax.tree.leaves(a.lowered.out_info)[state_leaves:]
+    return {
+        "arrays_in": len(arrays_in),
+        "scalars_in": len(params) - len(arrays_in),
+        "arrays_out": len(results),
+        "wide": sorted({
+            f"{v.dtype}{list(v.shape)}" for v in arrays_in + results
+            if v.dtype.itemsize == 8 and len(v.shape)
+        }),
+    }
+
+
 def pass_boundary(audited: List[AuditedEntry], budget: dict, report: dict):
     """The host boundary of each device program: no callbacks, no
-    implicit transfers, and every state-carrying argument donated with
-    the aliasing actually materialized in the lowering."""
+    implicit transfers, every state-carrying argument donated with the
+    aliasing actually materialized in the lowering, and, for the step
+    programs budgeted under ``boundary.wave_io``, no more arrays a call
+    than the wave's packed pair in and the pair and one stats vector out
+    (a transfer costs by the array, not by the byte: PERF.md, PR 32), none
+    of them 64 bits wide."""
     findings: List[Finding] = []
     per: Dict[str, dict] = {}
+    io_budget = budget.get("boundary", {}).get("wave_io", {})
     for a in audited:
         callbacks = set()
         transfers = set()
@@ -392,6 +428,24 @@ def pass_boundary(audited: List[AuditedEntry], budget: dict, report: dict):
                 "in the lowering (outputs do not reuse the donated "
                 "buffers — shape/dtype mismatch?)",
             ))
+        io_cfg = io_budget.get(a.name)
+        if io_cfg and a.lowered is not None:
+            got = wave_io(a, io_cfg.get("resident_args", ()))
+            per[a.name]["wave_io"] = got
+            over = [
+                f"{k} {got[k]} > {io_cfg[k]}"
+                for k in ("arrays_in", "arrays_out") if got[k] > io_cfg[k]
+            ]
+            if got["wide"]:
+                over.append(f"64-bit arrays cross: {got['wide']}")
+            if over and not a.suppresses("boundary-wave-io"):
+                findings.append(a.finding(
+                    "boundary-wave-io",
+                    "a call moves more across the host boundary than the "
+                    f"wave's packed pair and a stats vector: {'; '.join(over)}"
+                    " (pack the new column into rb.StagedBatch's matrices, "
+                    "or the new count into kernel.STATS)",
+                ))
     report["boundary"] = per
     return findings
 

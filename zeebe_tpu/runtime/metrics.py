@@ -398,6 +398,16 @@ def _phase_handles() -> dict:
                 "serving_d2h_bytes_total",
                 "Bytes fetched by device_get at wave collect",
             ),
+            h2d_transfers=g.counter(
+                "serving_h2d_transfers_total",
+                "Arrays handed to device_put by wave staging (two a "
+                "segment: the packed pair)",
+            ),
+            d2h_transfers=g.counter(
+                "serving_d2h_transfers_total",
+                "Arrays fetched by device_get at wave collect (three a "
+                "segment: the emission's packed pair and the stats vector)",
+            ),
             job_row_reads=g.counter(
                 "serving_job_row_reads_total",
                 "Device job rows read back to build a record",

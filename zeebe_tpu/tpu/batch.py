@@ -227,8 +227,8 @@ def columns_to_payload(
 
 # the scalar columns' dtype families, schema-derived so a new field fails
 # loudly here instead of silently dropping: the ONE layout shared by the
-# packed row takes below and by a staged wave (``StagedBatch``), whose
-# family matrices hold these columns in this order
+# packed row takes below, the kernel's emission and a wave as it crosses the
+# host-device boundary (``StagedBatch``)
 I32_COLS = ("rtype", "vtype", "intent", "elem", "wf", "req_stream",
             "type_id", "retries", "worker", "src", "rej")
 I64_COLS = ("key", "instance_key", "scope_key", "req", "aux_key",
@@ -238,44 +238,134 @@ assert set(I32_COLS + I64_COLS + BOOL_COLS
            + ("v_vt", "v_num", "v_str")) == set(_FIELDS)
 
 
+def packed_widths(num_vars: int) -> Tuple[int, int]:
+    """Last-axis widths of a packed wave's ``(i32, i8)`` matrices."""
+    return (
+        len(I32_COLS) + 2 * num_vars + 2 * len(I64_COLS),
+        len(BOOL_COLS) + num_vars,
+    )
+
+
 @partial(
-    jax.tree_util.register_dataclass,
-    data_fields=["i64", "i32", "bools", "v_vt", "v_num", "v_str"],
+    jax.tree_util.register_dataclass, data_fields=["i32", "i8"],
     meta_fields=[],
 )
 @dataclasses.dataclass
 class StagedBatch:
-    """A wave as it crosses to the device: one matrix per dtype family
-    (six leaves, one transfer each) instead of one array per column. Any
-    leading dims (the routed ``[num_shards]`` lane dim) ride along."""
+    """A wave as it crosses the host-device boundary, either way: ONE
+    packed pair (two leaves, one transfer each) instead of one array per
+    column. No 64-bit array crosses: a 64-bit column is a little-endian
+    lo/hi pair of i32 planes, ``v_num`` its own bits. Any leading dims
+    (the routed ``[num_shards]`` lane dim) ride along.
 
-    i64: jax.Array    # [.., B, len(I64_COLS)] i64
-    i32: jax.Array    # [.., B, len(I32_COLS)] i32
-    bools: jax.Array  # [.., B, len(BOOL_COLS)] bool
-    v_vt: jax.Array   # [.., B, V] i8
-    v_num: jax.Array  # [.., B, V] f32
-    v_str: jax.Array  # [.., B, V] i32
+    ``i32``: ``I32_COLS``, then ``v_str`` [V], ``v_num`` bit for bit [V],
+    then ``I64_COLS`` as plane pairs; ``i8``: ``BOOL_COLS``, then ``v_vt``
+    [V]. ``column_views`` gives the columns by name, on either side."""
+
+    i32: jax.Array  # [.., B, len(I32_COLS) + 2V + 2 len(I64_COLS)] i32
+    i8: jax.Array   # [.., B, len(BOOL_COLS) + V] i8
+
+    @property
+    def size(self) -> int:
+        return self.i32.shape[-2]
+
+    @property
+    def num_vars(self) -> int:
+        return self.i8.shape[-1] - len(BOOL_COLS)
+
+
+def host_pair(size: int, num_vars: int, lead: tuple = ()) -> StagedBatch:
+    """An all-zero packed wave of numpy matrices, to fill through
+    ``column_views``."""
+    w32, w8 = packed_widths(num_vars)
+    return StagedBatch(
+        i32=np.zeros(lead + (size, w32), np.int32),
+        i8=np.zeros(lead + (size, w8), np.int8),
+    )
+
+
+def pair_shapes(size: int, num_vars: int, lead: tuple = ()) -> StagedBatch:
+    """A packed wave's abstract form (``jax.ShapeDtypeStruct`` leaves):
+    what the compile checks and the IR audit lower the step programs with."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+        host_pair(size, num_vars, lead),
+    )
 
 
 def column_views(batch) -> RecordBatch:
-    """The ``RecordBatch`` of a staged wave's column views (last-axis
-    slices of its family matrices); a ``RecordBatch`` passes through.
-    The step program calls this at trace time, so the slices are part of
-    the compiled program and the host launches none."""
+    """The ``RecordBatch`` of a packed wave's column views (last-axis
+    slices of its two matrices, the 64-bit columns and ``v_num`` as the
+    same bits under their own dtype); a ``RecordBatch`` passes through.
+
+    Of numpy matrices the views are numpy VIEWS: what is written through
+    them lands in the matrices (staging fills a wave this way), and a
+    fetched emission is decoded without a copy of its columns. One
+    exception: a TPU hands a fetched ``[B, W]`` matrix over column-major,
+    where a 64-bit column's two planes are not adjacent in memory, so of
+    a matrix that is not row-major the 64-bit columns are made from a
+    row-major copy of the plane block (``14 x B`` words; read-only use).
+    Of device arrays, or under a trace, the views are slices and bitcasts
+    of the program: the step program calls this at trace time, so the host
+    launches none."""
     if isinstance(batch, RecordBatch):
         return batch
-    kw = {n: batch.i64[..., j] for j, n in enumerate(I64_COLS)}
-    kw.update({n: batch.i32[..., j] for j, n in enumerate(I32_COLS)})
-    kw.update({n: batch.bools[..., j] for j, n in enumerate(BOOL_COLS)})
+    m32, m8 = batch.i32, batch.i8
+    v = batch.num_vars
+    n32, n64, nb = len(I32_COLS), len(I64_COLS), len(BOOL_COLS)
+    str_at, num_at, i64_at = n32, n32 + v, n32 + 2 * v
+    num_bits = m32[..., num_at:i64_at]
+    planes = m32[..., i64_at : i64_at + 2 * n64]
+    if isinstance(m32, np.ndarray):
+        v_num = num_bits.view(np.float32)
+        if planes.strides[-1] != planes.itemsize:
+            planes = np.ascontiguousarray(planes)
+        i64 = planes.view(np.int64)
+        flags = m8[..., :nb].view(np.bool_)
+    else:
+        v_num = jax.lax.bitcast_convert_type(num_bits, jnp.float32)
+        i64 = jax.lax.bitcast_convert_type(
+            planes.reshape(planes.shape[:-1] + (n64, 2)), jnp.int64
+        )
+        flags = m8[..., :nb] != 0
+    kw = {n: m32[..., j] for j, n in enumerate(I32_COLS)}
+    kw.update({n: i64[..., j] for j, n in enumerate(I64_COLS)})
+    kw.update({n: flags[..., j] for j, n in enumerate(BOOL_COLS)})
     return RecordBatch(
-        v_vt=batch.v_vt, v_num=batch.v_num, v_str=batch.v_str, **kw
+        v_vt=m8[..., nb:], v_num=v_num, v_str=m32[..., str_at:num_at], **kw
+    )
+
+
+def pack(batch: RecordBatch) -> StagedBatch:
+    """The packed pair of a device ``RecordBatch`` (``column_views``'
+    inverse, exact: bitcasts and a bool widening)."""
+    i64 = jnp.stack([getattr(batch, n) for n in I64_COLS], axis=-1)
+    return StagedBatch(
+        i32=jnp.concatenate(
+            [jnp.stack([getattr(batch, n) for n in I32_COLS], axis=-1),
+             batch.v_str,
+             jax.lax.bitcast_convert_type(batch.v_num, jnp.int32),
+             jax.lax.bitcast_convert_type(i64, jnp.int32).reshape(
+                 i64.shape[:-1] + (2 * len(I64_COLS),)
+             )],
+            axis=-1,
+        ),
+        i8=jnp.concatenate(
+            [jnp.stack(
+                [getattr(batch, n).astype(jnp.int8) for n in BOOL_COLS],
+                axis=-1,
+            ),
+             batch.v_vt],
+            axis=-1,
+        ),
     )
 
 
 def take_rows(batch: RecordBatch, idx: jax.Array) -> RecordBatch:
     """``batch[idx]`` (row take along axis 0) as TWO packed row gathers
-    instead of one per field: an i32 mega-matrix (i32 scalars + v_str +
-    bitcast v_num + i64 lo/hi planes) and an i8 matrix (bool flags + v_vt).
+    instead of one per field: the packed pair's i32 matrix (i32 scalars +
+    v_str + bitcast v_num + i64 lo/hi planes) and its i8 matrix (bool
+    flags + v_vt).
     A gather costs per-index issue, not bytes (PERF_NOTES round-4 cost
     model), so the naive per-field tree.map paid ~24 serial gathers where
     2 suffice. Bitcast/widen round-trips are exact — the result is
@@ -284,42 +374,13 @@ def take_rows(batch: RecordBatch, idx: jax.Array) -> RecordBatch:
     mega-pass picks them up on TPU."""
     from zeebe_tpu.tpu import pallas_ops as pops
 
-    v = batch.num_vars
-    i32_mat = jnp.concatenate(
-        [jnp.stack([getattr(batch, n) for n in I32_COLS], axis=-1),
-         batch.v_str,
-         jax.lax.bitcast_convert_type(batch.v_num, jnp.int32),
-         pops.i64_to_planes(
-             jnp.stack([getattr(batch, n) for n in I64_COLS], axis=-1)
-         )],
-        axis=1,
-    )
-    i8_mat = jnp.concatenate(
-        [jnp.stack([getattr(batch, n).astype(jnp.int8) for n in BOOL_COLS],
-                   axis=-1),
-         batch.v_vt],
-        axis=1,
-    )
+    packed = pack(batch)
     t32, t8 = pops.fused_gather_rows(
-        [i32_mat, i8_mat],
+        [packed.i32, packed.i8],
         [pops.GatherOp(0, idx), pops.GatherOp(1, idx)],
         family="emit",
     )
-    n32 = len(I32_COLS)
-    i64_mat = pops.planes_to_i64(t32[:, n32 + 2 * v :])
-    out = {n: t32[:, i] for i, n in enumerate(I32_COLS)}
-    out.update({n: i64_mat[:, i] for i, n in enumerate(I64_COLS)})
-    out.update(
-        valid=t8[:, 0].astype(bool),
-        resp=t8[:, 1].astype(bool),
-        push=t8[:, 2].astype(bool),
-        v_vt=t8[:, 3:],
-        v_str=t32[:, n32 : n32 + v],
-        v_num=jax.lax.bitcast_convert_type(
-            t32[:, n32 + v : n32 + 2 * v], jnp.float32
-        ),
-    )
-    return RecordBatch(**out)
+    return column_views(StagedBatch(i32=t32, i8=t8))
 
 
 def compact(batch: RecordBatch) -> RecordBatch:

@@ -42,7 +42,7 @@ from zeebe_tpu.tpu import batch as rb
 from zeebe_tpu.tpu import jit_registry
 from zeebe_tpu.tpu.batch import RecordBatch
 from zeebe_tpu.tpu.graph import DeviceGraph
-from zeebe_tpu.tpu.kernel import step_kernel
+from zeebe_tpu.tpu.kernel import stats_of, step_kernel
 from zeebe_tpu.tpu.state import EngineState
 
 
@@ -138,9 +138,9 @@ def drive_round(
     state, out, stats = step_kernel(
         graph, state, batch, now, synthetic_workers=synthetic_workers
     )
-    queue = enqueue(queue, out)
-    stats = dict(stats)
-    stats["overflow"] = stats["overflow"] | queue.overflow
+    queue = enqueue(queue, rb.column_views(out))
+    stats = stats_of(stats)
+    stats["overflow"] = (stats["overflow"] != 0) | queue.overflow
     return state, queue, stats
 
 
@@ -180,7 +180,8 @@ def _quiesce_device_fn(graph, state, queue, now, batch_size, synthetic_workers, 
         s, out, stats = step_kernel(
             graph, s, batch, now, synthetic_workers=synthetic_workers
         )
-        q = enqueue(q, out)
+        q = enqueue(q, rb.column_views(out))
+        stats = stats_of(stats)
         t = {
             "processed": t["processed"] + stats["processed"].astype(jnp.int64),
             "emitted": t["emitted"] + stats["emitted"].astype(jnp.int64),

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -246,6 +247,29 @@ def _count_staged_columnar(n: int = 1) -> None:
     _staged_columnar_counter.inc(n)
 
 
+# staging defaults of the scalar columns (an all-default row is an invalid
+# row; the payload columns default to zeros)
+_COL_DEFAULTS = {
+    "valid": False, "rtype": 0, "vtype": 0, "intent": 0, "key": -1,
+    "elem": -1, "wf": -1, "instance_key": -1, "scope_key": -1,
+    "req": -1, "req_stream": -1, "aux_key": -1, "aux2_key": -1,
+    "type_id": 0, "retries": 0, "deadline": -1, "worker": 0,
+    "src": -1, "resp": False, "push": False, "rej": 0,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _default_row(num_vars: int) -> rb.StagedBatch:
+    """One all-default row of a packed wave, written through the column
+    views: what a wave's two host matrices are filled with, one broadcast
+    each, before its rows are staged."""
+    row = rb.host_pair(1, num_vars)
+    views = rb.column_views(row)
+    for name, default in _COL_DEFAULTS.items():
+        getattr(views, name)[...] = default
+    return row
+
+
 @dataclasses.dataclass
 class _PendingSegment:
     """One dispatched (not yet collected) device segment of a wave."""
@@ -256,8 +280,8 @@ class _PendingSegment:
     suppress: set             # segment-record indices with host-emitted
                               # job-incident follow-ups (kernel copy drops)
     rows: List[int] = dataclasses.field(default_factory=list)
-    out: Optional[RecordBatch] = None   # device emission batch (unfetched)
-    stats: Optional[dict] = None        # device stats (unfetched)
+    out: Optional[rb.StagedBatch] = None  # device emission pair (unfetched)
+    stats: Optional[jax.Array] = None   # device stats vector (unfetched)
     route_owner: Optional[int] = None   # routed wave's owner shard (v2)
     seq: int = -1                       # dispatch order (residency ordering)
     fb_pop: bool = False                # gathered fallback under routing:
@@ -2519,16 +2543,6 @@ class TpuPartitionEngine:
     # -- host record → batch row -------------------------------------------
     _TPU_BATCH = 512  # one canonical staged shape on TPU (= drain chunk)
 
-    # staging defaults of the scalar columns (an all-default row is an
-    # invalid row); the family each column rides in is ``rb.*_COLS``
-    _COL_DEFAULTS = {
-        "valid": False, "rtype": 0, "vtype": 0, "intent": 0, "key": -1,
-        "elem": -1, "wf": -1, "instance_key": -1, "scope_key": -1,
-        "req": -1, "req_stream": -1, "aux_key": -1, "aux2_key": -1,
-        "type_id": 0, "retries": 0, "deadline": -1, "worker": 0,
-        "src": -1, "resp": False, "push": False, "rej": 0,
-    }
-
     def _stage(
         self, records: List[Record], pad_to: int = 0, lane_owner=None
     ) -> rb.StagedBatch:
@@ -2547,33 +2561,27 @@ class TpuPartitionEngine:
             pad_to = max(pad_to, self._TPU_BATCH)
         size = max(_pow2(n), pad_to)
         v = self.num_vars
-        # the wave is filled where it ships from: one host matrix per dtype
-        # family, its defaults written with one broadcast. A routed wave
-        # (``lane_owner``, sharded-state v2) gets a leading [num_shards]
+        # the wave is filled where it ships from: the packed pair's two host
+        # matrices, their defaults written with one broadcast each. A routed
+        # wave (``lane_owner``, sharded-state v2) gets a leading [num_shards]
         # lane dim: the owner's lane takes the rows, every other lane
         # keeps the all-invalid defaults.
         lead = () if lane_owner is None else (self._state_shards,)
-
-        def family(names, dtype):
-            mat = np.empty(lead + (size, len(names)), dtype)
-            mat[...] = [self._COL_DEFAULTS[name] for name in names]
-            return mat
-
+        row = _default_row(v)
         staged = rb.StagedBatch(
-            i64=family(rb.I64_COLS, np.int64),
-            i32=family(rb.I32_COLS, np.int32),
-            bools=family(rb.BOOL_COLS, bool),
-            v_vt=np.zeros(lead + (size, v), np.int8),
-            v_num=np.zeros(lead + (size, v), np.float32),
-            v_str=np.zeros(lead + (size, v), np.int32),
+            i32=np.empty(lead + (size, row.i32.shape[-1]), np.int32),
+            i8=np.empty(lead + (size, row.i8.shape[-1]), np.int8),
         )
+        staged.i32[...] = row.i32
+        staged.i8[...] = row.i8
         lane = (
             staged if lane_owner is None
             else jax.tree.map(lambda a: a[lane_owner], staged)
         )
         # the columns by name, as numpy VIEWS of the matrices (the same
-        # slicing the step program does on the device): the row and column
-        # writers below fill the matrices through them
+        # slicing the step program does on the device; a 64-bit column is
+        # an int64 view of its two planes): the row and column writers
+        # below fill the matrices through them
         views = rb.column_views(lane)
         cols = {
             f.name: getattr(views, f.name) for f in dataclasses.fields(views)
@@ -2640,14 +2648,14 @@ class TpuPartitionEngine:
         self, staged: rb.StagedBatch, cols: Dict[str, np.ndarray],
         lane_owner=None,
     ) -> rb.StagedBatch:
-        """The filled host matrices → the wave on the device: one
-        device_put per dtype family and nothing else — the step program
-        takes the column views itself (``rb.column_views``), so no device
-        op runs between the fill and the launch.
+        """The filled host matrices → the wave on the device: the packed
+        pair's two arrays and nothing else — the step program takes the
+        column views itself (``rb.column_views``), so no device op runs
+        between the fill and the launch.
 
         A routed wave (``lane_owner``) is put lane-sharded over the mesh
         axis, so each device receives ONLY its own lane while the
-        transfer count stays one per dtype family."""
+        transfer count stays two."""
         # sharded-state routing accounting: record the staged row split
         # (residency basis: instance_key in resident mode, advisory key
         # hash otherwise) and the valid count — _run_step observes them
@@ -2668,33 +2676,28 @@ class TpuPartitionEngine:
         # what routes the step program to it); sharded mode replicates
         # them over the span via _place-style NamedSharding (lane-sharded
         # in routed staging); default device otherwise
+        target = self.device
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             from zeebe_tpu.tpu import shard as shard_mod
 
-            sharding = NamedSharding(
+            target = NamedSharding(
                 self._mesh,
                 PartitionSpec() if lane_owner is None
                 else PartitionSpec(shard_mod.STATE_AXIS),
             )
-            put = lambda a: jax.device_put(a, sharding)  # noqa: E731
-        else:
-            put = (
-                jnp.asarray if self.device is None
-                else (lambda a: jax.device_put(a, self.device))
-            )
-        leaves, treedef = jax.tree_util.tree_flatten(staged)
-        return jax.tree_util.tree_unflatten(
-            treedef, self._put_staged(put, leaves)
-        )
+        return self._put_staged(staged, target)
 
-    def _put_staged(self, put, arrays: list) -> list:
-        """The wave's host->device transfers: one ``put`` per staged family
-        matrix, as phase ``h2d`` of the wave being dispatched."""
+    def _put_staged(self, staged: rb.StagedBatch, target) -> rb.StagedBatch:
+        """The wave's host->device transfers: the packed pair's two arrays
+        in one ``device_put``, as phase ``h2d`` of the wave being
+        dispatched."""
         clock = self._clock
         with clock.phase("h2d"):
-            placed = [put(a) for a in arrays]
+            placed = jax.device_put(staged, target)
+        arrays = jax.tree_util.tree_leaves(staged)
         clock.count("h2d_bytes", sum(a.nbytes for a in arrays))
+        clock.count("h2d_transfers", len(arrays))
         return placed
 
     def warm(self, sizes=(512,)) -> None:
@@ -3116,25 +3119,27 @@ class TpuPartitionEngine:
         return seg
 
     def _collect_device(self, seg: _PendingSegment, clock) -> None:
-        """Synchronize on one dispatched segment: overflow check + ONE
-        bulk device→host fetch of the whole emission batch, then columnar
-        decode into the segment's per-record results. The first sync is
-        the wave's phase ``blocked`` (host time waiting for the device),
-        the fetch its ``readback``; the decode runs in the caller's
-        phase."""
+        """Synchronize on one dispatched segment: wait for its step (phase
+        ``blocked``: host time waiting for the device, no transfer), then
+        ONE device→host fetch of the emission's packed pair and the stats
+        vector (phase ``readback``), the overflow check on the fetched
+        vector, and the columnar decode of the pair's host column views
+        into the segment's per-record results, in the caller's phase."""
         if seg.out is None:
             return
         with clock.phase("blocked"):
-            overflow = bool(seg.stats["overflow"])
-        if overflow:
+            jax.block_until_ready(seg.stats)
+        with clock.phase("readback"):
+            fetched = jax.device_get((seg.out, seg.stats))
+        arrays = jax.tree_util.tree_leaves(fetched)
+        clock.count("d2h_bytes", sum(a.nbytes for a in arrays))
+        clock.count("d2h_transfers", len(arrays))
+        packed, stats = fetched
+        if kernel.stats_of(stats)["overflow"]:
             raise RuntimeError(
                 "device table overflow — raise TpuPartitionEngine capacity"
             )
-        with clock.phase("readback"):
-            o = jax.device_get(seg.out)
-        clock.count(
-            "d2h_bytes", sum(a.nbytes for a in jax.tree_util.tree_leaves(o))
-        )
+        o = rb.column_views(packed)
         # collection is one-shot: clear the device refs BEFORE decoding so
         # a re-collect of this wave (the drain's finally path after an
         # exception elsewhere) can never append duplicate emissions into
@@ -3180,7 +3185,7 @@ class TpuPartitionEngine:
         live_rows: List[int],
         suppress_incident_create: "set | None" = None,
     ) -> None:
-        """Decode one emission batch (``out``: np-array RecordBatch — the
+        """Decode one emission batch (``out``: the host column views of the
         caller's single bulk ``device_get``) into Record objects. Columnar:
         scalar columns convert to Python lists ONCE (`.tolist()`); rows
         materialize lazily from those lists only up to the valid count."""

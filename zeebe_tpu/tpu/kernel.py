@@ -349,23 +349,36 @@ def scope_to_global(ei_i32, prev_global_scope, shard_index, local_rows):
     return ei_i32.at[:, EI_SCOPE].set(back.astype(ei_i32.dtype))
 
 
+# the entries of a step's stats vector (i32), in order
+STATS = ("processed", "stepped", "emitted", "completed_roots", "overflow")
+
+
+def stats_of(stats) -> dict:
+    """A step's stats vector by name (device scalars or, of a fetched
+    vector, numpy ones); ``overflow`` is 0 or 1."""
+    return {name: stats[..., i] for i, name in enumerate(STATS)}
+
+
 def step_kernel(
-    graph: DeviceGraph, state: EngineState, batch: RecordBatch, now,
+    graph: DeviceGraph, state: EngineState, batch, now,
     synthetic_workers: bool = False, partition_id=0,
-) -> Tuple[EngineState, RecordBatch, dict]:
+) -> Tuple[EngineState, rb.StagedBatch, jax.Array]:
     """Process one committed-record batch; returns (state', emissions, stats).
 
-    Emissions are compacted in oracle append order; ``emissions.src`` links
-    each emission to its source row (host assigns positions/responses).
+    Emissions are compacted in oracle append order and leave as ONE packed
+    pair (``rb.StagedBatch``; ``rb.column_views`` gives the columns):
+    ``src`` links each emission to its source row (host assigns
+    positions/responses), the first ``emitted`` rows are valid. ``stats``
+    is one i32 vector, ``STATS`` its entries.
 
     ``synthetic_workers`` (static, bench-only): every ACTIVATED push also
     emits an instant COMPLETE command — the worker round-trip of
     ``gateway/.../impl/subscription/job/JobSubscriber.java:51`` without
     leaving the device.
 
-    ``batch`` is a ``RecordBatch`` or a staged wave (``rb.StagedBatch``,
-    what the serving engine transfers): the column views of the latter
-    are taken here, inside the program.
+    ``batch`` is a ``RecordBatch`` or a packed wave (``rb.StagedBatch``,
+    what the serving engine transfers, and what a step emits): the column
+    views of the latter are taken here, inside the program.
     """
     batch = rb.column_views(batch)
     b = batch.size
@@ -2511,23 +2524,21 @@ def step_kernel(
     # family — the per-dtype-group takes before this dominated the
     # emission tail at ~20ns/record of per-index issue apiece. The
     # bitcast/widen round-trips are exact, so the packed take is
-    # bit-identical to per-field takes.
-    i32_names = ["rtype", "vtype", "intent", "elem", "wf", "req_stream",
-                 "type_id", "retries", "worker", "src", "rej"]
-    i64_names = ["key", "instance_key", "scope_key", "req", "aux_key",
-                 "aux2_key", "deadline"]
+    # bit-identical to per-field takes. The gathered pair IS the emission
+    # as it leaves the program (``rb.StagedBatch``'s layout): nothing is
+    # cut into columns again on the way out.
 
     def _flat(n):
         return em[n].reshape((be,) + em[n].shape[2:])
 
     with jax.named_scope("zb_emit"):
         i32_mat = jnp.concatenate(
-            [jnp.stack([_flat(n).astype(jnp.int32) for n in i32_names],
+            [jnp.stack([_flat(n).astype(jnp.int32) for n in rb.I32_COLS],
                        axis=-1),
              _flat("v_str"),
              jax.lax.bitcast_convert_type(_flat("v_num"), jnp.int32),
              pops.i64_to_planes(
-                 jnp.stack([_flat(n) for n in i64_names], axis=-1)
+                 jnp.stack([_flat(n) for n in rb.I64_COLS], axis=-1)
              )],
             axis=1,
         )
@@ -2542,41 +2553,15 @@ def step_kernel(
             [pops.GatherOp(0, idx), pops.GatherOp(1, idx)],
             family="emit",
         )
-    n32 = len(i32_names)
-    i32 = {n: taken_i32[:, i] for i, n in enumerate(i32_names)}
-    i64_mat = pops.planes_to_i64(
-        taken_i32[:, n32 + 2 * v : n32 + 2 * v + 2 * len(i64_names)]
-    )
-    i64 = {n: i64_mat[:, i] for i, n in enumerate(i64_names)}
-    flags = {"resp": taken_i8[:, 0], "push": taken_i8[:, 1]}
-
-    out = RecordBatch(
-        valid=jnp.arange(be, dtype=jnp.int32) < count,
-        rtype=i32["rtype"],
-        vtype=i32["vtype"],
-        intent=i32["intent"],
-        key=i64["key"],
-        elem=i32["elem"],
-        wf=i32["wf"],
-        instance_key=i64["instance_key"],
-        scope_key=i64["scope_key"],
-        v_vt=taken_i8[:, 2:],
-        v_num=jax.lax.bitcast_convert_type(
-            taken_i32[:, n32 + v : n32 + 2 * v], jnp.float32
+    # ``valid`` is the compacted prefix: the flag column written from the count
+    out = rb.StagedBatch(
+        i32=taken_i32,
+        i8=jnp.concatenate(
+            [(jnp.arange(be, dtype=jnp.int32) < count)
+             .astype(jnp.int8)[:, None],
+             taken_i8],
+            axis=1,
         ),
-        v_str=taken_i32[:, n32 : n32 + v],
-        req=i64["req"],
-        req_stream=i32["req_stream"],
-        aux_key=i64["aux_key"],
-        aux2_key=i64["aux2_key"],
-        type_id=i32["type_id"],
-        retries=i32["retries"],
-        deadline=i64["deadline"],
-        worker=i32["worker"],
-        src=i32["src"],
-        resp=flags["resp"].astype(bool),
-        push=flags["push"].astype(bool),
-        rej=i32["rej"],
     )
 
     new_state = EngineState(
@@ -2604,21 +2589,21 @@ def step_kernel(
         sub_rr=state.sub_rr,
         next_wf_key=next_wf_key, next_job_key=next_job_key,
     )
-    stats = {
-        "processed": jnp.sum(valid, dtype=jnp.int32),
-        "stepped": jnp.sum(stepped, dtype=jnp.int32)
+    # one small vector (``STATS`` names its entries), so a collect fetches
+    # the wave's counts and its overflow flag as one array
+    stats = jnp.stack([
+        jnp.sum(valid, dtype=jnp.int32),
+        jnp.sum(stepped, dtype=jnp.int32)
         + jnp.sum(job_cmd | job_ev | timer_cmd | m_create | m_created_ev
                   | msg_pub | msg_del | ms_open | ms_close | wisub_corr,
                   dtype=jnp.int32),
-        "emitted": count,
-        "completed_roots": jnp.sum(
-            m_complete_proc & (batch.elem == 0), dtype=jnp.int32
-        ),
-        "overflow": (
+        count,
+        jnp.sum(m_complete_proc & (batch.elem == 0), dtype=jnp.int32),
+        (
             ei_overflow | job_overflow | join_overflow | timer_overflow
             | message_overflow
-        ),
-    }
+        ).astype(jnp.int32),
+    ])
     return new_state, out, stats
 
 

@@ -35,7 +35,7 @@ from zeebe_tpu.tpu import jit_registry
 from zeebe_tpu.tpu import state as state_mod
 from zeebe_tpu.tpu.batch import RecordBatch
 from zeebe_tpu.tpu.graph import DeviceGraph
-from zeebe_tpu.tpu.kernel import step_kernel
+from zeebe_tpu.tpu.kernel import stats_of, step_kernel
 from zeebe_tpu.tpu.state import EngineState, corr_composite
 
 # partition id lives in the key's high bits (reference Protocol.java keeps
@@ -140,11 +140,12 @@ def build_sharded_step(mesh: Mesh, exchange_slots: int = 128):
         batch = _squeeze(batch)
         sends = _squeeze(sends)  # [P, S, ...] rows addressed per destination
         state, out, stats = step_kernel(graph, state, batch, now)
+        out = rb.column_views(out)
         # subscription-transport hop: deliver each partition its inbound rows
         sends_in = jax.tree.map(
             lambda a: jax.lax.all_to_all(a, axis, 0, 0), sends
         )
-        total = jax.lax.psum(stats["processed"], axis)
+        total = jax.lax.psum(stats_of(stats)["processed"], axis)
         pending = jax.lax.psum(
             jnp.sum(out.valid, dtype=jnp.int32)
             + jnp.sum(sends_in.valid, dtype=jnp.int32),
@@ -342,6 +343,8 @@ def build_sharded_drive(
                 graph, s, batch, now, synthetic_workers=synthetic_workers,
                 partition_id=my_pid,
             )
+            out = rb.column_views(out)
+            stats = stats_of(stats)
             xover = jnp.zeros((), bool)
             if graph.has_messages and nparts > 1:
                 target = correlation_route(out, nparts, my_pid)
@@ -398,7 +401,8 @@ def build_sharded_drive(
                 # overflow anywhere aborts everywhere (lockstep)
                 "overflow": t["overflow"]
                 | (jax.lax.psum(
-                    (stats["overflow"] | q.overflow | xover).astype(jnp.int32),
+                    ((stats["overflow"] != 0) | q.overflow | xover)
+                    .astype(jnp.int32),
                     axis,
                 ) > 0),
             }
@@ -835,10 +839,10 @@ def build_state_step_routed(mesh: Mesh, state_template: EngineState):
       (graph, state, lanes, now, partition_id) → (state', out, stats)
 
     ``state`` arrives sharded per ``state_partition_specs`` exactly like
-    ``shard.state_step``; ``lanes`` is a staged wave (or a RecordBatch)
+    ``shard.state_step``; ``lanes`` is a packed wave (or a RecordBatch)
     with a leading ``[num_shards]`` lane dim, sharded over the mesh axis,
-    so each device receives ONLY its own routed rows (one host→device put
-    per dtype family covers all lanes). Each shard translates the
+    so each device receives ONLY its own routed rows (the host→device put
+    of the pair's two matrices covers all lanes). Each shard translates the
     parent-slot column into its local row space, rebuilds the lookup
     structures from its own block, and steps the UNMODIFIED kernel on
     local rows + local lane — no table gather anywhere in the lowering.
@@ -894,11 +898,10 @@ def build_state_step_routed(mesh: Mesh, state_template: EngineState):
             else:
                 rec.append(_delta_psum(nl, ol, mine, axis))
         new_state = jax.tree_util.tree_unflatten(treedef, rec)
+        # the packed pair reduces exactly: every plane is an integer, and
+        # only the owner lane's term is not zero
         out = jax.tree.map(lambda a: _psum_masked(a, mine, axis), out)
-        stats = {
-            k: _psum_masked(v, mine, axis) for k, v in stats.items()
-        }
-        return new_state, out, stats
+        return new_state, out, _psum_masked(stats, mine, axis)
 
     fn = jax.shard_map(
         shard_fn,
