@@ -294,7 +294,7 @@ def _population(seed):
     broker.run_until_idle()
     assert len(engine._parked) == 14 and not engine._assigning
     waiting = sorted(
-        k for k, (_t, held) in engine._parked.items()
+        k for k, (_t, held, _since) in engine._parked.items()
         if held.type == "payment-service" and k not in active
     )
     broker.partitions[0].log.append([_job_command(JI.CANCEL, waiting[1])])
@@ -307,7 +307,240 @@ def _population(seed):
     return broker, client, clock, engine, active
 
 
+PAY, INV, SHP = 21, 22, 23  # subscriber keys of the quickstart's three workers
+QUICKSTART_TYPES = {
+    PAY: "payment-service", INV: "inventory-service", SHP: "shipment-service",
+}
+
+
+def _quickstart_population(seed):
+    """The quickstart's order process (three service tasks in sequence,
+    ``zbench/processes/order_quickstart.py``) with one subscription a job
+    type at the client's default of 32 credits, driven until jobs of all
+    three types wait parked with their keys interleaved: payment jobs of
+    the first burst, inventory and shipment jobs made while their
+    subscriptions were dry, then payment jobs of a later burst. The
+    workers' credits are returned by the caller, never here."""
+    import importlib
+
+    rng = random.Random(seed)
+    broker, client, clock, engine = _device_broker(capacity=1 << 11)
+    client.deploy_model(
+        importlib.import_module("zbench.processes.order_quickstart").build()
+    )
+    for key, job_type in QUICKSTART_TYPES.items():
+        _subscribe(broker, engine, key, job_type, 32)
+
+    def start(n, first):
+        for i in range(first, first + n):
+            client.create_instance("order-quickstart", payload={"orderId": i})
+        broker.run_until_idle()
+
+    def active(job_type):
+        done = {r.key for r in _job_events(broker, JI.COMPLETED)}
+        return [
+            r.key for r in _job_events(broker, JI.ACTIVATED)
+            if r.value.type == job_type and r.key not in done
+        ]
+
+    def complete(job_type, n):
+        keys = active(job_type)
+        rng.shuffle(keys)
+        for key in keys[:n]:
+            client.complete_job(key, {"done": True})
+        broker.run_until_idle()
+
+    start(70, 0)  # 32 payments activated by the pool, 38 parked
+    assert len(engine._parked) == 38 and not engine._assigning
+    complete("payment-service", 32)  # 32 inventory jobs take every credit
+    clock.advance(40)
+    engine.increase_job_credits(PAY, 24)
+    broker.tick()
+    broker.run_until_idle()  # 24 parked payments leave with the sweep
+    assert len(engine._parked) == 14
+    complete("payment-service", 24)  # their inventory jobs find no credit
+    assert len(engine._parked) == 14 + 24
+    complete("inventory-service", 32)  # 32 shipments take every credit
+    clock.advance(40)
+    engine.increase_job_credits(INV, 20)
+    broker.tick()
+    broker.run_until_idle()
+    complete("inventory-service", 20)  # their shipments find no credit
+    clock.advance(40)
+    start(9, 70)  # later payments: their keys follow the others'
+    parked_types = [
+        held.type for _k, (_t, held, _since) in sorted(engine._parked.items())
+    ]
+    assert sorted(set(parked_types)) == sorted(QUICKSTART_TYPES.values())
+    # interleaved: sorted by key, the types change more often than twice
+    assert sum(a != b for a, b in zip(parked_types, parked_types[1:])) > 2
+    assert not engine._assigning
+    return broker, client, clock, engine, complete
+
+
 class TestParkedSet:
+    @pytest.mark.parametrize("seed", [11, 12, 13])
+    def test_three_types_at_32_credits_sweep_equals_table_scan(self, seed):
+        """The quickstart's deployment (ISSUE 33): three job types, 32
+        credits a subscription, parked keys interleaved across the types.
+        Every sweep hands out what a scan of the table would, in key order,
+        each type from its own credits; every job is activated once."""
+        broker, _client, clock, engine, complete = _quickstart_population(seed)
+        try:
+            parked = dict(engine._parked)
+            handed = []
+            for credits in ((5, 0, 3), (0, 7, 32), (32, 32, 32), (32, 32, 32)):
+                for key, n in zip((PAY, INV, SHP), credits):
+                    if n:
+                        engine.increase_job_credits(key, n)
+                clock.advance(100)
+                (a, left_a), (b, left_b) = _sweep_both_ways(engine)
+                assert a == b and left_a == left_b
+                assert [r.key for r in a] == sorted(r.key for r in a)
+                by_type = {t: 0 for t in QUICKSTART_TYPES.values()}
+                for r in a:
+                    assert r.value.type == parked[r.key][1].type
+                    assert r.metadata.request_stream_id == next(
+                        k for k, t in QUICKSTART_TYPES.items() if t == r.value.type
+                    )
+                    by_type[r.value.type] += 1
+                gone = {h.key for h in handed}
+                waiting = {t: 0 for t in QUICKSTART_TYPES.values()}
+                for k, (_t, held, _since) in parked.items():
+                    waiting[held.type] += k not in gone
+                assert by_type == {
+                    t: min(n, waiting[t])
+                    for t, n in zip(QUICKSTART_TYPES.values(), credits)
+                }
+                handed += a
+                broker.partitions[0].log.append(a)
+                broker.run_until_idle()
+                assert not engine._assigning
+                # the workers answer: what this makes next finds no credit
+                # (every one is out) and parks behind the others
+                for job_type in QUICKSTART_TYPES.values():
+                    complete(job_type, 1 << 10)
+                parked.update(engine._parked)
+            assert len(handed) == len({r.key for r in handed})
+            activated = [r.key for r in _job_events(broker, JI.ACTIVATED)]
+            assert len(activated) == len(set(activated))
+            rejected = [
+                r for r in broker.records(0)
+                if r.metadata.record_type == RecordType.COMMAND_REJECTION
+                and r.metadata.value_type == ValueType.JOB
+            ]
+            assert not rejected
+        finally:
+            broker.close()
+
+    def test_park_wait_counts_only_jobs_with_a_known_entry_time(self):
+        """``serving_backlog_park_wait_seconds_total``: the engine's clock
+        at the hand-out minus the clock at the job's entry into the parked
+        set; a job that the scan after a restore found has no entry time
+        and adds nothing. ``serving_backlog_parked_walked_total``: the keys
+        a sweep looked at, which ends where the free credits do."""
+        broker, client, clock, engine = _device_broker()
+        try:
+            _subscribe(broker, engine, PAY_A, "payment-service", 0)
+            for i in range(3):
+                client.create_instance("order-process", payload={"orderId": i})
+            broker.run_until_idle()
+            clock.advance(250)
+            for i in range(3, 5):
+                client.create_instance("order-process", payload={"orderId": i})
+            broker.run_until_idle()
+            keys = sorted(engine._parked)
+            assert len(keys) == 5
+            assert [engine._parked[k][2] for k in keys] == (
+                [1_000_000] * 3 + [1_000_250] * 2
+            )
+            clock.advance(100)
+            engine.increase_job_credits(PAY_A, 4)
+            waited = event_count("serving_backlog_park_wait_seconds_total")
+            out, counts = _counts(engine, engine.device_backlog_activations)
+            assert [r.key for r in out] == keys[:4]
+            # 3 x 350 ms + 1 x 100 ms
+            assert counts["backlog_park_wait"] == pytest.approx(1.15)
+            assert counts["backlog_parked_walked"] == 4  # the fifth: no credit left
+            assert counts["backlog_activations"] == 4
+            # a clock of the caller's is flushed by the caller (the tick's);
+            # nothing reached the global counter behind its back
+            assert event_count("serving_backlog_park_wait_seconds_total") == waited
+            broker.partitions[0].log.append(out)
+            broker.run_until_idle()
+            # the last one is found again by a scan (as after a restore):
+            # since when it waits is not known
+            assert sorted(engine._parked) == keys[4:]
+            engine._parked = None
+            clock.advance(5_000)
+            engine.increase_job_credits(PAY_A, 1)
+            out, counts = _counts(engine, engine.device_backlog_activations)
+            assert [r.key for r in out] == keys[4:]
+            assert counts["backlog_table_scans"] == 1
+            assert counts["backlog_parked_walked"] == 1
+            assert "backlog_park_wait" not in counts
+        finally:
+            broker.close()
+
+    def test_tick_flushes_the_park_wait_and_the_walk(self):
+        """Flushed as the cluster broker's tick flushes its clock
+        (``PartitionServer.tick``), the two counts reach the registry by
+        the names the benchmark's readers use."""
+        from zeebe_tpu.runtime.metrics import observe_phases
+
+        broker, client, clock, engine = _device_broker()
+        try:
+            _subscribe(broker, engine, PAY_A, "payment-service", 0)
+            for i in range(6):
+                client.create_instance("order-process", payload={"orderId": i})
+            broker.run_until_idle()
+            clock.advance(300)
+            engine.increase_job_credits(PAY_A, 2)
+            before = {
+                n: event_count(n) for n in (
+                    "serving_backlog_park_wait_seconds_total",
+                    "serving_backlog_parked_walked_total",
+                    "serving_backlog_activations_total",
+                    "serving_backlog_sweeps_total",
+                )
+            }
+            clock_of_tick = tracing.PhaseClock()
+            with clock_of_tick.phase("tick"), engine.on_clock(clock_of_tick):
+                out = engine.device_backlog_activations()
+            observe_phases(clock_of_tick, "ticks")
+            assert len(out) == 2
+            grew = {n: event_count(n) - v for n, v in before.items()}
+            assert grew["serving_backlog_activations_total"] == 2
+            assert grew["serving_backlog_sweeps_total"] == 1
+            assert grew["serving_backlog_parked_walked_total"] == 2
+            assert grew["serving_backlog_park_wait_seconds_total"] == pytest.approx(0.6)
+        finally:
+            broker.close()
+
+    @pytest.mark.parametrize("calls", [[(PAY_A, 1)], [(PAY_A, 3), (SHIP, 2), (PAY_A, 1)]])
+    def test_credit_returns_are_counted_once_a_call(self, calls):
+        """``serving_job_credit_returns_total`` counts calls, not credits,
+        and ``serving_job_credit_return_seconds_total`` their seconds; the
+        credits land on the subscription that returned them."""
+        broker, _client, _clock, engine = _device_broker()
+        try:
+            _subscribe(broker, engine, PAY_A, "payment-service", 0)
+            _subscribe(broker, engine, SHIP, "shipping-service", 0)
+            returns = event_count("serving_job_credit_returns_total")
+            seconds = event_count("serving_job_credit_return_seconds_total")
+            for key, n in calls:
+                engine.increase_job_credits(key, n)
+            assert event_count("serving_job_credit_returns_total") == returns + len(calls)
+            assert event_count("serving_job_credit_return_seconds_total") > seconds
+            s = engine.state
+            by_key = dict(zip(
+                np.asarray(s.sub_key).tolist(), np.asarray(s.sub_credits).tolist()
+            ))
+            for key in (PAY_A, SHIP):
+                assert by_key[key] == sum(n for k, n in calls if k == key)
+        finally:
+            broker.close()
+
     @pytest.mark.parametrize("seed", [1, 2, 3])
     def test_sweep_from_parked_set_equals_table_scan(self, seed):
         broker, _client, _clock, engine, active = _population(seed)

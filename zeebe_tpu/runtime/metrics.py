@@ -436,6 +436,30 @@ def _phase_handles() -> dict:
                 "Jobs that entered the engine's parked set: a collected "
                 "wave stepped their pool event and no credit was free",
             ),
+            backlog_parked_walked=g.counter(
+                "serving_backlog_parked_walked_total",
+                "Parked jobs the backlog sweeps looked at: those of the "
+                "credited types, in key order, until the free credits ran "
+                "out",
+            ),
+            backlog_park_wait=g.counter(
+                "serving_backlog_park_wait_seconds_total",
+                "Seconds the jobs a sweep handed out had waited in the "
+                "engine's parked set (its clock, milliseconds; a job the "
+                "scan after a restore found has no entry time and adds "
+                "nothing)",
+            ),
+            credit_return=g.counter(
+                "serving_job_credit_return_seconds_total",
+                "Seconds in the device engine's increase_job_credits: the "
+                "fetch of the subscription keys from the device (it waits "
+                "for a step that is in flight) and the update of the credits",
+            ),
+            credit_returns=g.counter(
+                "serving_job_credit_returns_total",
+                "Credit returns the device engine took "
+                "(increase_job_credits calls)",
+            ),
             backlog_skipped_in_flight=g.counter(
                 "serving_backlog_skipped_in_flight_total",
                 "Activatable jobs a backlog sweep left alone because a pool "

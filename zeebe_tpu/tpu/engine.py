@@ -377,7 +377,9 @@ class TpuPartitionEngine:
         #   ACTIVATED, as a rejection, or as an event the pool let pass),
         #   before that wave's own emissions enter.
         # - ``_parked`` (the sweep's): key -> (type id, job value as the
-        #   stepped event carried it). A job enters when a collected wave
+        #   stepped event carried it, ``self.clock()`` at its entry: what
+        #   ``serving_backlog_park_wait_seconds_total`` counts from when
+        #   a sweep hands it out). A job enters when a collected wave
         #   stepped its pool event and neither holds an ACTIVATE of that
         #   key nor ended the job (collect_wave, _park_unassigned: what the
         #   host engine keeps as _awaiting_jobs). It leaves when a sweep
@@ -388,8 +390,9 @@ class TpuPartitionEngine:
         #   None = not known: after a restore, and on an engine over a
         #   table it did not step, the first sweep the due probe asks for
         #   scans the table once (_scan_parked_jobs; such an entry holds
-        #   the row's slot and is read back when handed out) and from then
-        #   on the set is kept. Not in a snapshot.
+        #   the row's slot and is read back when handed out, and None for
+        #   its entry time, which nobody knows: its wait is not counted)
+        #   and from then on the set is kept. Not in a snapshot.
         # - ``_ended``: jobs that left the table (COMPLETED, CANCELED,
         #   demoted) while a record of theirs was still in flight; the pool
         #   event that arrives late must not park them. Pruned to the keys
@@ -1312,13 +1315,23 @@ class TpuPartitionEngine:
         )
 
     def increase_job_credits(self, subscriber_key: int, credits: int) -> None:
-        self._host.increase_job_credits(subscriber_key, credits)
-        self._mark_device_dirty("sub")
-        s = self.state
-        match = jnp.asarray(np.asarray(s.sub_key) == subscriber_key)
-        self.state = dataclasses.replace(
-            s, sub_credits=s.sub_credits + jnp.where(match, credits, 0)
-        )
+        """A worker's credit return. Like a subscription it arrives outside
+        every cycle, so its seconds (the fetch of the subscription keys
+        from the device, which waits for a step that is in flight,
+        included) and its count are flushed from a clock of its own."""
+        from zeebe_tpu.runtime.metrics import observe_phases
+
+        clock = tracing.PhaseClock()
+        with clock.phase("credit_return"):
+            self._host.increase_job_credits(subscriber_key, credits)
+            self._mark_device_dirty("sub")
+            s = self.state
+            match = jnp.asarray(np.asarray(s.sub_key) == subscriber_key)
+            self.state = dataclasses.replace(
+                s, sub_credits=s.sub_credits + jnp.where(match, credits, 0)
+            )
+        clock.count("credit_returns", 1)
+        observe_phases(clock)
 
     # -- deadline scans (broker tick) --------------------------------------
     def deadlines_due_probe(self):
@@ -1389,9 +1402,11 @@ class TpuPartitionEngine:
         # host-oracle parity
         rr = int(np.asarray(s.sub_rr)) % len(sub_slots)
         left = int(sub_credits[valid].clip(min=0).sum())
+        walked = 0
         for key in self._parked_keys(credited):
             if not left:
                 break
+            walked += 1
             if key in assigning:
                 skipped += 1
                 continue
@@ -1415,6 +1430,8 @@ class TpuPartitionEngine:
                     int(sub_keys[target]),
                 )
             )
+        if walked:
+            self._clock.count("backlog_parked_walked", walked)
         if skipped:
             self._clock.count("backlog_skipped_in_flight", skipped)
         if out:  # rr only advances on an assignment, which also appends
@@ -1443,7 +1460,7 @@ class TpuPartitionEngine:
 
     def _scan_parked_jobs(self) -> Dict[int, tuple]:
         """Every activatable row with retries left and nothing on its way,
-        as key -> (type id, slot)."""
+        as key -> (type id, slot, None: since when it waits is not known)."""
         self._clock.count("backlog_table_scans", 1)
         s = self.state
         job_i32 = np.asarray(s.job_i32)
@@ -1454,7 +1471,7 @@ class TpuPartitionEngine:
         )[0]
         assigning = self._assigning
         return {
-            key: (type_id, slot)
+            key: (type_id, slot, None)
             for key, type_id, slot in zip(
                 job_keys[slots].tolist(),
                 job_i32[slots, state_mod.JB_TYPE].tolist(),
@@ -1468,8 +1485,13 @@ class TpuPartitionEngine:
     ) -> Record:
         """The ACTIVATE command that hands parked job ``key`` out: it
         leaves ``_parked`` and is on its way (``_assigning``)."""
-        _type_id, held = self._parked.pop(key)
+        _type_id, held, since = self._parked.pop(key)
         self._assigning.add(key)
+        if since is not None:
+            # a sum of seconds that is no phase: the wait spans cycles
+            self._clock.count(
+                "backlog_park_wait", max(0, self.clock() - since) / 1e3
+            )
         activated = (
             held.copy() if isinstance(held, JobRecord)
             else self._job_value_from_slot(held)
@@ -2437,13 +2459,14 @@ class TpuPartitionEngine:
         if pool_events and parked is not None:
             assigning = self._assigning
             entered = 0
+            now = self.clock()
             for key, entry in pool_events:
                 parked.pop(key, None)  # judged anew
                 if key in assigning or key in ended:
                     continue
                 value = _as_record(entry).value
                 if value.retries > 0:
-                    parked[key] = (self.interns.intern(value.type), value)
+                    parked[key] = (self.interns.intern(value.type), value, now)
                     entered += 1
             if entered:
                 clock.count("backlog_parked", entered)

@@ -123,7 +123,9 @@ class PhaseClock:
         if self.slices is not None:
             self.slices.append([name, t0, t1])
 
-    def count(self, name: str, n: int) -> None:
+    def count(self, name: str, n) -> None:
+        """Add ``n`` to a total that is no phase: bytes, jobs, or seconds
+        of a wait that spans cycles (a parked job's)."""
         self.counts[name] = self.counts.get(name, 0) + n
 
     def seconds(self, *names: str) -> float:
