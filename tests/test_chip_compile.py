@@ -139,6 +139,17 @@ def test_served_program_compiles_at_deployment_size(one_chip, lower):
     assert resident < HBM_BYTES
 
 
+def test_credit_flush_compiles_with_its_column_aliased(one_chip):
+    """``engine.credit_flush`` at the served subscription capacity: the
+    donated credit column aliases the result, and nothing table-sized is
+    an argument of it (a launch of it costs by its two small leaves)."""
+    column = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    compiled = engine_mod._credit_flush_jit.lower(column, column).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes <= 2 * 512  # two padded small vectors
+    assert mem.alias_size_in_bytes >= 16 * 4
+
+
 def test_sharded_state_step_divides_the_tables_by_the_span(topo):
     """``shard.state_step`` (gathered, the default routing) on the
     described 2x2 mesh: each device holds about a quarter of what one

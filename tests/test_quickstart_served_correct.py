@@ -59,6 +59,9 @@ def test_quickstart_rehearsal_is_correct(seed):
     assert n["serving_backlog_park_wait_seconds_total"] > 0
     assert n["serving_job_credit_returns_total"] > 0
     assert n["serving_job_credit_return_seconds_total"] > 0
+    # and reached the device in flushes, each before a reader of the state
+    assert 0 < n["serving_job_credit_flushes_total"] <= n["serving_job_credit_returns_total"]
+    assert n["serving_job_credit_flush_seconds_total"] > 0
     # a parked job carries its value: no row is read back for it
     assert n["serving_job_row_reads_total"] == 0
     log = report["derived"]
@@ -95,9 +98,10 @@ def test_the_cell_is_the_issues():
     assert tasks == ["payment-service", "inventory-service", "shipment-service"]
     assert [m["name"] for m in cell.end_to_end] == ["instances_per_s", "setup_s"]
     names = {m["name"] for m in cell.per_layer}
-    assert len(names) == 24 and {
+    assert len(names) == 26 and {  # ISSUE 35 added the flush's two
         "job_park_wait_ms", "parked_walked_per_sweep", "backlog_skipped_per_sweep",
         "credit_return_ms", "credit_returns_per_job", "backlog_activations_per_job.quick",
+        "credit_flush_ms", "credit_returns_per_flush",
         "step_ms.quick", "step_roofline.quick", "device_idle_share.quick",
     } <= names
     assert all(m["moves"] == "instances_per_s" for m in cell.per_layer)

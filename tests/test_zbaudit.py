@@ -153,13 +153,15 @@ class TestTableI64:
 
     def test_the_served_programs_hold_no_table_sized_int64(self):
         """The pass itself, on the live tree at the budget's shape: the
-        step (every specialisation on), the tick and the due probe."""
+        step (every specialisation on), the tick, the due probe and the
+        credit flush."""
         result = audit(passes=["table-i64"], entries=[])
         assert result.findings == []
         per = result.report["table-i64"]
         assert per["config"]["capacity"] >= 1 << 20
         assert per["floor_elements"] == per["config"]["capacity"] // 8
-        for name in ("kernel.step", "kernel.tick", "engine.due_probe"):
+        for name in ("kernel.step", "kernel.tick", "engine.due_probe",
+                     "engine.credit_flush"):
             assert per[name] == 0
 
     def test_a_table_scan_through_int64_would_fire(self, monkeypatch):
@@ -637,7 +639,7 @@ class TestGate:
             doc = json.load(f)
         assert doc["findings"] == []
         for name in ("kernel.step", "kernel.tick", "engine.due_probe",
-                     "drive.round", "drive.quiesce", "shard.sharded_step",
+                     "engine.credit_flush", "drive.round", "drive.quiesce", "shard.sharded_step",
                      "shard.frame_exchange", "shard.sharded_drive"):
             assert name in doc["entries"]
         assert doc["report"]["hbm"]["serving_peak_bytes"] > 0

@@ -27,6 +27,7 @@ DRIVER_NAMES = (
     "kernel.step",
     "kernel.tick",
     "engine.due_probe",
+    "engine.credit_flush",
     "drive.round",
     "drive.quiesce",
     "shard.sharded_step",
@@ -127,6 +128,13 @@ def build_entries(
         add(
             "engine.due_probe", engine_mod._due_probe_jit,
             state_sds, now_sds,
+        )
+    if wanted("engine.credit_flush"):
+        # the two small leaves it is launched with: the credit column and
+        # the returned credits it has not been told of, one i32 a slot
+        add(
+            "engine.credit_flush", engine_mod._credit_flush_jit,
+            state_sds.sub_credits, state_sds.sub_credits,
         )
 
     if wanted("drive.round") or wanted("drive.quiesce"):

@@ -261,11 +261,11 @@ def pass_table_i64(audited: List[AuditedEntry], budget: dict, report: dict):
     table-sized ``int64`` leaf or intermediate costs passes over the whole
     table for a wave of a few hundred records (PERF.md, PR 30). The state
     holds its 64-bit columns as 32-bit planes (``tpu/state.py``); this pass
-    traces ``kernel.step``, ``kernel.tick`` and ``engine.due_probe`` at the
-    served shape (budget ``dtype.table_i64``: the tables dwarf the wave
-    there, which the census config's do not) and fails on any 64-bit
-    integer array with at least as many elements as the smallest table
-    that holds a 64-bit column."""
+    traces ``kernel.step``, ``kernel.tick``, ``engine.due_probe`` and
+    ``engine.credit_flush`` at the served shape (budget
+    ``dtype.table_i64``: the tables dwarf the wave there, which the census
+    config's do not) and fails on any 64-bit integer array with at least
+    as many elements as the smallest table that holds a 64-bit column."""
     cfg = budget.get("dtype", {}).get("table_i64")
     if not cfg:
         return []
@@ -307,6 +307,9 @@ def pass_table_i64(audited: List[AuditedEntry], budget: dict, report: dict):
         "kernel.step": lambda: kernel.step_jit.trace(graph, state, batch, now),
         "kernel.tick": lambda: kernel.tick_jit.trace(state, now),
         "engine.due_probe": lambda: engine_mod._due_probe_jit.trace(state, now),
+        "engine.credit_flush": lambda: engine_mod._credit_flush_jit.trace(
+            state.sub_credits, state.sub_credits
+        ),
     }
     by_name = {a.name: a for a in audited}
     findings: List[Finding] = []
@@ -370,9 +373,10 @@ def wave_io(a: AuditedEntry, resident_args=()) -> dict:
 def pass_boundary(audited: List[AuditedEntry], budget: dict, report: dict):
     """The host boundary of each device program: no callbacks, no
     implicit transfers, every state-carrying argument donated with the
-    aliasing actually materialized in the lowering, and, for the step
-    programs budgeted under ``boundary.wave_io``, no more arrays a call
-    than the wave's packed pair in and the pair and one stats vector out
+    aliasing actually materialized in the lowering, and, for the programs
+    budgeted under ``boundary.wave_io``, no more arrays a call than the
+    budget's: for a step the wave's packed pair in and the pair and one
+    stats vector out, for the credit flush its addends in and nothing out
     (a transfer costs by the array, not by the byte: PERF.md, PR 32), none
     of them 64 bits wide."""
     findings: List[Finding] = []
@@ -632,5 +636,8 @@ PASSES = {
 # census_gate shim run the op-census family without paying the full build
 PASS_ENTRIES = {
     "op-census": {"kernel.step"},
-    "table-i64": {"kernel.step", "kernel.tick", "engine.due_probe"},
+    "table-i64": {
+        "kernel.step", "kernel.tick", "engine.due_probe",
+        "engine.credit_flush",
+    },
 }

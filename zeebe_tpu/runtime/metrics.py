@@ -451,14 +451,28 @@ def _phase_handles() -> dict:
             ),
             credit_return=g.counter(
                 "serving_job_credit_return_seconds_total",
-                "Seconds in the device engine's increase_job_credits: the "
-                "fetch of the subscription keys from the device (it waits "
-                "for a step that is in flight) and the update of the credits",
+                "Seconds in the device engine's increase_job_credits: host "
+                "arithmetic on the host side of the subscription table (the "
+                "credits wait there for the credit column's next reader; "
+                "the table is fetched only after a state assigned from "
+                "outside)",
             ),
             credit_returns=g.counter(
                 "serving_job_credit_returns_total",
                 "Credit returns the device engine took "
                 "(increase_job_credits calls)",
+            ),
+            credit_flush=g.counter(
+                "serving_job_credit_flush_seconds_total",
+                "Seconds adding the returned credits to the device's credit "
+                "column before its next reader (a step, the due probe, the "
+                "sweep, a subscription, a snapshot): one launch a flush",
+            ),
+            credit_flushes=g.counter(
+                "serving_job_credit_flushes_total",
+                "Flushes of returned credits to the device (over "
+                "serving_job_credit_returns_total: the returns one launch "
+                "carried)",
             ),
             backlog_skipped_in_flight=g.counter(
                 "serving_backlog_skipped_in_flight_total",

@@ -184,6 +184,42 @@ _due_probe_jit = jit_registry.register_jit(
 )
 
 
+def _credit_flush_entry(sub_credits: jax.Array, delta: jax.Array) -> jax.Array:
+    """Donating entry that adds the workers' returned credits (``delta``,
+    i32 per subscription slot, summed on the host since the last flush)
+    to the device's credit column. Over the column and not over the
+    state: a launch costs by its leaves (PERF.md, PR 32), and no other
+    table has a part in it."""
+    return sub_credits + delta
+
+
+_credit_flush_jit = jit_registry.register_jit(
+    "engine.credit_flush",
+    _credit_flush_entry,
+    state_args=(0,),
+    donate_argnums=(0,),
+    max_signatures=2,
+    notes="the subscription table's shape is fixed per engine; one extra "
+    "signature allowed for an engine of another sub_capacity in the same "
+    "process",
+)
+
+
+@dataclasses.dataclass(frozen=True)
+class _HostSubscriptions:
+    """The host side of the job-subscription table: the columns that only
+    the host writes, as the device holds them. The arrays are never
+    written in place (a change makes new ones), so one may be handed to
+    the device as it is."""
+
+    key: np.ndarray      # i64 [S] subscriber key
+    type: np.ndarray     # i32 [S] interned job type
+    worker: np.ndarray   # i32 [S] interned worker name
+    timeout: np.ndarray  # i64 [S]
+    valid: np.ndarray    # bool [S]
+    rr: int              # the sweep's round-robin cursor (state.sub_rr)
+
+
 def _host_unpack_payload(pay: np.ndarray):
     """Host-side view of one packed payload row ([3V] i32 — see
     state.pack_payload): returns (vt, num, sid) for columns_to_payload."""
@@ -405,6 +441,25 @@ class TpuPartitionEngine:
         self._assigning: set = set()
         self._parked: Optional[Dict[int, tuple]] = None
         self._ended: set = set()
+        # THE JOB-SUBSCRIPTION TABLE HAS A HOST SIDE. Of its columns only
+        # ``sub_credits`` is written by the device (the pool's assignments,
+        # a rejected ACTIVATE's return); the others and ``sub_rr`` are
+        # written by this engine's subscription methods and its sweep, and
+        # every step passes them through. So:
+        # - ``_subs`` is the host's copy of those columns; who needs a
+        #   subscriber's slot, a free slot or a worker's name reads it
+        #   there and fetches nothing. None = not known: whoever assigns
+        #   ``state`` from outside (tests, the restore) may have written
+        #   the table, and its next reader fetches it once
+        #   (``_subscriptions``).
+        # - ``_credit_delta`` holds the credits the workers returned and
+        #   the device has not been told of, per slot (None = none): a
+        #   return is host arithmetic (``increase_job_credits``). It is
+        #   added to the device's column in one program (``_flush_credits``)
+        #   before anything reads that column: a step, the due probe, the
+        #   sweep, a subscription method, a snapshot, a read of ``state``.
+        self._subs: Optional[_HostSubscriptions] = None
+        self._credit_delta: Optional[np.ndarray] = None
         self.num_partitions = num_partitions
         # mesh placement (scheduler/placement.DevicePlan): this engine's
         # state lives COMMITTED on `device`, batches stage onto it, and the
@@ -629,6 +684,70 @@ class TpuPartitionEngine:
             return tree
         return jax.device_put(tree, self.device)
 
+    # -- the device state, as every reader must see it ----------------------
+    @property
+    def state(self) -> "state_mod.EngineState":
+        """The device tables, with every returned credit applied."""
+        if self._credit_delta is not None:
+            self._flush_credits()
+        return self._state
+
+    @state.setter
+    def state(self, value) -> None:
+        # assigned from outside the subscription methods: the table may be
+        # another one, so the host's copy of it is not known, and returns
+        # not yet applied were the replaced table's
+        self._state = value
+        self._subs = None
+        self._credit_delta = None
+
+    def _flush_credits(self) -> None:
+        """Add the returned credits the device has not been told of to
+        its credit column: one donating program for however many returns,
+        as phase ``credit_flush`` of the cycle that needs the column (a
+        wave's or a tick's; outside every cycle, of a clock of its own)."""
+        delta = self._credit_delta
+        if delta is None:
+            return
+        self._credit_delta = None
+        own = self._clock is self._idle_clock
+        clock = tracing.PhaseClock() if own else self._clock
+        with clock.phase("credit_flush"):
+            self._mark_device_dirty("sub")
+            self._add_credits(delta)
+        clock.count("credit_flushes", 1)
+        if own:
+            from zeebe_tpu.runtime.metrics import observe_phases
+
+            observe_phases(clock)
+
+    def _add_credits(self, delta: np.ndarray) -> None:
+        """One launch of the flush program. ``delta`` rides in it as the
+        numpy array it is; the sum is placed like the leaf it replaces
+        (``_put_leaves``)."""
+        s = self._state
+        self._state = dataclasses.replace(
+            s,
+            sub_credits=self._place(_credit_flush_jit(s.sub_credits, delta)),
+        )
+
+    def _subscriptions(self) -> _HostSubscriptions:
+        """The host side of the subscription table; fetched from the
+        device (one ``device_get``) only where it is not known."""
+        subs = self._subs
+        if subs is None:
+            s = self._state
+            *columns, rr = jax.device_get(
+                (s.sub_key, s.sub_type, s.sub_worker, s.sub_timeout,
+                 s.sub_valid, s.sub_rr)
+            )
+            # copies: a fetched array may be a view of the device's buffer,
+            # which the next step is given to write on
+            subs = self._subs = _HostSubscriptions(
+                *(np.array(c) for c in columns), rr=rr.item()
+            )
+        return subs
+
     def place_on(self, device, device_index: int = -1) -> None:
         """Migrate this engine's live device state onto another mesh device
         (DevicePlan rebalance after a device exclusion or leadership
@@ -644,7 +763,7 @@ class TpuPartitionEngine:
         self.device = device
         self.device_index = device_index
         if device is not None:
-            self.state = jax.device_put(self.state, device)
+            self._state = jax.device_put(self.state, device)
             if self.graph is not None:
                 self.graph = jax.device_put(self.graph, device)
 
@@ -843,7 +962,7 @@ class TpuPartitionEngine:
             state = dataclasses.replace(state, msg_map=g)
         # host-built leaves are uncommitted arrays on the default device:
         # re-place, or the next step sees a new signature and recompiles
-        self.state = self._place(state)
+        self._state = self._place(state)
 
     def _migrate_message_store_to_host(self) -> None:
         """Device message tables → host oracle store (a host-only workflow
@@ -895,7 +1014,7 @@ class TpuPartitionEngine:
                 deadline=int(msg_deadline[slot]),
             )
         v = self.num_vars
-        self.state = self._place(dataclasses.replace(
+        self._state = self._place(dataclasses.replace(
             s,
             msub_ckey=jnp.full_like(s.msub_ckey, -1),
             msub_i64=jnp.full_like(s.msub_i64, -1),
@@ -1160,7 +1279,7 @@ class TpuPartitionEngine:
         # re-derive it (and the lookup structures) NOW, or near capacity
         # the ring runs dry and inserts report spurious table overflow
         # while the freed rows sit unused until the next cadence rebuild
-        self.state = state_mod.rebuild_lookup_state(new_state)
+        self._state = state_mod.rebuild_lookup_state(new_state)
         self._note_lookup_rebuilt()
 
     def _routes_to_host(self, record: Record) -> bool:
@@ -1260,11 +1379,10 @@ class TpuPartitionEngine:
         the per-subscription in-flight bound is per-engine."""
         self.remove_job_subscription(sub.subscriber_key)
         host_backlog = self._host.add_job_subscription(dataclasses.replace(sub))
-        s = self.state
-        valid = np.asarray(s.sub_valid)
-        free = int(np.argmin(valid)) if not valid.all() else -1
-        if free < 0 or valid[free]:
+        subs = self._subscriptions()
+        if subs.valid.all():
             raise RuntimeError("subscription table full")
+        free = int(np.argmin(subs.valid))
 
         # the backlog of this type, from the engine's account of parked
         # jobs (the table is scanned only where that is not known). A
@@ -1291,45 +1409,75 @@ class TpuPartitionEngine:
                 credits -= 1
         observe_phases(clock)
 
+        def with_slot(column: np.ndarray, value) -> np.ndarray:
+            column = column.copy()
+            column[free] = value
+            return column
+
         self._mark_device_dirty("sub")
-        self.state = dataclasses.replace(
-            s,
-            sub_key=s.sub_key.at[free].set(sub.subscriber_key),
-            sub_type=s.sub_type.at[free].set(type_id),
-            sub_worker=s.sub_worker.at[free].set(self.interns.intern(sub.worker)),
-            # backlog activations consumed credits up front; the kernel
-            # returns them on ACTIVATE rejection like pool assignments
-            sub_credits=s.sub_credits.at[free].set(credits),
-            sub_timeout=s.sub_timeout.at[free].set(sub.timeout),
-            sub_valid=s.sub_valid.at[free].set(True),
+        s = self.state  # with every returned credit applied
+        subs = dataclasses.replace(
+            subs,
+            key=with_slot(subs.key, sub.subscriber_key),
+            type=with_slot(subs.type, type_id),
+            worker=with_slot(subs.worker, self.interns.intern(sub.worker)),
+            timeout=with_slot(subs.timeout, sub.timeout),
+            valid=with_slot(subs.valid, True),
         )
+        # backlog activations consumed credits up front; the kernel
+        # returns them on ACTIVATE rejection like pool assignments
+        sub_credits = with_slot(jax.device_get(s.sub_credits), credits)
+        key, type_, worker, timeout, valid, sub_credits = self._put_leaves(
+            subs.key, subs.type, subs.worker, subs.timeout, subs.valid,
+            sub_credits,
+        )
+        self._state = dataclasses.replace(
+            s, sub_key=key, sub_type=type_, sub_worker=worker,
+            sub_timeout=timeout, sub_valid=valid, sub_credits=sub_credits,
+        )
+        self._subs = subs
         return host_backlog + backlog
 
     def remove_job_subscription(self, subscriber_key: int) -> None:
         self._host.remove_job_subscription(subscriber_key)
         self._mark_device_dirty("sub")
-        s = self.state
-        match = np.asarray(s.sub_key) == subscriber_key
-        self.state = dataclasses.replace(
-            s, sub_valid=s.sub_valid & jnp.asarray(~match)
-        )
+        subs = self._subscriptions()
+        match = subs.key == subscriber_key
+        if not (match & subs.valid).any():
+            return  # the device's column holds no such subscription
+        subs = dataclasses.replace(subs, valid=subs.valid & ~match)
+        (valid,) = self._put_leaves(subs.valid)
+        self._state = dataclasses.replace(self.state, sub_valid=valid)
+        self._subs = subs
+
+    def _put_leaves(self, *columns: np.ndarray) -> tuple:
+        """Host arrays as leaves of the state, in one ``device_put``,
+        placed like the leaves they replace: an uncommitted leaf on the
+        default device gives the step, the due probe and the credit flush
+        a second signature, compiled on the broker actor mid-traffic."""
+        return self._place(jax.device_put(columns))
 
     def increase_job_credits(self, subscriber_key: int, credits: int) -> None:
-        """A worker's credit return. Like a subscription it arrives outside
-        every cycle, so its seconds (the fetch of the subscription keys
-        from the device, which waits for a step that is in flight,
-        included) and its count are flushed from a clock of its own."""
+        """A worker's credit return: host arithmetic. The subscriber's
+        slot is found in the host side of the table and the credits wait
+        in ``_credit_delta`` for the column's next reader
+        (``_flush_credits``); nothing is launched and nothing fetched
+        (but the table where it is not known). Like a subscription it
+        arrives outside every cycle, so its seconds and its count are
+        flushed from a clock of its own."""
         from zeebe_tpu.runtime.metrics import observe_phases
 
         clock = tracing.PhaseClock()
         with clock.phase("credit_return"):
             self._host.increase_job_credits(subscriber_key, credits)
-            self._mark_device_dirty("sub")
-            s = self.state
-            match = jnp.asarray(np.asarray(s.sub_key) == subscriber_key)
-            self.state = dataclasses.replace(
-                s, sub_credits=s.sub_credits + jnp.where(match, credits, 0)
-            )
+            # as the device's column would take it: every slot that holds
+            # the key, valid or not
+            match = self._subscriptions().key == subscriber_key
+            if match.any():
+                self._mark_device_dirty("sub")
+                if self._credit_delta is None:
+                    self._credit_delta = np.zeros(match.shape, np.int32)
+                self._credit_delta[match] += credits
         clock.count("credit_returns", 1)
         observe_phases(clock)
 
@@ -1345,7 +1493,7 @@ class TpuPartitionEngine:
         are NOT covered: the broker sweeps those (cheap dict scans) every
         tick via ``host_deadline_commands``."""
         now = jnp.asarray(self.clock(), jnp.int64)
-        self.state, mask = _due_probe_jit(self.state, now)
+        self._state, mask = _due_probe_jit(self.state, now)
         return mask
 
     def backlog_activations(self) -> List[Record]:
@@ -1376,15 +1524,16 @@ class TpuPartitionEngine:
 
     def _sweep_job_backlog(self) -> List[Record]:
         self._clock.count("backlog_sweeps", 1)
-        s = self.state
-        valid = np.asarray(s.sub_valid)
+        subs = self._subscriptions()
+        valid = subs.valid
         if not valid.any():
             return []
-        sub_keys = np.asarray(s.sub_key)
-        sub_types = np.asarray(s.sub_type)
-        sub_credits = np.asarray(s.sub_credits).copy()
-        sub_timeouts = np.asarray(s.sub_timeout)
-        sub_workers = np.asarray(s.sub_worker)
+        sub_keys, sub_types = subs.key, subs.type
+        sub_timeouts, sub_workers = subs.timeout, subs.worker
+        # the one column the device writes too: the sweep's one fetch,
+        # with every returned credit applied
+        s = self.state
+        sub_credits = np.array(jax.device_get(s.sub_credits))
         if not (sub_credits[valid] > 0).any():
             return []
         assigning = self._assigning
@@ -1400,7 +1549,7 @@ class TpuPartitionEngine:
         # the first credited subscription win every drain, starving the
         # rest — the oracle's _job_rr_cursor is global, so this is also
         # host-oracle parity
-        rr = int(np.asarray(s.sub_rr)) % len(sub_slots)
+        rr = subs.rr % len(sub_slots)
         left = int(sub_credits[valid].clip(min=0).sum())
         walked = 0
         for key in self._parked_keys(credited):
@@ -1437,14 +1586,11 @@ class TpuPartitionEngine:
         if out:  # rr only advances on an assignment, which also appends
             self._clock.count("backlog_activations", len(out))
             self._mark_device_dirty("sub")
-            # placed like the leaves they replace: an uncommitted leaf
-            # on the default device gives the step and the due probe a
-            # second signature, compiled on the broker actor mid-traffic
-            self.state = dataclasses.replace(
-                s,
-                sub_credits=self._place(jnp.asarray(sub_credits)),
-                sub_rr=self._place(jnp.asarray(rr, jnp.int32)),
+            sub_credits, sub_rr = self._put_leaves(sub_credits, np.int32(rr))
+            self._state = dataclasses.replace(
+                s, sub_credits=sub_credits, sub_rr=sub_rr
             )
+            self._subs = dataclasses.replace(subs, rr=rr)
         return out
 
     def _parked_keys(self, type_ids) -> List[int]:
@@ -1745,12 +1891,13 @@ class TpuPartitionEngine:
         # change with it: 64-bit tables, columns and hash-map keys are
         # written as the int64 arrays they always were (host views of the
         # pulled planes; restore_state converts back)
-        for f in dataclasses.fields(self.state):
+        state = self.state  # with every returned credit applied
+        for f in dataclasses.fields(state):
             skip = (
                 dirty_dev is not None
                 and stateser.device_array_family(f.name) not in dirty_dev
             )
-            v = getattr(self.state, f.name)
+            v = getattr(state, f.name)
             if isinstance(v, hashmap.HashTable):
                 put(f.name + ".keys", v, skip, hashmap.host_keys)
                 put(f.name + ".vals", v.vals, skip)
@@ -2039,7 +2186,7 @@ class TpuPartitionEngine:
                 return
             self._mark_device_dirty("keys")
             # device-side maxima: no host↔device round trip
-            self.state = dataclasses.replace(
+            self._state = dataclasses.replace(
                 self.state,
                 next_wf_key=jnp.maximum(
                     self.state.next_wf_key,
@@ -2366,7 +2513,7 @@ class TpuPartitionEngine:
             1, self.graph.emit_width if self.graph is not None else 1
         )
         window = (
-            self.state.ei_index.shape[0] // self._state_shards
+            self._state.ei_index.shape[0] // self._state_shards
         ) // (4 * fanout)
         return max(1, min(self._routed_lane_slots, window))
 
@@ -2731,7 +2878,12 @@ class TpuPartitionEngine:
         out. Compiles nothing while no workflow is deployed — there is no
         graph to step — so a broker on a fresh data directory still pays
         the compile on its first instance; after a restart the replayed
-        deployments come after this call too."""
+        deployments come after this call too. The credit flush needs no
+        graph and compiles here in any case, on a delta of zeros placed
+        as a return's is, so that the first worker's first return finds
+        it compiled (no flush is counted for it)."""
+        self._flush_credits()
+        self._add_credits(np.zeros(self._state.sub_credits.shape, np.int32))
         if self.graph is None:
             self._recompile()
         if self.graph is None:
@@ -2760,6 +2912,9 @@ class TpuPartitionEngine:
         # ``now`` and the partition id ride in the launch as numpy scalars
         # (an eager ``jnp.asarray`` would be a device dispatch of its own)
         pid = np.int32(self.partition_id)
+        # the pool reads the credit column: what the workers returned since
+        # the last reader goes in first, as a phase of its own
+        self._flush_credits()
         with self._clock.phase("launch"):
             if self._resident_mode:
                 program = (
@@ -2767,16 +2922,16 @@ class TpuPartitionEngine:
                     if lane_owner is not None
                     else self._state_step_fallback
                 )
-                self.state, out, stats = program(
-                    self.graph, self.state, batch, now, pid
+                self._state, out, stats = program(
+                    self.graph, self._state, batch, now, pid
                 )
             elif self._state_step is not None:
-                self.state, out, stats = self._state_step(
-                    self.graph, self.state, batch, now, pid
+                self._state, out, stats = self._state_step(
+                    self.graph, self._state, batch, now, pid
                 )
             else:
-                self.state, out, stats = kernel.step_jit(
-                    self.graph, self.state, batch, now, partition_id=pid
+                self._state, out, stats = kernel.step_jit(
+                    self.graph, self._state, batch, now, partition_id=pid
                 )
         if self._mesh is not None:
             from zeebe_tpu.runtime import metrics as metrics_mod
@@ -3124,7 +3279,7 @@ class TpuPartitionEngine:
             fanout = max(
                 1, self.graph.emit_width if self.graph is not None else 1
             )
-            window = self.state.ei_index.shape[0] // 4
+            window = self._state.ei_index.shape[0] // 4
             this_wave = 5 * fanout * len(live)
             self._keys_at_rebuild += this_wave
             if self._keys_at_rebuild > window:
@@ -3133,7 +3288,7 @@ class TpuPartitionEngine:
                     in zip(self._device_key_counters(), self._keys_rebuilt)
                 )
                 if self._keys_at_rebuild > window:
-                    self.state = state_mod.rebuild_lookup_state(self.state)
+                    self._state = state_mod.rebuild_lookup_state(self.state)
                     self._note_lookup_rebuilt()
         self._mark_device_dirty()  # a kernel step may write any table
         out, stats = self._run_step(batch, now, lane_owner=lane_owner)
@@ -3193,7 +3348,7 @@ class TpuPartitionEngine:
         sync (rejections consume a key in the oracle too)."""
         key = int(np.asarray(self.state.next_wf_key))
         self._mark_device_dirty("keys")
-        self.state = dataclasses.replace(
+        self._state = dataclasses.replace(
             self.state,
             next_wf_key=self.state.next_wf_key + 5,
         )

@@ -32,10 +32,10 @@ from zeebe_tpu.tracing.spans import now_us
 # the phases of the contract, by track, in the order a cycle runs them (a
 # phase that does not occur in a cycle has zero length and leaves no slice)
 TRACKS = {
-    "wave": ("pack", "route", "stage", "h2d", "launch", "blocked",
-             "readback", "decode", "apply", "push", "job_read"),
+    "wave": ("pack", "route", "stage", "h2d", "credit_flush", "launch",
+             "blocked", "readback", "decode", "apply", "push", "job_read"),
     "drain": ("drain_wait", "pump"),
-    "tick": ("tick", "backlog", "job_read"),
+    "tick": ("tick", "credit_flush", "backlog", "job_read"),
     "raft": ("log_append", "fsync", "commit"),
 }
 # The job path's phases are cut out of the phase they run in: ``push`` out
