@@ -124,7 +124,8 @@ def test_the_cell_is_the_issues():
     assert cfg.scheduler.wave_size == 512
     assert [m["name"] for m in cell.end_to_end] == ["instances_per_s", "setup_s"]
     names = {m["name"] for m in cell.per_layer}
-    assert len(names) == 33 and {
+    assert len(names) == 42 and {  # ISSUE 36 added the actor timeline's nine
+        "actor_busy_share", "actor_idle_ms", "mailbox_wait_ms",
         "h2d_transfers_per_wave", "d2h_transfers_per_wave",  # ISSUE 32
         "segments_per_wave", "segment_max_share", "launch_ahead_depth",
         "backpressure_skips_per_wave", "step_roofline.4p", "device_idle_share.4p",

@@ -98,7 +98,8 @@ def test_the_cell_is_the_issues():
     assert tasks == ["payment-service", "inventory-service", "shipment-service"]
     assert [m["name"] for m in cell.end_to_end] == ["instances_per_s", "setup_s"]
     names = {m["name"] for m in cell.per_layer}
-    assert len(names) == 26 and {  # ISSUE 35 added the flush's two
+    assert len(names) == 35 and {  # ISSUE 35: the flush's two; 36: the actor's nine
+        "actor_busy_share", "actor_idle_before_drain_share", "actor_offcpu_ms",
         "job_park_wait_ms", "parked_walked_per_sweep", "backlog_skipped_per_sweep",
         "credit_return_ms", "credit_returns_per_job", "backlog_activations_per_job.quick",
         "credit_flush_ms", "credit_returns_per_flush",

@@ -644,7 +644,7 @@ class TestClusterScheduler:
             jobs = []
 
             class Mailbox:
-                def run(self, fn):
+                def run(self, fn, kind="other"):
                     jobs.append(fn)
 
             class CommittingFeed(FakeFeed):

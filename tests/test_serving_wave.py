@@ -185,7 +185,7 @@ class TestWaveDrainParity:
         # the gauges render on the global registry (the /metrics surface)
         text = GLOBAL_REGISTRY.dump()
         assert "zb_serving_wave_fill" in text
-        assert "zb_serving_wave_occupancy" in text
+        assert "zb_serving_wave_records_total" in text
         assert "zb_serving_host_seconds_total" in text
 
 

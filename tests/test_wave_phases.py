@@ -477,7 +477,10 @@ class TestReaders:
         events = trace_report.convert(doc)["traceEvents"]
         host = [e for e in events if e["pid"] == "host" and e["ph"] == "X"]
         assert len(host) == want > 0
-        assert {e["tid"] for e in host} == {"wave", "drain", "tick", "raft"}
+        # the actors' own rows (ISSUE 36) above the cycles that run inside them
+        assert {e["tid"] for e in host} == {
+            "actor", "wave", "drain", "tick", "actor:raft", "raft",
+        }
         assert all(e["dur"] > 0 for e in host)
 
     @pytest.fixture

@@ -4,7 +4,8 @@
 Input: the JSON document ``RecordTracer.dump`` writes (format
 ``zeebe-tpu-trace-v1``: record-lifecycle spans, per-wave device
 timelines with their host phases, the drains', ticks' and raft group
-commits' phases, and the flight-recorder event ring).
+commits' phases, the measured actors' jobs, and the flight-recorder event
+ring).
 
 Output: Chrome trace-event JSON (load in ``chrome://tracing`` or
 https://ui.perfetto.dev):
@@ -12,10 +13,11 @@ https://ui.perfetto.dev):
 - one track per traced record (``pid="records"``, ``tid=trace-<id>``)
   with an ``X`` slice per stage interval plus instant events at each
   stamp — the per-stage attribution view;
-- one row per host track (``pid="host"``: ``wave``, ``drain``, ``tick``
-  on the broker actor, ``raft`` on the raft actor), sorted above the
-  devices, with an ``X`` slice per phase (docs/operations/tracing.md,
-  "Wave phases");
+- one row per host track (``pid="host"``: ``actor``, the broker actor's
+  jobs and idle stretches, above the ``wave``, ``drain`` and ``tick``
+  cycles that run inside them; ``actor:raft`` above ``raft``, the raft
+  actor's), sorted above the devices, with an ``X`` slice per phase
+  (docs/operations/tracing.md, "Wave phases");
 - one track per mesh device (``pid="devices"``) with an ``X`` slice per
   wave segment (dispatch → collect), labeled with fill and the
   host/device time split;
@@ -88,12 +90,19 @@ def wave_events(wave: dict) -> list:
     return out
 
 
+# the host rows from the top: an actor's jobs above the cycles inside them
+HOST_ROWS = ("actor", "wave", "drain", "tick", "actor:raft", "raft")
+
+
 def phase_events(cycle: dict) -> list:
-    """One slice per host phase of a wave (``wave_id``) or of a drain,
-    tick or raft group commit (``track``, ``cycle_id``)."""
+    """One slice per host phase of a wave (``wave_id``), of a drain, tick
+    or raft group commit, or of an actor's job (``track``, ``cycle_id``);
+    each measured role's jobs on a row of their own."""
     track = cycle.get("track", "wave")
+    if track == "actor" and cycle.get("role", "broker") != "broker":
+        track = f"actor:{cycle['role']}"
     ident = {
-        k: cycle[k] for k in ("wave_id", "cycle_id", "partition")
+        k: cycle[k] for k in ("wave_id", "cycle_id", "partition", "role", "kind")
         if k in cycle
     }
     return [
@@ -151,6 +160,11 @@ def convert(doc: dict) -> dict:
         for i, pid in enumerate(("host", "devices"))
     )
     events.extend(
+        {"name": "thread_sort_index", "ph": "M", "pid": "host", "tid": row,
+         "args": {"sort_index": i}}
+        for i, row in enumerate(HOST_ROWS)
+    )
+    events.extend(
         flight_events(doc.get("events", []), doc.get("span_t0_wall"))
     )
     return {
@@ -188,6 +202,9 @@ def selftest() -> int:
         "cycles": [{
             "track": "raft", "cycle_id": 0, "partition": 0,
             "phases": [["log_append", 24, 27], ["fsync", 27, 29]],
+        }, {
+            "track": "actor", "cycle_id": 1, "role": "broker", "kind": "drain",
+            "phases": [["actor_idle", 12, 17], ["job:drain", 17, 46]],
         }],
         "events": [
             {"seq": 0, "t": 100.0, "cat": "raft", "msg": "state -> leader"},
@@ -201,6 +218,7 @@ def selftest() -> int:
     assert [(e["tid"], e["name"]) for e in host] == [
         ("wave", "pack"), ("wave", "route"), ("wave", "stage"),
         ("raft", "log_append"), ("raft", "fsync"),
+        ("actor", "actor_idle"), ("actor", "job:drain"),
     ]
     flight = [e for e in events if e["pid"] == "flight"]
     assert flight

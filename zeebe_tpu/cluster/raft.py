@@ -109,6 +109,8 @@ class RaftPersistentStorage:
 class Raft(Actor):
     """One node's raft endpoint for one partition."""
 
+    role = "raft"  # every job of this actor is timed (actors._run_job)
+
     def __init__(
         self,
         node_id: str,

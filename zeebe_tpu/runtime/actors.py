@@ -16,16 +16,31 @@ raft heartbeats. Python threads suffice for that (the GIL is irrelevant to
 control-plane rates); the single-writer actor contract is what matters and
 is preserved: an actor's jobs are serialized through its own mailbox, so
 actor state needs no locks.
+
+The loop that runs the jobs is also where an actor's time is measured
+(``_run_job``; docs/operations/tracing.md, "The actor's timeline"): every job
+of an actor that names a ``role`` is timed where it runs, whoever enqueued
+it, so the actor's busy and idle time add up to the wall clock.
 """
 
 from __future__ import annotations
 
 import heapq
 import itertools
+import sys
 import threading
 import time
 from collections import deque
 from typing import Any, Callable, List, Optional
+
+from zeebe_tpu import tracing
+from zeebe_tpu._events import observe_phases
+from zeebe_tpu.tracing.phases import (
+    CPU_CLOCK_STRIDE,
+    ROLES,
+    job_names,
+    thread_phase_us,
+)
 
 
 class ActorFuture:
@@ -97,11 +112,12 @@ class _Timer:
 
 
 class _Job:
-    __slots__ = ("actor", "fn")
+    __slots__ = ("actor", "fn", "kind")
 
-    def __init__(self, actor: "Actor", fn: Callable[[], None]):
+    def __init__(self, actor: "Actor", fn: Callable[[], None], kind: str = "other"):
         self.actor = actor
         self.fn = fn
+        self.kind = kind  # what a measured actor's time in it counts as
 
 
 class _Condition:
@@ -127,26 +143,34 @@ class ActorControl:
         self._actor = actor
         self._scheduler = scheduler
 
-    def run(self, fn: Callable[[], None]) -> None:
-        """Enqueue a job on this actor (reference actor.run/submit)."""
-        self._scheduler._enqueue(_Job(self._actor, fn))
+    def run(self, fn: Callable[[], None], kind: str = "other") -> None:
+        """Enqueue a job on this actor (reference actor.run/submit).
+        ``kind`` names what the job is for on a measured actor's timeline
+        (``tracing.phases.JOB_KINDS``)."""
+        self._scheduler._enqueue(_Job(self._actor, fn, kind))
 
     submit = run
 
-    def run_delayed(self, delay_ms: int, fn: Callable[[], None]) -> _Timer:
+    def run_delayed(
+        self, delay_ms: int, fn: Callable[[], None], kind: str = "other"
+    ) -> _Timer:
         return self._scheduler._schedule_timer(
-            self._actor, delay_ms, fn, interval_ms=None
+            self._actor, delay_ms, fn, interval_ms=None, kind=kind
         )
 
-    def run_at_fixed_rate(self, period_ms: int, fn: Callable[[], None]) -> _Timer:
+    def run_at_fixed_rate(
+        self, period_ms: int, fn: Callable[[], None], kind: str = "other"
+    ) -> _Timer:
         return self._scheduler._schedule_timer(
-            self._actor, period_ms, fn, interval_ms=period_ms
+            self._actor, period_ms, fn, interval_ms=period_ms, kind=kind
         )
 
-    def on_condition(self, name: str, fn: Callable[[], None]) -> _Condition:
-        return _Condition(name, _Job(self._actor, fn), self._scheduler)
+    def on_condition(
+        self, name: str, fn: Callable[[], None], kind: str = "other"
+    ) -> _Condition:
+        return _Condition(name, _Job(self._actor, fn, kind), self._scheduler)
 
-    def call(self, fn: Callable[[], Any]) -> ActorFuture:
+    def call(self, fn: Callable[[], Any], kind: str = "other") -> ActorFuture:
         """Run ``fn`` on this actor; complete a future with its result
         (reference ActorControl.call — the cross-actor ask pattern)."""
         future = ActorFuture()
@@ -157,23 +181,32 @@ class ActorControl:
             except BaseException as e:  # noqa: BLE001 - forwarded to future
                 future.complete_exceptionally(e)
 
-        self.run(run)
+        self.run(run, kind)
         return future
 
-    def run_on_completion(self, future: ActorFuture, fn: Callable[[ActorFuture], None]) -> None:
+    def run_on_completion(
+        self, future: ActorFuture, fn: Callable[[ActorFuture], None],
+        kind: str = "other",
+    ) -> None:
         """Resume on this actor when ``future`` completes (the actor-safe
         continuation; reference actor.runOnCompletion)."""
-        future.on_complete(lambda f: self.run(lambda: fn(f)))
+        future.on_complete(lambda f: self.run(lambda: fn(f), kind))
 
 
 class Actor:
     """Base class: subclass and override ``on_actor_started`` /
     ``on_actor_closing``. All callbacks run serialized (single-writer)."""
 
+    # an actor that names its role (``broker``, ``raft``) has every job
+    # timed by the loop that runs it (ActorScheduler._run_job)
+    role: Optional[str] = None
+
     def __init__(self, name: Optional[str] = None):
         self.name = name or type(self).__name__
         self.actor: ActorControl = None  # injected at submit
-        self._mailbox: deque = deque()
+        self._mailbox: deque = deque()  # (fn, kind, enqueue stamp)
+        self._job_end_us = 0  # where this actor's last timed job ended
+        self._cpu_clock_in = 0  # jobs until the next one on the CPU clock
         self._running = False  # a worker is draining this actor's mailbox
         self._closed = False
         self._failure_count = 0  # jobs that raised (see ActorScheduler._drain)
@@ -287,6 +320,9 @@ class ActorScheduler:
 
         Reference: ActorScheduler.submitActor (+ io-bound group selection).
         """
+        if actor.role is not None and actor.role not in ROLES:
+            # its first mailbox run would find no counters to flush into
+            raise ValueError(f"actor {actor.name}: unknown role {actor.role!r}")
         actor.actor = ActorControl(actor, self)
         actor._io_bound = io_bound
         started = ActorFuture()
@@ -317,10 +353,12 @@ class ActorScheduler:
 
     def _enqueue(self, job: _Job) -> None:
         actor = job.actor
+        # a measured actor's job waits in the mailbox from here
+        entry = (job.fn, job.kind, tracing.now_us() if actor.role else 0)
         with actor._mailbox_lock:
             if actor._closed:
                 return
-            actor._mailbox.append(job.fn)
+            actor._mailbox.append(entry)
             if actor._running:
                 return  # the draining worker will pick it up
             actor._running = True
@@ -330,10 +368,12 @@ class ActorScheduler:
             self._cv.notify()
 
     def _schedule_timer(
-        self, actor: Actor, delay_ms: int, fn: Callable[[], None], interval_ms
+        self, actor: Actor, delay_ms: int, fn: Callable[[], None], interval_ms,
+        kind: str = "other",
     ) -> _Timer:
         timer = _Timer(
-            self.now_ms() + delay_ms, next(self._timer_seq), _Job(actor, fn), interval_ms
+            self.now_ms() + delay_ms, next(self._timer_seq),
+            _Job(actor, fn, kind), interval_ms,
         )
         with self._cv:
             heapq.heappush(self._timers, timer)
@@ -350,24 +390,106 @@ class ActorScheduler:
                 actor = queue.popleft()
             self._drain(actor)
 
-    def _drain(self, actor: Actor, max_jobs: int = 64) -> None:
+    def _drain(self, actor: Actor, max_jobs: int = 64) -> int:
         """Run up to max_jobs queued jobs of one actor, then yield the thread
-        (cooperative fairness — the reference's task-switching)."""
-        for _ in range(max_jobs):
-            with actor._mailbox_lock:
-                if not actor._mailbox:
-                    actor._running = False
-                    return
-                fn = actor._mailbox.popleft()
-            try:
-                fn()
-            except Exception as exc:  # noqa: BLE001
-                self._record_failure(actor, exc)
+        (cooperative fairness — the reference's task-switching). Returns the
+        jobs run: ``max_jobs`` where the actor was requeued."""
+        # the job times of this mailbox run, flushed into the counters ONCE
+        # at its end (a counter's inc takes a lock)
+        times = tracing.PhaseClock() if actor.role else None
+        try:
+            for ran in range(max_jobs):
+                with actor._mailbox_lock:
+                    if not actor._mailbox:
+                        actor._running = False
+                        return ran
+                    job = actor._mailbox.popleft()
+                self._run_job(actor, job, times)
+        finally:
+            if times is not None and times.us:
+                observe_phases(times)
         # still work left: requeue for fairness
         queue = self._io_runq if getattr(actor, "_io_bound", False) else self._runq
         with self._cv:
             queue.append(actor)
             self._cv.notify()
+        return max_jobs
+
+    def _run_job(self, actor: Actor, job: tuple, times) -> None:
+        """Run one job. For a measured actor (``times`` is its mailbox
+        run's totals) this is the ONE stopwatch over everything the actor
+        does: the job's wall time, its wait in the mailbox, the idle stretch
+        it ended, and its SELF time (wall less the phases that
+        ``PhaseClock``s cut on this thread while it ran); for one job in
+        ``CPU_CLOCK_STRIDE`` of the kinds that can wait for the device
+        (``JobNames.cpu_clock``) its thread-CPU time too. A job the tracer's
+        stride selects also lands on track ``actor`` of the cycle ring and
+        holds a profiler annotation."""
+        fn, kind, asked_us = job
+        if times is None:
+            try:
+                fn()
+            except Exception as exc:  # noqa: BLE001
+                self._record_failure(actor, exc)
+            return
+        t0 = tracing.now_us()
+        names = job_names(actor.role, kind)
+        on_cpu_clock = False
+        if names.cpu_clock:
+            actor._cpu_clock_in -= 1
+            if actor._cpu_clock_in < 0:
+                actor._cpu_clock_in = CPU_CLOCK_STRIDE - 1
+                on_cpu_clock = True
+                cpu0 = time.thread_time_ns()
+        slices = annotation = None
+        tracer = tracing.TRACER
+        if tracer is not None:
+            slices = tracer.cycles.cycle("actor", role=actor.role, kind=kind)
+            if slices is not None:
+                # jax loaded, and to its end: another thread may be in the
+                # middle of importing it while this actor already runs jobs
+                profiler = getattr(sys.modules.get("jax"), "profiler", None)
+                if profiler is not None:
+                    annotation = profiler.TraceAnnotation(
+                        "zb:" + names.job_slice
+                    )
+                    annotation.__enter__()
+        in_phases = thread_phase_us()
+        try:
+            fn()
+        except Exception as exc:  # noqa: BLE001
+            self._record_failure(actor, exc)
+        in_phases = thread_phase_us() - in_phases
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+        if on_cpu_clock:
+            cpu = (time.thread_time_ns() - cpu0) // 1000
+        t1 = tracing.now_us()
+        wall = t1 - t0
+        # the mailbox was empty from the end of the last job until this one
+        # was asked for: the actor waited for whatever asked
+        ended = actor._job_end_us
+        idle = t0 - ended if 0 < ended <= asked_us else 0
+        actor._job_end_us = t1
+        us = times.us
+        us[names.busy] = us.get(names.busy, 0) + wall
+        if on_cpu_clock:
+            us[names.cpu] = us.get(names.cpu, 0) + cpu
+            # two clocks: never below zero, a counter does not fall
+            us[names.offcpu] = us.get(names.offcpu, 0) + max(0, wall - cpu)
+            times.counts[names.cpu_jobs] = times.counts.get(names.cpu_jobs, 0) + 1
+        if idle:
+            us[names.idle] = us.get(names.idle, 0) + idle
+        if names.jobs is not None:
+            us[names.self_time] = us.get(names.self_time, 0) + wall - in_phases
+            us[names.mailbox_wait] = us.get(names.mailbox_wait, 0) + t0 - asked_us
+            if idle:
+                us[names.idle_before] = us.get(names.idle_before, 0) + idle
+            times.counts[names.jobs] = times.counts.get(names.jobs, 0) + 1
+        if slices is not None:
+            if idle:
+                slices.append([names.idle_slice, ended, t0])
+            slices.append([names.job_slice, t0, t1])
 
     def _expire_due_timers(self, now: int) -> None:
         """Pop cancelled/due timers, enqueue their jobs, reschedule fixed
@@ -432,16 +554,6 @@ class ControlledActorScheduler(ActorScheduler):
                     break
             if actor is None:
                 return ran
-            while True:
-                with actor._mailbox_lock:
-                    if not actor._mailbox:
-                        actor._running = False
-                        break
-                    fn = actor._mailbox.popleft()
-                try:
-                    fn()
-                except Exception as exc:  # noqa: BLE001
-                    self._record_failure(actor, exc)
-                ran += 1
-                if ran > max_jobs:
-                    raise RuntimeError("controlled scheduler did not quiesce")
+            ran += self._drain(actor, max_jobs + 1 - ran)
+            if ran > max_jobs:
+                raise RuntimeError("controlled scheduler did not quiesce")

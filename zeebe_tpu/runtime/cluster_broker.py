@@ -913,6 +913,8 @@ class ClusterBroker(Actor):
     APIs. Create several in one process for a cluster (the reference's
     ClusteringRule runs 3 real brokers in one JVM)."""
 
+    role = "broker"  # every job of this actor is timed (actors._run_job)
+
     def __init__(
         self,
         cfg: BrokerCfg,
@@ -1126,7 +1128,7 @@ class ClusterBroker(Actor):
         self.actor.run_at_fixed_rate(
             self._snapshot_period_ms, self._snapshot_all_on_actor
         )
-        self.actor.run_at_fixed_rate(100, self._tick_engines)
+        self.actor.run_at_fixed_rate(100, self._tick_engines, kind="tick")
         # disseminate this node's client endpoint so the topic orchestrator
         # can reach any member over the management plane (reference: local
         # node info broadcast via gossip custom events)
@@ -1532,7 +1534,7 @@ class ClusterBroker(Actor):
         if self._drain_scheduled:
             return
         self._drain_scheduled = True
-        self.actor_control.run(self._drain_committed)
+        self.actor_control.run(self._drain_committed, kind="drain")
 
     def _drain_committed(self) -> None:
         """One drain job is ONE shared wave, and the next job goes to the
@@ -1631,7 +1633,9 @@ class ClusterBroker(Actor):
                 result.on_complete(
                     lambda _f, k=conn_key: self.admission.release(k)
                 )
-            self.actor.run(lambda: self._handle_command(msg, result))
+            self.actor.run(
+                lambda: self._handle_command(msg, result), kind="command"
+            )
             return result
         if t == "topology":
             # answered inline on the transport thread: topology state has
@@ -1642,11 +1646,17 @@ class ClusterBroker(Actor):
             return self._handle_topology_request()
         if t == "job-subscription":
             result = ActorFuture()
-            self.actor.run(lambda: self._handle_job_subscription(msg, conn, result))
+            self.actor.run(
+                lambda: self._handle_job_subscription(msg, conn, result),
+                kind="job_subscription",
+            )
             return result
         if t == "topic-subscription":
             result = ActorFuture()
-            self.actor.run(lambda: self._handle_topic_subscription(msg, conn, result))
+            self.actor.run(
+                lambda: self._handle_topic_subscription(msg, conn, result),
+                kind="topic_subscription",
+            )
             return result
         if t == "fetch-workflow":
             return self.actor.call(lambda: self._handle_fetch_workflow(msg))

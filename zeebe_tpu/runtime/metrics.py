@@ -247,8 +247,10 @@ def event_count(name: str) -> float:
 # -- serving-plane wave instrumentation --------------------------------------
 # The pipelined batched drain (runtime/broker.run_until_idle waves, the
 # wave scheduler's shared waves in the cluster broker) reports each
-# dispatched wave here: fill + occupancy gauges localize "the pipeline is
-# running empty" vs "the device is the bottleneck" without a profiler, and
+# dispatched wave here: the fill gauge and records over waves localize "the
+# pipeline is running empty" vs "the device is the bottleneck" without a
+# profiler (occupancy = serving_wave_records_total / (serving_waves_total x
+# the wave size)), and
 # the host/device second counters give the time split ``wave_host_share``
 # reads. Handles are cached — this sits on the drain hot loop.
 _WAVE_HANDLES: dict = {}
@@ -268,10 +270,6 @@ def _wave_handles() -> dict:
             ),
             fill=g.gauge(
                 "serving_wave_fill", "Records in the most recent drain wave"
-            ),
-            occupancy=g.gauge(
-                "serving_wave_occupancy",
-                "Most recent wave's fill fraction of the drain-chunk capacity",
             ),
             host_s=g.counter(
                 "serving_host_seconds_total",
@@ -498,6 +496,178 @@ def _phase_handles() -> dict:
                 "raft_group_commits_total",
                 "Raft group commits (one log.flush each)",
             ),
+            # the actors' own timeline (runtime/actors.ActorScheduler._run_job),
+            # under the keys tracing.phases.JobNames gives; flushed once per
+            # mailbox run
+            broker_actor_busy=g.counter(
+                "broker_actor_busy_seconds_total",
+                "Seconds the broker actor spent running jobs (wall time, "
+                "the phases inside them included)",
+            ),
+            broker_actor_cpu=g.counter(
+                "broker_actor_cpu_seconds_total",
+                "Thread-CPU seconds of the broker actor's jobs on the CPU "
+                "clock (one in eight of its drain jobs, the kind that runs "
+                "the waves)",
+            ),
+            broker_actor_offcpu=g.counter(
+                "broker_actor_offcpu_seconds_total",
+                "Seconds those jobs were off the CPU: waiting for the "
+                "device, a lock, the interpreter lock, a system call (their "
+                "wall time less their CPU time)",
+            ),
+            broker_actor_cpu_clock_jobs=g.counter(
+                "broker_actor_cpu_clock_jobs_total",
+                "Jobs of the broker actor put on the CPU clock (a system "
+                "call a reading: a sample, not every job)",
+            ),
+            broker_actor_idle=g.counter(
+                "broker_actor_idle_seconds_total",
+                "Seconds the broker actor had an empty mailbox, from the "
+                "end of a job to the start of the next",
+            ),
+            raft_actor_busy=g.counter(
+                "raft_actor_busy_seconds_total",
+                "Seconds the raft actors spent running jobs (wall time, the "
+                "phases inside them included)",
+            ),
+            raft_actor_cpu=g.counter(
+                "raft_actor_cpu_seconds_total",
+                "Thread-CPU seconds of the raft actors' jobs on the CPU "
+                "clock (one in eight of their jobs)",
+            ),
+            raft_actor_offcpu=g.counter(
+                "raft_actor_offcpu_seconds_total",
+                "Seconds those jobs were off the CPU: waiting for the "
+                "device, a lock, the interpreter lock, a system call (their "
+                "wall time less their CPU time)",
+            ),
+            raft_actor_cpu_clock_jobs=g.counter(
+                "raft_actor_cpu_clock_jobs_total",
+                "Jobs of the raft actors put on the CPU clock (a system "
+                "call a reading: a sample, not every job)",
+            ),
+            raft_actor_idle=g.counter(
+                "raft_actor_idle_seconds_total",
+                "Seconds the raft actors had an empty mailbox, from the end "
+                "of a job to the start of the next",
+            ),
+            broker_actor_command=g.counter(
+                "broker_actor_command_seconds_total",
+                "Self seconds of the broker actor's client commands "
+                "(_handle_command): wall time less the phases recorded on "
+                "its thread meanwhile",
+            ),
+            broker_actor_command_jobs=g.counter(
+                "broker_actor_command_jobs_total",
+                "The broker actor's command jobs run",
+            ),
+            broker_actor_command_mailbox_wait=g.counter(
+                "broker_actor_command_mailbox_wait_seconds_total",
+                "Seconds the broker actor's command jobs waited in its "
+                "mailbox, enqueue to start",
+            ),
+            broker_actor_idle_before_command=g.counter(
+                "broker_actor_idle_before_command_seconds_total",
+                "Idle seconds of the broker actor that a command job ended",
+            ),
+            broker_actor_job_subscription=g.counter(
+                "broker_actor_job_subscription_seconds_total",
+                "Self seconds of the broker actor's job-subscription "
+                "requests (a worker's open, close and credit returns): wall "
+                "time less the phases recorded on its thread meanwhile",
+            ),
+            broker_actor_job_subscription_jobs=g.counter(
+                "broker_actor_job_subscription_jobs_total",
+                "The broker actor's job_subscription jobs run",
+            ),
+            broker_actor_job_subscription_mailbox_wait=g.counter(
+                "broker_actor_job_subscription_mailbox_wait_seconds_total",
+                "Seconds the broker actor's job_subscription jobs waited in "
+                "its mailbox, enqueue to start",
+            ),
+            broker_actor_idle_before_job_subscription=g.counter(
+                "broker_actor_idle_before_job_subscription_seconds_total",
+                "Idle seconds of the broker actor that a job_subscription "
+                "job ended",
+            ),
+            broker_actor_topic_subscription=g.counter(
+                "broker_actor_topic_subscription_seconds_total",
+                "Self seconds of the broker actor's topic-subscription "
+                "requests (open, close, check, acknowledgements): wall time "
+                "less the phases recorded on its thread meanwhile",
+            ),
+            broker_actor_topic_subscription_jobs=g.counter(
+                "broker_actor_topic_subscription_jobs_total",
+                "The broker actor's topic_subscription jobs run",
+            ),
+            broker_actor_topic_subscription_mailbox_wait=g.counter(
+                "broker_actor_topic_subscription_mailbox_wait_seconds_total",
+                "Seconds the broker actor's topic_subscription jobs waited "
+                "in its mailbox, enqueue to start",
+            ),
+            broker_actor_idle_before_topic_subscription=g.counter(
+                "broker_actor_idle_before_topic_subscription_seconds_total",
+                "Idle seconds of the broker actor that a topic_subscription "
+                "job ended",
+            ),
+            broker_actor_drain=g.counter(
+                "broker_actor_drain_seconds_total",
+                "Self seconds of the broker actor's drain jobs "
+                "(_drain_committed: one shared wave and the drain's pump): "
+                "wall time less the phases recorded on its thread meanwhile",
+            ),
+            broker_actor_drain_jobs=g.counter(
+                "broker_actor_drain_jobs_total",
+                "The broker actor's drain jobs run",
+            ),
+            broker_actor_drain_mailbox_wait=g.counter(
+                "broker_actor_drain_mailbox_wait_seconds_total",
+                "Seconds the broker actor's drain jobs waited in its "
+                "mailbox, enqueue to start",
+            ),
+            broker_actor_idle_before_drain=g.counter(
+                "broker_actor_idle_before_drain_seconds_total",
+                "Idle seconds of the broker actor that a drain job ended",
+            ),
+            broker_actor_tick=g.counter(
+                "broker_actor_tick_seconds_total",
+                "Self seconds of the broker actor's tick jobs "
+                "(_tick_engines, every 100 ms): wall time less the phases "
+                "recorded on its thread meanwhile",
+            ),
+            broker_actor_tick_jobs=g.counter(
+                "broker_actor_tick_jobs_total",
+                "The broker actor's tick jobs run",
+            ),
+            broker_actor_tick_mailbox_wait=g.counter(
+                "broker_actor_tick_mailbox_wait_seconds_total",
+                "Seconds the broker actor's tick jobs waited in its "
+                "mailbox, enqueue to start",
+            ),
+            broker_actor_idle_before_tick=g.counter(
+                "broker_actor_idle_before_tick_seconds_total",
+                "Idle seconds of the broker actor that a tick job ended",
+            ),
+            broker_actor_other=g.counter(
+                "broker_actor_other_seconds_total",
+                "Self seconds of the broker actor's other jobs (leader "
+                "install, snapshots, gossip, fetches, continuations): wall "
+                "time less the phases recorded on its thread meanwhile",
+            ),
+            broker_actor_other_jobs=g.counter(
+                "broker_actor_other_jobs_total",
+                "The broker actor's other jobs run",
+            ),
+            broker_actor_other_mailbox_wait=g.counter(
+                "broker_actor_other_mailbox_wait_seconds_total",
+                "Seconds the broker actor's other jobs waited in its "
+                "mailbox, enqueue to start",
+            ),
+            broker_actor_idle_before_other=g.counter(
+                "broker_actor_idle_before_other_seconds_total",
+                "Idle seconds of the broker actor that a other job ended",
+            ),
         )
     return _PHASE_HANDLES
 
@@ -531,8 +701,6 @@ def observe_wave(
     h["waves"].inc()
     h["records"].inc(records)
     h["fill"].set(records)
-    if capacity > 0:
-        h["occupancy"].set(records / capacity)
     if host_seconds > 0:
         h["host_s"].inc(host_seconds)
     if device_seconds > 0:
@@ -600,7 +768,7 @@ def observe_shared_wave(
     launch_ahead: int = 0,
 ) -> None:
     """Record one SHARED drain wave (scheduler path): the plain wave
-    series (fill/occupancy/time split) plus the traffic-mix gauges.
+    series (fill/time split) plus the traffic-mix gauges.
     ``segments`` counts the segments whose dispatch returned,
     ``segment_max`` is the record count of the wave's largest segment,
     ``launch_ahead`` the sum over its segments of the launched segments

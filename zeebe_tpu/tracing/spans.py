@@ -167,8 +167,9 @@ class WaveTimeline:
 
     def cycle(self, track: str, **fields) -> Optional[list]:
         """Stride-select one cycle that is no wave (a drain, a tick, a raft
-        group commit): returns the slice list of its event for the cycle's
-        phase clock to fill, or None where the stride passes it over."""
+        group commit, an actor's job): returns the slice list of its event
+        for the cycle's phase clock (or the job's stopwatch) to fill, or
+        None where the stride passes it over."""
         cycle_id = next(self.seq)
         if cycle_id % self.stride:
             return None
@@ -252,11 +253,14 @@ class RecordTracer:
         elif self.sample_rate < 1.0:
             stride = min(1000, max(1, round(1.0 / self.sample_rate)))
         self.waves = WaveTimeline(stride=stride)
-        # drains, ticks and raft group commits: same class, same stride.
-        # They come several times as often as waves (a group commit per
-        # client command at worst), and a reader of a 51 s run at rate 1.0
-        # still wants the seconds in its middle when the run has ended.
-        self.cycles = WaveTimeline(capacity=32768, stride=stride)
+        # drains, ticks, raft group commits and the measured actors' jobs
+        # (track ``actor``): same class, same stride. The jobs come by the
+        # thousand a second (every client command is one on the broker
+        # actor and one on the raft actor), and a reader of a 51 s run at
+        # rate 1.0 still wants the seconds in its middle when the run has
+        # ended: room for half a minute of 8,000 cycles a second (the ring
+        # holds what was recorded, a few hundred bytes a cycle).
+        self.cycles = WaveTimeline(capacity=262144, stride=stride)
         self._dropped = 0
         self._sampled = 0
 
