@@ -356,37 +356,45 @@ class TestInstanceCounts:
 
 
 class TestPayloadContract:
-    """TPU partitions reject (not crash on, not round) payload numbers that
-    are not exactly representable in float32 — the device stores payload
-    numerics as f32 (state.pack_payload); the reference likewise validates
-    msgpack documents at the client API boundary
-    (``ClientApiMessageHandler.java:90-165``)."""
+    """TPU partitions hold payload numbers that f32 does not hold exactly
+    on the host (``tpu/values.py``) and give them back exactly, as the host
+    engine does: the device stores payload numerics as f32
+    (state.pack_payload), so such a number rides as a handle, and a
+    workflow that compares it runs on the host engine."""
 
     def _tpu_broker(self):
         from tests.conftest import make_tpu_broker
 
         return make_tpu_broker()
 
-    def test_inexact_float_create_is_rejected(self):
-        from zeebe_tpu.protocol.enums import RejectionType
+    def test_inexact_float_create_is_rejected(self, rig):
+        """(Named for the rejection that went:) the create of an inexact
+        float is accepted, and its records are the host engine's."""
 
-        broker = self._tpu_broker()
-        try:
-            client = ZeebeClient(broker)
+        def scenario(broker, client, clock):
             client.deploy_model(order_process())
-            with pytest.raises(ClientException) as err:
-                client.create_instance("order-process", {"x": 0.1})
-            assert "float32" in str(err.value)
-            broker.run_until_idle()
-            rejections = [
-                r for r in broker.records(0)
-                if int(r.metadata.record_type) == int(RecordType.COMMAND_REJECTION)
-                and int(r.metadata.value_type) == int(ValueType.WORKFLOW_INSTANCE)
-            ]
-            assert len(rejections) == 1
-            assert rejections[0].metadata.rejection_type == RejectionType.BAD_VALUE
-        finally:
-            broker.close()
+            JobWorker(broker, "payment-service", lambda ctx: {"paid": True})
+            inst = client.create_instance(
+                "order-process", {"x": 0.1, "big": (1 << 53) + 1}
+            )
+            assert inst.workflow_instance_key > 0
+
+        rig.run(scenario)
+        rig.assert_parity()
+        tpu = rig.brokers[1]
+        assert not any(
+            int(r.metadata.record_type) == int(RecordType.COMMAND_REJECTION)
+            for r in tpu.records(0)
+        )
+        payloads = [
+            r.value.payload for r in tpu.records(0)
+            if int(r.metadata.value_type) == int(ValueType.WORKFLOW_INSTANCE)
+            and "x" in r.value.payload
+        ]
+        assert len(payloads) > 5 and all(
+            p["x"] == 0.1 and p["big"] == (1 << 53) + 1 and type(p["big"]) is int
+            for p in payloads
+        )
 
     def test_exact_float_passes(self):
         broker = self._tpu_broker()

@@ -472,6 +472,39 @@ def _phase_handles() -> dict:
                 "serving_job_credit_returns_total: the returns one launch "
                 "carried)",
             ),
+            # the device engine's host-held payload values (tpu/values.py)
+            payload_values_held=g.counter(
+                "serving_payload_values_held_total",
+                "Payload values with no column form (a map, a list, a number "
+                "f32 does not hold exactly) the device engine staged into its "
+                "value store under a new handle",
+            ),
+            payload_value_bytes=g.counter(
+                "serving_payload_value_bytes_total",
+                "Msgpack bytes of the payload values staged into the value "
+                "store",
+            ),
+            payload_values_freed=g.counter(
+                "serving_payload_values_freed_total",
+                "Held payload values freed: their last owner (an instance, a "
+                "message) ended, or the command that staged them was rejected",
+            ),
+            payload_values_live=g.gauge(
+                "serving_payload_values_live",
+                "Held payload values now live (held less freed, set at each "
+                "flush)",
+            ),
+            payload_value_seconds=g.counter(
+                "serving_payload_value_seconds_total",
+                "Seconds holding, resolving, owning and freeing payload "
+                "values, on a clock of its own: inside the phases stage, "
+                "decode and push, which keep the time",
+            ),
+            payload_host_routed=g.counter(
+                "serving_payload_host_routed_total",
+                "Instances sent to the host engine because a variable their "
+                "workflow reads held a value with no column form",
+            ),
             backlog_skipped_in_flight=g.counter(
                 "serving_backlog_skipped_in_flight_total",
                 "Activatable jobs a backlog sweep left alone because a pool "
@@ -681,6 +714,10 @@ def observe_phases(clock, cycle: Optional[str] = None) -> None:
         h[name].inc(us / 1e6)
     for name, n in clock.counts.items():
         h[name].inc(n)
+    if "payload_values_held" in clock.counts or "payload_values_freed" in clock.counts:
+        h["payload_values_live"].set(
+            h["payload_values_held"].value - h["payload_values_freed"].value
+        )
     if cycle is not None:
         h[cycle].inc()
 

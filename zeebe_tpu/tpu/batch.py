@@ -26,6 +26,7 @@ from zeebe_tpu.tpu.conditions import (
     VT_ABSENT,
     VT_BOOL,
     VT_FLOAT,
+    VT_HELD,
     VT_NIL,
     VT_NUM,
     VT_STR,
@@ -104,9 +105,9 @@ class RecordBatch:
     scope_key: jax.Array    # [B] i64 scopeInstanceKey
     v_vt: jax.Array         # [B, V] i8 payload types
     v_num: jax.Array        # [B, V] f32 (f32-exact by construction; see
-                            # payload_to_columns — inexact values take the
-                            # host-oracle path)
-    v_str: jax.Array        # [B, V] i32
+                            # payload_to_columns — an inexact number is
+                            # held on the host like a map or a list)
+    v_str: jax.Array        # [B, V] i32 intern id, or held-value handle
     req: jax.Array          # [B] i64 request id (-1 none)
     req_stream: jax.Array   # [B] i32 request stream / subscriber key
     aux_key: jax.Array      # [B] i64 job activityInstanceKey / incident aik / timer aik
@@ -167,8 +168,20 @@ def empty(size: int, num_vars: int) -> RecordBatch:
 
 
 class PayloadError(ValueError):
-    """Payload not columnarizable (nested document / unknown type) — the
-    caller must fall back to the host oracle engine."""
+    """Payload not columnarizable (variable space overflow, or a value the
+    caller gave no store to hold) — the caller must fall back to the host
+    oracle engine."""
+
+
+def scalar_form(value) -> bool:
+    """True when ``value`` has a column form of its own: nil, a bool, a
+    string, or a number that f32 holds exactly. Anything else is held on
+    the host (``VT_HELD``)."""
+    if value is None or isinstance(value, (bool, str)):
+        return True
+    if isinstance(value, (int, float)):
+        return f32_exact(value)
+    return False
 
 
 def payload_to_columns(
@@ -176,6 +189,7 @@ def payload_to_columns(
     column_of,          # name -> column (VarSpace.column, growable)
     interns: InternTable,
     num_vars: int,
+    hold=None,          # value -> handle (ValueStore.hold), or None
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     vt = np.zeros((num_vars,), np.int8)
     num = np.zeros((num_vars,), np.float32)
@@ -189,24 +203,26 @@ def payload_to_columns(
         elif isinstance(value, bool):
             vt[col] = VT_BOOL
             num[col] = 1.0 if value else 0.0
-        elif isinstance(value, (int, float)):
-            if not f32_exact(value):
-                raise PayloadError(
-                    f"payload number not f32-exact for {name!r}: {value!r}"
-                )
-            vt[col] = VT_NUM if isinstance(value, int) else VT_FLOAT
-            num[col] = value
         elif isinstance(value, str):
             vt[col] = VT_STR
             sid[col] = interns.intern(value)
+        elif isinstance(value, (int, float)) and f32_exact(value):
+            vt[col] = VT_NUM if isinstance(value, int) else VT_FLOAT
+            num[col] = value
+        elif hold is not None:
+            vt[col] = VT_HELD
+            sid[col] = hold(value)
         else:
-            raise PayloadError(f"non-scalar payload value for {name!r}: {value!r}")
+            raise PayloadError(f"no column form for {name!r}: {value!r}")
     return vt, num, sid
 
 
 def columns_to_payload(
-    vt: np.ndarray, num: np.ndarray, sid: np.ndarray, names, interns: InternTable
+    vt: np.ndarray, num: np.ndarray, sid: np.ndarray, names,
+    interns: InternTable, values=None,
 ) -> Dict[str, Any]:
+    """The document of one row's payload columns; ``values`` resolves a
+    held value's handle (a ``ValueStore`` or a dict a decode kept)."""
     doc: Dict[str, Any] = {}
     for col, name in enumerate(names):
         t = int(vt[col])
@@ -222,6 +238,10 @@ def columns_to_payload(
             doc[name] = float(num[col])
         elif t == VT_STR:
             doc[name] = interns.string(int(sid[col]))
+        elif t == VT_HELD:
+            doc[name] = values[int(sid[col])]
+        else:
+            raise ValueError(f"payload column {name!r} has unknown type tag {t}")
     return doc
 
 

@@ -15,10 +15,13 @@ widen), or ordering is applied to non-numbers — exactly the oracle's raise
 conditions, so an ERROR result maps to the same CONDITION_ERROR incident.
 
 Strings compare by interned id (exact); numbers compare as float32 —
-sound because only f32-EXACT values reach the device (payload
-columnarization and literal compilation both reject inexact values into
-the host path, where the oracle compares float64), and f64→f32 is
-order-preserving on exactly-representable values.
+sound because every number a program reads is f32-EXACT: literal
+compilation sends a workflow with an inexact literal to the host engine,
+and a payload number f32 does not hold is held on the host (``VT_HELD``,
+``tpu/values.py``) exactly like a map or a list, where the engine sends an
+instance whose workflow reads that variable to the host engine, which
+compares in float64. So no held value ever reaches ``_compare``; and
+f64→f32 is order-preserving on exactly-representable values.
 """
 
 from __future__ import annotations
@@ -57,8 +60,11 @@ K_VAR, K_NUM, K_STR, K_BOOL, K_NIL = 0, 1, 2, 3, 4
 
 # variable value types (the ``vtype`` payload column); INTEGER and FLOAT are
 # distinct for document round-trips but compare as one numeric class
-# (the oracle's int/float widening, interpreter.py _coerce_same_type)
-VT_ABSENT, VT_NIL, VT_BOOL, VT_NUM, VT_STR, VT_FLOAT = 0, 1, 2, 3, 4, 5
+# (the oracle's int/float widening, interpreter.py _coerce_same_type).
+# VT_HELD: a value with no scalar column form (a map, a list, a number f32
+# does not hold exactly), its ``v_str`` cell a handle into the engine's
+# host-side value store (``tpu/values.py``); never an operand here
+VT_ABSENT, VT_NIL, VT_BOOL, VT_NUM, VT_STR, VT_FLOAT, VT_HELD = 0, 1, 2, 3, 4, 5, 6
 
 STACK_DEPTH = 8
 
@@ -69,9 +75,17 @@ def f32_exact(value: float) -> bool:
     BOTH gates — payload columnarization (batch.payload_to_columns) and
     condition-literal compilation below — must use this same predicate, or
     the "only f32-exact values reach the device" soundness argument of this
-    module's header breaks."""
-    f = np.float32(value)
-    return bool(np.isfinite(f)) and float(f) == float(value)
+    module's header breaks. An int compares as an int: in float64 an int
+    above 2^53 rounds before it is compared."""
+    try:
+        f = np.float32(value)
+    except OverflowError:
+        return False
+    if not np.isfinite(f):
+        return False
+    if isinstance(value, int):
+        return int(f) == value
+    return float(f) == float(value)
 
 
 class DeviceIneligible(ValueError):

@@ -60,8 +60,9 @@ from zeebe_tpu.tpu import graph as graph_mod
 from zeebe_tpu.tpu import jit_registry
 from zeebe_tpu.tpu import kernel, state as state_mod
 from zeebe_tpu.tpu.batch import PayloadError, RecordBatch
-from zeebe_tpu.tpu.conditions import DeviceIneligible
+from zeebe_tpu.tpu.conditions import VT_HELD, DeviceIneligible
 from zeebe_tpu.tpu.intern import InternTable
+from zeebe_tpu.tpu.values import ValueStore
 
 _DEVICE_VALUE_TYPES = {
     int(ValueType.WORKFLOW_INSTANCE),
@@ -324,6 +325,12 @@ class _PendingSegment:
                                         # collect pops residency from emissions
     blind: bool = False                 # fallback carried rows whose instance
                                         # key the host could not prove
+    # (handle, owner or None) of the values its staging held anew: owned
+    # or freed when the segment is decoded (TpuPartitionEngine._own_held)
+    held: List[tuple] = dataclasses.field(default_factory=list)
+    # per staged row, whether it is a command (None: the value store was
+    # empty, so no emitted row can carry a handle; _held_values)
+    src_commands: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass
@@ -357,6 +364,10 @@ class PendingWave:
     # (key, entry) of the job pool events its device segments step: judged
     # at collect (TpuPartitionEngine._park_unassigned)
     pool_events: List[tuple] = dataclasses.field(default_factory=list)
+    # owners of held payload values that end with this wave (an instance
+    # whose last record it steps or that it demoted, a message deleted):
+    # their values are freed once the wave is collected
+    ending: List = dataclasses.field(default_factory=list)
 
     @property
     def host_seconds(self) -> float:
@@ -560,6 +571,19 @@ class TpuPartitionEngine:
         self.capacity = capacity
         self.num_vars = num_vars
         self.interns = InternTable()
+        # payload values with no column form, held on the host under the
+        # handles their columns carry (tpu/values.py); the variables each
+        # device workflow reads (workflow key -> names, and their union):
+        # where one holds such a value the instance runs on the host engine
+        self._values = ValueStore()
+        self._reads: Dict[int, frozenset] = {}
+        self._reads_any: frozenset = frozenset()
+        # the held values of the segment being staged (_stage_and_launch),
+        # the row whose payload is being staged (_stage_payload), and the
+        # owners that end with the wave being routed
+        self._staged_held: List[tuple] = []
+        self._staging_row: Optional[tuple] = None
+        self._ending: List = []
 
         # host oracle engine for control-plane records (deployment, messages,
         # incidents); shares the repository and the workflow keyspace via
@@ -805,6 +829,8 @@ class TpuPartitionEngine:
                 workflows.append(wf)
         self._host_only_keys = host_only
         self._meta_epoch += 1  # older emission batches' wf slots are stale
+        self._reads = {wf.key: graph_mod.read_variables(wf) for wf in workflows}
+        self._reads_any = frozenset().union(*self._reads.values())
         if not workflows:
             self.graph = None
             self._compiled_count = 0
@@ -925,8 +951,11 @@ class TpuPartitionEngine:
             )
             msg_deadline[slot] = message.deadline
             vt, num, sid = rb.payload_to_columns(
-                message.payload, self._var_column, self.interns, self.num_vars
+                message.payload, self._var_column, self.interns, self.num_vars,
+                hold=self._values.hold,
             )
+            for handle in sid[vt == VT_HELD].tolist():
+                self._values.own(("m", key), handle)
             msg_pay[slot] = np.concatenate(
                 [vt.astype(np.int32), sid,
                  np.ascontiguousarray(num).view(np.int32)]
@@ -1008,11 +1037,13 @@ class TpuPartitionEngine:
                 ),
                 time_to_live=0,
                 payload=rb.columns_to_payload(
-                    *_host_unpack_payload(msg_pay[slot]), names, self.interns
+                    *_host_unpack_payload(msg_pay[slot]), names, self.interns,
+                    self._values,
                 ),
                 message_id=self.interns.string(int(msg_i32[slot, 3])) or "",
                 deadline=int(msg_deadline[slot]),
             )
+            self._ending.append(("m", key))
         v = self.num_vars
         self._state = self._place(dataclasses.replace(
             s,
@@ -1036,7 +1067,7 @@ class TpuPartitionEngine:
         hits = np.nonzero((keys == key) & (states != -1))[0]
         return int(hits[0]) if len(hits) else -1
 
-    def _demote_instance(self, root_key: int) -> None:
+    def _demote_instance(self, root_key: int) -> bool:
         """Migrate a live instance's scope tree (+ its jobs and timers)
         from the device SoA tables into the embedded host oracle.
 
@@ -1046,7 +1077,7 @@ class TpuPartitionEngine:
         sorting, job CANCEL commands) without teaching the SIMD kernel a
         cold path. The device keeps the hot lifecycle; a demoted instance
         finishes on the oracle — semantically invisible, since the oracle
-        IS the semantics."""
+        IS the semantics. True when a live device instance was demoted."""
         from zeebe_tpu.tpu import hashmap
 
         # demotion rewrites device tables AND inserts instances/jobs/timers
@@ -1069,7 +1100,10 @@ class TpuPartitionEngine:
 
         root_slot = self._live_device_instance_slot(root_key)
         if root_slot < 0:
-            return
+            return False
+        # its values now live in the host engine's documents: the device's
+        # handles are freed once the waves in flight are decoded
+        self._ending.append(int(root_key))
         # collect the scope tree (parent-slot pointers, bounded depth)
         tree = {root_slot}
         changed = True
@@ -1106,7 +1140,8 @@ class TpuPartitionEngine:
                     if self.meta else ""
                 ),
                 payload=rb.columns_to_payload(
-                    *_host_unpack_payload(ei_pay[slot]), names, self.interns
+                    *_host_unpack_payload(ei_pay[slot]), names, self.interns,
+                    self._values,
                 ),
                 scope_instance_key=(
                     int(ei_i64[parent_slot, 0]) if parent_slot in tree else -1
@@ -1208,7 +1243,8 @@ class TpuPartitionEngine:
             if scope is None:
                 continue
             merged = rb.columns_to_payload(
-                *_host_unpack_payload(join_pay_np[sl]), names, self.interns
+                *_host_unpack_payload(join_pay_np[sl]), names, self.interns,
+                self._values,
             )
             arrivals = {
                 int(pos): dict(merged)
@@ -1281,6 +1317,7 @@ class TpuPartitionEngine:
         # while the freed rows sit unused until the next cadence rebuild
         self._state = state_mod.rebuild_lookup_state(new_state)
         self._note_lookup_rebuilt()
+        return True
 
     def _routes_to_host(self, record: Record) -> bool:
         """True when a device-value-type record belongs to a host-only
@@ -1298,10 +1335,14 @@ class TpuPartitionEngine:
                 wf_key = wf.key if wf is not None else -1
             if wf_key in self._host_only_keys:
                 return True
-            if self._nonscalar_payload(record):
-                # nested/list payload values have no device column form —
-                # the instance is born (and lives) host-side; the oracle
-                # supports arbitrary documents
+            if self._unscalar_read(record, self._reads_of(wf_key)):
+                # a variable the workflow reads (a condition, a correlation
+                # key, a mapping source) holds a value with no column form:
+                # the instance is born (and lives) host-side, where the
+                # oracle walks documents and compares in f64. Device-born
+                # records never hold one there (the kernel only moves what
+                # staging let in), so this also finds the CREATED event of
+                # an instance whose CREATE it sent to the host
                 return True
             if int(record.metadata.record_type) == int(RecordType.COMMAND) and (
                 intent in (int(WI.CANCEL), int(WI.UPDATE_PAYLOAD))
@@ -1318,10 +1359,13 @@ class TpuPartitionEngine:
                 or value.workflow_instance_key in instances
             )
         if vt == int(ValueType.JOB):
-            if self._nonscalar_payload(record):
-                # e.g. a worker completing with a list-valued result:
-                # process_batch demotes the owning instance first (for
-                # commands; job events with such payloads are host-born)
+            if self._unscalar_read(
+                record, self._reads_of(value.headers.workflow_key)
+            ):
+                # e.g. a worker completing with a map under a variable a
+                # condition reads: process_batch demotes the owning
+                # instance first (for commands; job events with such
+                # payloads are host-born)
                 return True
             return (
                 value.headers.workflow_key in self._host_only_keys
@@ -1342,6 +1386,13 @@ class TpuPartitionEngine:
             # the message store lives on exactly one side (see _recompile)
             return not self._messages_on_device
         if vt == int(ValueType.WORKFLOW_INSTANCE_SUBSCRIPTION):
+            if int(record.metadata.record_type) == int(
+                RecordType.COMMAND
+            ) and self._unscalar_read(record, self._reads_any):
+                # the message brings a value with no column form under a
+                # variable a workflow reads: process_batch demotes the
+                # target instance, and it correlates on the oracle
+                return True
             # CORRELATE routes by where the TARGET INSTANCE lives: demoted
             # and host-only instances correlate on the oracle, device
             # instances in the kernel
@@ -1803,7 +1854,7 @@ class TpuPartitionEngine:
                         ),
                         payload=rb.columns_to_payload(
                             *_host_unpack_payload(msg_pay[slot]),
-                            names, self.interns,
+                            names, self.interns, self._values,
                         ),
                         message_id=(
                             self.interns.string(int(msg_i32[slot, 3])) or ""
@@ -1916,6 +1967,9 @@ class TpuPartitionEngine:
                 # restoring in order reproduces identical ids, which the
                 # table columns (job types, workers, string payloads) hold
                 "interns": [s or "" for s in self.interns._by_id[1:]],
+                # held payload values under the handles the tables carry,
+                # and who owns each (tpu/values.py)
+                "values": self._values.snapshot(),
                 "variables": (
                     list(self.meta.varspace.names) if self.meta else []
                 ),
@@ -1943,6 +1997,7 @@ class TpuPartitionEngine:
         self.interns = InternTable()
         for s in meta.get("interns", []):
             self.interns.intern(s)
+        self._values.restore(meta.get("values"))
         # recompile through the SAME path as deployments (_recompile):
         # it re-derives the host-only split and compiles only the
         # device-compatible subset, so workflow slot numbering matches the
@@ -2068,7 +2123,7 @@ class TpuPartitionEngine:
                 payload=rb.columns_to_payload(
                     *_host_unpack_payload(pay),
                     self.meta.varspace.names if self.meta else [],
-                    self.interns,
+                    self.interns, self._values,
                 ),
                 # as the job's events carry them (_materialize_value)
                 custom_headers=dict(element.job_headers) if element else {},
@@ -2118,6 +2173,7 @@ class TpuPartitionEngine:
         ``Record`` ever materializes for it (the columnar plane's
         device-path slice)."""
         clock = tracing.phase_clock(tracing.selected_slices())
+        self._values.annotate = tracing.TRACER is not None
         with self.on_clock(clock), clock.phase("route"):
             return self._route_wave(records, clock)
 
@@ -2293,17 +2349,10 @@ class TpuPartitionEngine:
             self.records_by_position[pos] = record
             md = record.metadata
             if eligible and not self._routes_to_host(record):
-                # data contract of TPU-backed partitions: payload numbers
-                # must be exactly representable in float32 (device payload
-                # columns are f32). Commands violating it are REJECTED at
-                # the boundary — the reference likewise validates msgpack
-                # documents at the client API (ClientApiMessageHandler) —
-                # instead of silently rounding. Events are engine-produced
-                # and therefore exact by induction.
-                bad = self._inexact_payload_value(record)
-                if bad is not None:
-                    per_record[i] = self._reject_payload(record, bad)
-                    continue
+                # a payload value with no column form (a map, a list, a
+                # number f32 does not hold exactly) under a variable no
+                # workflow reads is staged as a handle into the value
+                # store (_stage_payload): held exactly, never rounded
                 rc = self._wave_route_class(record, False, vt, rt, intent)
                 if pending and rc != pending_route[0]:
                     flush()
@@ -2341,12 +2390,38 @@ class TpuPartitionEngine:
                         self._demote_instance(
                             record.value.workflow_instance_key
                         )
+                    elif int(md.intent) == int(WI.CREATE):
+                        workflow = self._resolve_workflow(record.value)
+                        if workflow is not None and self._unscalar_read(
+                            record, self._reads.get(workflow.key, frozenset())
+                        ):
+                            clock.count("payload_host_routed", 1)
+                elif (
+                    vt in (
+                        int(ValueType.WORKFLOW_INSTANCE_SUBSCRIPTION),
+                        int(ValueType.INCIDENT),
+                    )
+                    and int(md.record_type) == int(RecordType.COMMAND)
+                    and self._unscalar_read(record, self._reads_any)
+                ):
+                    # a correlated message, or an incident resolved with a
+                    # payload, brings a value with no column form under a
+                    # variable a workflow reads: the instance goes to the
+                    # host engine before the command applies there
+                    if self._demote_instance(
+                        record.value.workflow_instance_key
+                    ):
+                        clock.count("payload_host_routed", 1)
                 elif (
                     vt == int(ValueType.JOB)
                     and int(md.record_type) == int(RecordType.COMMAND)
-                    and self._nonscalar_payload(record)
+                    and self._unscalar_read(
+                        record,
+                        self._reads_of(record.value.headers.workflow_key),
+                    )
                 ):
-                    # a non-columnar job result drags the owning instance
+                    # a job result with a value with no column form under a
+                    # variable a workflow reads drags the owning instance
                     # to the host path before the command applies. Client
                     # commands may omit headers — resolve the owner from
                     # the device job table by job key then.
@@ -2360,7 +2435,8 @@ class TpuPartitionEngine:
                             owner = int(
                                 job_i64[int(slots[0]), state_mod.JBL_IKEY]
                             )
-                    self._demote_instance(owner)
+                    if self._demote_instance(owner):
+                        clock.count("payload_host_routed", 1)
                 deployed_before = len(self.repository.by_key)
                 self.host_records_processed += 1
                 wf_of = getattr(
@@ -2390,6 +2466,7 @@ class TpuPartitionEngine:
         push_host_keys()
         if positions:
             self.last_processed_position = positions[-1]
+        wave.ending, self._ending = self._ending, []
         return wave
 
     def _lazy_device_row(self, entry, vt, rt, intent, key) -> bool:
@@ -2399,8 +2476,9 @@ class TpuPartitionEngine:
         materializes and takes the exact per-record path.
 
         Mirrors ``_routes_to_host`` for the EVENT cases it admits —
-        device-born events are f32-exact and scalar by induction, so the
-        payload-contract checks are vacuous for them."""
+        device-born events hold no value without a column form under a
+        variable their workflow reads, by induction, so the payload checks
+        are vacuous for them."""
         if rt != int(RecordType.EVENT):
             return False
         ref = entry[0].device_ref(entry[1])
@@ -2581,6 +2659,9 @@ class TpuPartitionEngine:
                 for i, res in zip(seg.rows, seg.results):
                     wave.per_record[i] = res
             self._park_unassigned(wave.pool_events, clock)
+            self._end_owners(wave.ending)
+            for name, n in self._values.take_counts().items():
+                clock.count(name, n)
             results: List[ProcessingResult] = []
             for pos, res in zip(wave.positions, wave.per_record):
                 if res is None:  # poisoned host record: contained, no output
@@ -2594,6 +2675,14 @@ class TpuPartitionEngine:
         self.last_wave_phases = clock
         wave.collected = results
         return results
+
+    def _end_owners(self, ending: List) -> None:
+        """Free the held values of owners that ended with a collected
+        wave (``_note_ending``, ``_demote_instance``)."""
+        if ending:
+            with self._values.timed():
+                for owner in ending:
+                    self._values.end(owner)
 
     def _park_unassigned(self, pool_events: List[tuple], clock) -> None:
         """The pool events a collected wave stepped, judged on the host
@@ -2650,65 +2739,28 @@ class TpuPartitionEngine:
         if self._host.job_keys.peek < dev_job:
             self._host.job_keys.set_key(dev_job - keyspace.STEP_SIZE)
 
+    def _reads_of(self, workflow_key: int) -> frozenset:
+        """The variables a device workflow reads; for a key the engine did
+        not compile (a client's job command may carry no headers), those
+        any device workflow reads."""
+        reads = self._reads.get(workflow_key)
+        return self._reads_any if reads is None else reads
+
     @staticmethod
-    def _nonscalar_payload(record: Record) -> bool:
-        """True when the record payload holds values with no device column
-        form (lists/nested documents) — such records take the host path.
-        Device-born events are scalar by induction, so a non-scalar
-        payload implies host ownership even before the oracle's
-        element-instance index has the entry (e.g. the CREATED event of an
-        instance whose CREATE was host-routed for this same reason)."""
+    def _unscalar_read(record: Record, reads: frozenset) -> bool:
+        """True when the record's payload holds, under one of ``reads``, a
+        value with no column form (a map, a list, a number f32 does not
+        hold exactly): such a value is never handed to the kernel where it
+        compares or extracts."""
+        if not reads:
+            return False
         payload = getattr(record.value, "payload", None)
         if not payload:
             return False
         return any(
-            not isinstance(v, (type(None), bool, int, float, str))
-            for v in payload.values()
+            name in payload and not rb.scalar_form(payload[name])
+            for name in reads
         )
-
-    def _inexact_payload_value(self, record: Record):
-        """Name of the first payload entry not exactly representable in
-        f32 on a COMMAND record, else None."""
-        from zeebe_tpu.tpu.conditions import f32_exact
-
-        if int(record.metadata.record_type) != int(RecordType.COMMAND):
-            return None
-        payload = getattr(record.value, "payload", None)
-        if not payload:
-            return None
-        for name, value in payload.items():
-            if (
-                isinstance(value, (int, float))
-                and not isinstance(value, bool)
-                and not f32_exact(value)
-            ):
-                return name
-        return None
-
-    def _reject_payload(self, record: Record, field: str) -> ProcessingResult:
-        out = ProcessingResult()
-        md = record.metadata
-        rejection = Record(
-            key=record.key,
-            value=record.value.copy(),
-            metadata=RecordMetadata(
-                record_type=RecordType.COMMAND_REJECTION,
-                value_type=md.value_type,
-                intent=md.intent,
-                rejection_type=RejectionType.BAD_VALUE,
-                rejection_reason=(
-                    f"payload value {field!r} is not exactly representable "
-                    "in float32 (TPU partition payload contract)"
-                ),
-                request_id=md.request_id,
-                request_stream_id=md.request_stream_id,
-            ),
-            source_record_position=record.position,
-        )
-        out.written.append(rejection)
-        if md.request_id >= 0:
-            out.responses.append(rejection)
-        return out
 
     # -- host record → batch row -------------------------------------------
     _TPU_BATCH = 512  # one canonical staged shape on TPU (= drain chunk)
@@ -2778,7 +2830,38 @@ class TpuPartitionEngine:
             _count_staged_columnar(
                 sum(len(rows) for _src, rows, _js in by_source.values())
             )
+        if len(self._values):
+            self._note_ending(cols)
         return self._pack_batch(staged, cols, lane_owner=lane_owner)
+
+    def _note_ending(self, cols) -> None:
+        """Owners of held values whose last record this segment steps: an
+        instance's own ELEMENT_COMPLETED or ELEMENT_TERMINATED event, a
+        message's DELETED event or its PUBLISHED event where it was not
+        stored (no time to live). Every earlier record of theirs is before
+        it in the log, so in this wave or an earlier one: their values are
+        freed once this wave is collected."""
+        from zeebe_tpu.protocol.intents import MessageIntent as MI
+
+        ev = cols["valid"] & (cols["rtype"] == int(RecordType.EVENT))
+        vt, intent, key = cols["vtype"], cols["intent"], cols["key"]
+        done = (
+            ev & (vt == int(ValueType.WORKFLOW_INSTANCE))
+            & (key == cols["instance_key"]) & (key >= 0)
+            & (
+                (intent == int(WI.ELEMENT_COMPLETED))
+                | (intent == int(WI.ELEMENT_TERMINATED))
+            )
+        )
+        gone = (
+            ev & (vt == int(ValueType.MESSAGE)) & (key >= 0)
+            & (
+                (intent == int(MI.DELETED))
+                | ((intent == int(MI.PUBLISHED)) & (cols["deadline"] <= 0))
+            )
+        )
+        self._ending.extend(key[done].tolist())
+        self._ending.extend(("m", k) for k in key[gone].tolist())
 
     def _stage_from_emission(self, cols, src, rows, js) -> None:
         """Stage ``rows`` by COPYING rows ``js`` of the backing emission
@@ -3079,21 +3162,47 @@ class TpuPartitionEngine:
     def _stage_payload(self, cols, i, payload) -> None:
         if not payload:
             return
+        rejection = int(cols["rtype"][i]) == int(RecordType.COMMAND_REJECTION)
+        self._staging_row = (cols, i)
         try:
+            # a rejection is terminal for the kernel: a value with no
+            # column form is not held for it (it stages with an empty
+            # payload: PayloadError below)
             vt, num, sid = rb.payload_to_columns(
-                payload, self._var_column, self.interns, self.num_vars
+                payload, self._var_column, self.interns, self.num_vars,
+                None if rejection else self._hold_staged,
             )
         except rb.PayloadError:
-            if int(cols["rtype"][i]) == int(RecordType.COMMAND_REJECTION):
-                # a rejection record echoes the offending command's payload
-                # (e.g. a non-f32-exact number) — it is terminal for the
-                # kernel, so it stages with an empty payload instead of
-                # re-tripping the payload contract it reported
+            if rejection:
                 return
             raise
         cols["v_vt"][i] = vt
         cols["v_num"][i] = num
         cols["v_str"][i] = sid
+
+    def _hold_staged(self, value) -> int:
+        """A value with no column form of the row being staged
+        (``_staging_row``), held for the segment (``_staged_held``). An
+        event's values belong to its instance (a message's own, to the
+        message) from the start; a command's wait for the rows its step
+        emits (``_own_held``)."""
+        handle = self._values.handle_of(value)
+        if handle is not None:
+            # a record the engine built from its handles: the value is
+            # held, and owned, already
+            return handle
+        cols, i = self._staging_row
+        owner = None
+        if int(cols["rtype"][i]) == int(RecordType.EVENT):
+            if int(cols["vtype"][i]) == int(ValueType.MESSAGE):
+                if cols["key"][i] >= 0:
+                    owner = ("m", int(cols["key"][i]))
+            elif cols["instance_key"][i] >= 0:
+                owner = int(cols["instance_key"][i])
+        with self._values.timed():
+            handle = self._values.hold(value)
+        self._staged_held.append((handle, owner))
+        return handle
 
     def _resolve_workflow(self, value: WorkflowInstanceRecord):
         if value.workflow_key > 0:
@@ -3258,9 +3367,18 @@ class TpuPartitionEngine:
                 if seg.blind:
                     self._blind_fb_inflight += 1
         seg.route_owner = lane_owner
-        batch = self._stage(
-            [records[i] for i in live], lane_owner=lane_owner
-        )
+        self._staged_held = seg.held
+        try:
+            batch = self._stage(
+                [records[i] for i in live], lane_owner=lane_owner
+            )
+        finally:
+            self._staged_held = []
+        if len(self._values):
+            rt_command = int(RecordType.COMMAND)
+            seg.src_commands = np.fromiter(
+                (metas[i][1] == rt_command for i in live), bool, len(live)
+            )
         now = np.int64(self.clock())
         # re-derive the fallback maps before the key window can wrap past
         # the direct-mapped index capacity (see rebuild_lookup_state).
@@ -3340,8 +3458,58 @@ class TpuPartitionEngine:
             }
         self._emit_records(
             o, [seg.positions[i] for i in seg.live], seg.results, seg.live,
-            seg.suppress,
+            seg.suppress, seg.src_commands,
         )
+        if seg.held:
+            self._own_held(seg.held)
+
+    def _own_held(self, held: List[tuple]) -> None:
+        """The values a decoded segment's staging held anew: an event's go
+        to its owner; a command's that no emitted row took (the command
+        was rejected) are freed."""
+        values = self._values
+        with values.timed():
+            for handle, owner in held:
+                if owner is not None:
+                    values.own(owner, handle)
+            values.release_unowned([handle for handle, _owner in held])
+
+    def _held_values(self, o, count: int, src_commands):
+        """handle -> value for the held values one decoded emission
+        carries (None where it carries none), each handle a COMMAND brought
+        added to the set of the row's owner: its instance, or the message
+        of a message's own row. (The rows an event emits carry its own
+        instance's values, owned already: an event's fresh values are owned
+        where it is staged.) A rejection's rows own nothing (its values go
+        with the command), nor do the subscription commands sent to an
+        instance's partition (that partition holds them anew). The dict is
+        what the batch's rows read, however late they are built."""
+        if src_commands is None:
+            return None
+        held = o["v_vt"][:count] == VT_HELD
+        if not held.any():
+            return None
+        values = self._values
+        with values.timed():
+            rows, cols = np.nonzero(held)
+            handles = o["v_str"][:count][rows, cols]
+            resolved = values.resolve(np.unique(handles).tolist())
+            vt = o["vtype"][:count][rows]
+            src = o["src"][:count][rows]
+            inside = (src >= 0) & (src < len(src_commands))
+            keep = (
+                (~inside | src_commands[np.where(inside, src, 0)])
+                & (o["rtype"][:count][rows] != int(RecordType.COMMAND_REJECTION))
+                & (vt != int(ValueType.WORKFLOW_INSTANCE_SUBSCRIPTION))
+            )
+            msg = keep & (vt == int(ValueType.MESSAGE))
+            owner = np.where(msg, o["key"][:count][rows], o["instance_key"][:count][rows])
+            keep &= owner >= 0
+            for handle, key, is_msg in set(zip(
+                handles[keep].tolist(), owner[keep].tolist(), msg[keep].tolist()
+            )):
+                values.own(("m", key) if is_msg else key, handle)
+        return resolved
 
     def _next_wf_key_host(self) -> int:
         """Allocate a workflow key host-side, keeping the device counter in
@@ -3362,6 +3530,7 @@ class TpuPartitionEngine:
         results: List[ProcessingResult],
         live_rows: List[int],
         suppress_incident_create: "set | None" = None,
+        src_commands: Optional[np.ndarray] = None,
     ) -> None:
         """Decode one emission batch (``out``: the host column views of the
         caller's single bulk ``device_get``) into Record objects. Columnar:
@@ -3379,6 +3548,7 @@ class TpuPartitionEngine:
         count = int(o["valid"].sum())
         if not count:
             return
+        values = self._held_values(o, count, src_commands)
         # per-row int(np_scalar) dominated readback CPU at serving wave
         # sizes; one C-level tolist per column replaces them all
         cols = {
@@ -3419,10 +3589,10 @@ class TpuPartitionEngine:
                 "raft_term": [0] * count,
             },
             materializer=lambda r: self._materialize(
-                o, cols, r, names, sources, meta
+                o, cols, r, names, sources, meta, values
             ),
             value_builder=lambda r: self._materialize_value(
-                o, cols, r, names, meta
+                o, cols, r, names, meta, values
             ),
         )
         emission.device_source = (o, cols, self._meta_epoch)
@@ -3517,15 +3687,16 @@ class TpuPartitionEngine:
             if push:
                 res.pushes.append((cols["req_stream"][r], record))
 
-    def _materialize(self, o, cols, r, names, sources, meta) -> Record:
+    def _materialize(self, o, cols, r, names, sources, meta, values) -> Record:
         """One emission row → Record. ``cols`` holds the scalar columns as
         Python lists (see _emit_records); ``o`` the 2D payload matrices;
         ``meta`` is the graph meta bound AT EMIT (slots in these columns
-        index it, not whatever self.meta later becomes)."""
+        index it, not whatever self.meta later becomes), ``values`` the
+        held values the batch carries (``_held_values``)."""
         vt = cols["vtype"][r]
         rt = cols["rtype"][r]
         rej = cols["rej"][r]
-        value = self._materialize_value(o, cols, r, names, meta)
+        value = self._materialize_value(o, cols, r, names, meta, values)
 
         md = RecordMetadata(
             record_type=RecordType(rt),
@@ -3551,7 +3722,7 @@ class TpuPartitionEngine:
         record.source_record_position = sources[r]
         return record
 
-    def _materialize_value(self, o, cols, r, names, meta):
+    def _materialize_value(self, o, cols, r, names, meta, values):
         """One emission row → its typed ``RecordValue`` only (no
         Record/metadata wrapper) — the append-edge encode path for lazy
         rows builds exactly this and nothing more."""
@@ -3560,7 +3731,8 @@ class TpuPartitionEngine:
         wf_slot = cols["wf"][r]
         elem = cols["elem"][r]
         payload = rb.columns_to_payload(
-            o["v_vt"][r], o["v_num"][r], o["v_str"][r], names, self.interns
+            o["v_vt"][r], o["v_num"][r], o["v_str"][r], names, self.interns,
+            values,
         )
         workflow = (
             meta.workflows[wf_slot]

@@ -292,6 +292,45 @@ def check_device_compatible(workflow: ExecutableWorkflow) -> Optional[str]:
     return None
 
 
+def read_variables(workflow: ExecutableWorkflow) -> frozenset:
+    """The top-level payload variables a workflow READS: those its
+    conditions compare, its correlation keys name, its mappings take from
+    (a root ``$`` mapping moves the document whole and names none) and its
+    multi-instance collections walk. A value with no column form under one
+    of them sends the instance to the host engine (``TpuPartitionEngine``);
+    under any other variable the device carries it as a handle."""
+    from zeebe_tpu.models.el.ast import Comparison, JsonPathLiteral
+
+    names = set()
+
+    def add(path: str) -> None:
+        if path and path != "$":
+            steps = compile_json_path(path)
+            if steps and isinstance(steps[0], str):
+                names.add(steps[0])
+
+    def walk(condition) -> None:
+        if isinstance(condition, Comparison):
+            for operand in (condition.left, condition.right):
+                if isinstance(operand, JsonPathLiteral):
+                    add(operand.path)
+        elif condition is not None:
+            walk(getattr(condition, "left", None))
+            walk(getattr(condition, "right", None))
+
+    for el in workflow.elements:
+        walk(el.condition)
+        if el.message_name:
+            add(el.correlation_key_path)
+        for boundary in el.boundary_events:
+            if boundary.message_name:
+                add(boundary.correlation_key_path)
+        for m in list(el.input_mappings) + list(el.output_mappings):
+            add(m.source)
+        add(el.mi_input_collection)
+    return frozenset(names)
+
+
 def compile_graph(
     workflows: List[ExecutableWorkflow],
     interns: Optional[InternTable] = None,

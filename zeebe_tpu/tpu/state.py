@@ -211,9 +211,10 @@ _STATE_FIELDS = [
 # string ids, [2V,3V) = float32 numbers bitcast to i32. XLA lowers general
 # scatters to SERIAL per-index loops on TPU, so a payload write must be one
 # scatter, not three — and float32 (not 64) halves the emulated-64-bit op
-# cost throughout the kernel. Values that are not exactly representable in
-# f32 never reach the device: ``batch.payload_to_columns`` rejects them
-# into the host-oracle fallback path.
+# cost throughout the kernel. A number that f32 does not hold exactly never
+# reaches the device as a number: ``batch.payload_to_columns`` holds it on
+# the host (type ``VT_HELD``, a handle in the string-id column), like a map
+# or a list.
 
 
 def pack_payload(vt, sid, num):

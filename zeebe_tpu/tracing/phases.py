@@ -67,6 +67,12 @@ TRACKS = {
 # job's row read back and made a record) out of whichever cycle asks for
 # it (a tick's ``backlog`` and deadline sweep; a subscription's backlog runs
 # outside every cycle and flushes a clock of its own).
+# The device engine's value store (tpu/values.py) times its own work
+# (holding, resolving, owning and freeing payload values) on a clock of its
+# own, counted as ``payload_value_seconds`` beside ``payload_values_held``,
+# ``payload_value_bytes``, ``payload_values_freed`` and
+# ``payload_host_routed``: it cuts no phase, so ``stage``, ``decode`` and
+# ``push`` keep the time it runs in.
 # what PendingWave.host_seconds / device_seconds sum: host work of the wave
 # path, and host time waiting for the device (never a device time); the job
 # path's phases are in neither
