@@ -107,6 +107,40 @@ class ClusterUnderTest:
             for nid, b in self.brokers.items()
         }
 
+    def await_members(self, timeout=60):
+        """Every broker knows every other member's client address (the
+        gossiped node-info a topic's placement chooses from)."""
+
+        def known():
+            return all(
+                b.topology.members.get(nid, ["", 0])[0]
+                for b in self.brokers.values()
+                for nid in self.brokers
+                if nid != b.node_id
+            )
+
+        assert wait_until(known, timeout), {
+            nid: dict(b.topology.members) for nid, b in self.brokers.items()
+        }
+
+    def await_agreed_leader(self, pid, timeout=60):
+        """Every replica of ``pid`` follows one leader in one term: the
+        elections right after bootstrap are over (a command sent into one
+        can be appended by a leader that is already deposed)."""
+
+        def views():
+            return {
+                nid: (b.partitions[pid].raft.leader_id, b.partitions[pid].raft.term)
+                for nid, b in self.brokers.items()
+                if pid in b.partitions
+            }
+
+        def agreed():
+            seen = set(views().values())
+            return len(seen) == 1 and None not in next(iter(seen))
+
+        assert wait_until(agreed, timeout), views()
+
     def leader_of(self, pid):
         for broker in self.brokers.values():
             server = broker.partitions.get(pid)
@@ -114,10 +148,11 @@ class ClusterUnderTest:
                 return broker
         return None
 
-    def client(self):
+    def client(self, request_timeout_ms=10_000):
         return ClusterClient(
             [b.client_address for b in self.brokers.values()],
             num_partitions=self.partitions,
+            request_timeout_ms=request_timeout_ms,
         )
 
     def close(self):
@@ -335,7 +370,17 @@ class TestTopicOrchestration:
         partition assigns partition ids, partitions come up on selected
         members, and the client is answered once every partition is led."""
         cluster3.await_leaders()
-        client = cluster3.client()
+        # placement picks among the members the system leader has heard
+        # of: with one not yet gossiped, a partition gets fewer replicas;
+        # a create caught in a re-election is orchestrated twice (more
+        # replicas) or, retried on the new leader, meets its own topic
+        cluster3.await_members()
+        cluster3.await_agreed_leader(0)
+        # the create is answered only once both partitions are created on
+        # two members, bootstrapped and led: the orchestration's own limit
+        # (60 s), not the client's default 10 s, which a loaded machine
+        # overruns
+        client = cluster3.client(request_timeout_ms=60_000)
         try:
             created = client.create_topic("orders", partitions=2, replication_factor=2)
             pids = created.value.partition_ids

@@ -12,6 +12,7 @@ import pytest
 from zeebe_tpu.protocol import codec, msgpack
 from zeebe_tpu.protocol.columnar import (
     ColumnarBatch,
+    FrameBlock,
     RecordsView,
     rows_materialized_total,
 )
@@ -273,3 +274,179 @@ class TestColumnarLogAppend:
         assert len(log.committed_view(2, 3)) == 3
         assert log.committed_view(2, 3).positions() == [2, 3, 4]
         storage.close()
+
+
+def _lazy_batch(records):
+    """A columnar batch over copies of ``records`` that builds no row to
+    be appended (every frame column given, values from a builder)."""
+    from zeebe_tpu.protocol.columnar import FRAME_COLUMNS
+
+    meta = {"record_type", "value_type", "intent", "rejection_type"}
+    cols = {}
+    for name in FRAME_COLUMNS:
+        if name == "position":
+            continue
+        if name in meta:
+            cols[name] = [int(getattr(r.metadata, name)) for r in records]
+        elif hasattr(records[0].metadata, name):
+            cols[name] = [getattr(r.metadata, name) for r in records]
+        else:
+            cols[name] = [getattr(r, name) for r in records]
+    return ColumnarBatch(
+        len(records), cols,
+        materializer=lambda i: records[i].copy(),
+        value_builder=lambda i: records[i].value,
+    )
+
+
+def _appended_twice(tmp_path, segment_size=1 << 20):
+    """Two logs holding the same lazy appends (a columnar batch, then a
+    mixed one: real rows between refs into a second batch); the second
+    log's entries are frozen into frame refs, the first's stay lazy."""
+    from zeebe_tpu.log import LogStream, SegmentedLogStorage
+    from zeebe_tpu.protocol.columnar import MixedBatch
+
+    logs = []
+    for name in ("lazy", "frozen"):
+        records = _assorted_records()
+        for r in records:
+            r.position = -1
+        log = LogStream(
+            SegmentedLogStorage(str(tmp_path / name), segment_size=segment_size),
+            clock=lambda: 7,
+        )
+        log.append(_lazy_batch(records))
+        more = _assorted_records()
+        backing = _lazy_batch(more)
+        log.append(MixedBatch([more[0], (backing, 1), more[2], (backing, 3), (backing, 4)]))
+        logs.append(log)
+    return logs
+
+
+class TestFrameRefs:
+    def test_frozen_entries_read_as_the_rows_the_batch_builds(self, tmp_path):
+        from zeebe_tpu.protocol.columnar import FRAME_COLUMNS
+
+        lazy, frozen = _appended_twice(tmp_path)
+        n = frozen.next_position
+        lazy_refs = sum(1 for e in frozen._records if type(e) is tuple)
+        assert frozen.freeze_below(n) == lazy_refs > 0
+        assert all(
+            type(e) is not tuple or type(e[0]) is FrameBlock
+            for e in frozen._records
+        )
+        assert frozen.freeze_below(n) == 0  # once
+        # columns and value bytes straight from the frames: nothing built
+        before = rows_materialized_total()
+        a, b = lazy.committed_view(0), frozen.committed_view(0)
+        for name in FRAME_COLUMNS:
+            assert b.col(name) == a.col(name), name
+        for i in range(n):
+            assert b.value_bytes(i) == a.value_bytes(i)
+            assert frozen.term_at(i) == lazy.term_at(i)
+        assert rows_materialized_total() == before
+        # rows decode once from the frame, equal to the batch's own
+        for p in range(n):
+            row = frozen.record_at(p)
+            assert row == lazy.record_at(p), p
+            assert frozen.record_at(p) is row
+        assert list(frozen.committed_view(0)) == list(lazy.committed_view(0))
+
+    def test_committed_frames_are_the_frames_on_disk(self, tmp_path):
+        import struct
+
+        from zeebe_tpu.log import LogStream, SegmentedLogStorage
+        from zeebe_tpu.log.logstream import _iter_disk_frames
+
+        lazy, frozen = _appended_twice(tmp_path)
+        frozen.freeze_below(3)
+        for log in (lazy, frozen):
+            disk = {}
+            for record, address in _iter_disk_frames(log, 0):
+                (length,) = struct.unpack("<i", log.storage.read(address, 4))
+                disk[record.position] = log.storage.read(address, length)
+            got = log.committed_frames(0)
+            assert [p for p, _vt, _f in got] == sorted(disk)
+            for p, vt, frame in got:
+                assert frame == disk[p]
+                assert vt == frame[69]
+            assert log.committed_frames(2, limit=3) == got[2:5]
+        # reopened, the log holds no block: the recovered Record instead
+        frozen.storage.close()
+        reopened = LogStream(
+            SegmentedLogStorage(str(tmp_path / "frozen")), clock=lambda: 7
+        )
+        for p, vt, record in reopened.committed_frames(0):
+            assert codec.encode_record(record) == disk[p]
+            assert vt == int(record.metadata.value_type)
+
+    def test_truncation_and_compaction_keep_frames_and_refs(self, tmp_path):
+        # a segment an append: compaction can drop whole appends
+        lazy, frozen = _appended_twice(tmp_path, segment_size=512)
+        n = frozen.next_position
+        frozen.freeze_below(n)
+        kept = {p: frozen.record_at(p) for p in range(4)}
+        frozen.truncate(4)  # inside the first append's block
+        records = _assorted_records()[:3]
+        for r in records:
+            r.position = -1
+        frozen.append(_lazy_batch(records))
+        got = frozen.committed_frames(0)
+        assert [p for p, _vt, _f in got] == list(range(7))
+        assert [codec.decode_record(f)[0] for _p, _vt, f in got[:4]] == [
+            kept[p] for p in range(4)
+        ]
+        assert codec.decode_record(got[4][2])[0] == frozen.record_at(4)
+        frozen.append(_lazy_batch(_assorted_records()[:2]))
+        got = frozen.committed_frames(0)
+        base = frozen.compact(7)
+        assert base == 7
+        assert frozen.committed_frames(0) == got[7:]
+        assert [b.first for b in frozen._blocks] == [7]
+
+    def test_appends_race_the_pump_reads(self, tmp_path):
+        """One thread appends (the raft actor), another reads frames and
+        freezes behind the commit (the broker actor's pump)."""
+        import struct
+        import sys
+        import threading
+
+        from zeebe_tpu.log import LogStream, SegmentedLogStorage
+
+        log = LogStream(SegmentedLogStorage(str(tmp_path)), clock=lambda: 7)
+        rounds = 200
+        errors = []
+
+        def append():
+            for _ in range(rounds):
+                records = _assorted_records()
+                for r in records:
+                    r.position = -1
+                log.append(_lazy_batch(records))
+
+        def pump():
+            cursor = 0
+            while cursor < rounds * 7:
+                for position, _vt, frame in log.committed_frames(cursor):
+                    if struct.unpack_from("<q", frame, 8)[0] != position:
+                        errors.append(position)
+                    cursor = position + 1
+                log.freeze_below(cursor)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=f) for f in (append, pump)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(interval)
+        assert errors == []
+        assert all(
+            type(e) is tuple and isinstance(e[0], FrameBlock) or type(e) is not tuple
+            for e in log._records
+        )
+        assert sum(1 for e in log._records if type(e) is tuple) == rounds * 7

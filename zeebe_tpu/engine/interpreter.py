@@ -977,7 +977,9 @@ class PartitionEngine:
         from zeebe_tpu.protocol.intents import DeploymentIntent
         from zeebe_tpu.protocol.records import DeployedWorkflowMeta
 
-        deployment = record.value
+        # the CREATED event carries the deployed workflows: fill a copy, so
+        # the command stays the record its log frame holds
+        deployment = record.value.copy()
         deployed: List[ExecutableWorkflow] = []
         try:
             for resource in deployment.resources:

@@ -16,14 +16,18 @@ import json
 import os
 import threading
 import time
+from bisect import bisect_right
+from operator import attrgetter
 from typing import Callable, Iterator, List, Optional
 
 from zeebe_tpu.log.storage import SegmentedLogStorage
 from zeebe_tpu.protocol import codec
-from zeebe_tpu.protocol.columnar import ColumnarBatch, RecordsView
+from zeebe_tpu.protocol.columnar import ColumnarBatch, FrameBlock, RecordsView
 from zeebe_tpu.protocol.records import Record
 
 BLOCK_INDEX_DENSITY = 256  # record a (position → address) entry every N records
+
+_first = attrgetter("first")  # a FrameBlock's bisect key
 
 
 class LogStream:
@@ -72,6 +76,13 @@ class LogStream:
         # compound mutation atomic (list.append alone is atomic under the
         # GIL, so the append hot path stays lock-free)
         self._view_lock = threading.Lock()
+        # the frames each append wrote, one FrameBlock an append in
+        # position order, for the live tail (a record recovered from disk
+        # or appended by a follower has none): the topic push reads its
+        # frames here, and a lazy (batch, row) entry below the freeze
+        # bound becomes a (block, i) frame ref (freeze_below)
+        self._blocks: List[FrameBlock] = []
+        self._frozen_to = 0  # no lazy batch ref is left below this
         self._commit_listeners: List[Callable[[int], None]] = []
         # floor providers (exporter directors): each returns the first
         # position it still needs; compact() never passes them (reference:
@@ -186,11 +197,17 @@ class LogStream:
             idx = position - self._base_position
             if idx < 0 or idx >= len(self._records):
                 return None
-            entry = self._records[idx]
-            if type(entry) is tuple:  # lazy (batch, row) columnar ref
-                entry = entry[0].row(entry[1])
-                self._records[idx] = entry
-            return entry
+            return self._entry_record(idx)
+
+    def _entry_record(self, idx: int) -> Record:
+        """The tail entry at ``idx`` as a ``Record``: a lazy or frame ref
+        materializes once and takes the entry's place (caller holds the
+        view lock)."""
+        entry = self._records[idx]
+        if type(entry) is tuple:
+            entry = entry[0].row(entry[1])
+            self._records[idx] = entry
+        return entry
 
     def slice_records(
         self,
@@ -213,13 +230,7 @@ class LogStream:
             i1 = hi - self._base_position + 1
             if limit is not None:
                 i1 = min(i1, i0 + limit)
-            out = self._records[i0:i1]
-            for k, entry in enumerate(out):
-                if type(entry) is tuple:
-                    entry = entry[0].row(entry[1])
-                    self._records[i0 + k] = entry
-                    out[k] = entry
-            return out
+            return [self._entry_record(i) for i in range(i0, i1)]
 
     def committed_view(
         self, start: int, limit: Optional[int] = None
@@ -239,15 +250,92 @@ class LogStream:
                 i1 = min(i1, i0 + limit)
             return RecordsView(self._records[i0:i1])
 
+    def committed_frames(self, start: int, limit: Optional[int] = None) -> list:
+        """``(position, value_type, frame)`` for the committed records from
+        ``start``, at most ``limit``, under ONE lock acquisition — the
+        topic push's read. ``frame`` is the slice of the bytes the append
+        wrote (no ``Record`` is built, nothing is encoded). A record with
+        no block (recovered from disk, or appended by a follower) comes
+        as its ``Record`` in the frame's place, for the caller to encode."""
+        out: list = []
+        with self._view_lock:
+            lo = max(start, self._base_position)
+            hi = self._commit_position + 1  # exclusive
+            if limit is not None:
+                hi = min(hi, lo + limit)
+            blocks = self._blocks
+            b = bisect_right(blocks, lo, key=_first) - 1
+            p = lo
+            while p < hi:
+                block = blocks[b] if b >= 0 else None
+                if block is not None and p < block.first + block.n:
+                    first = block.first
+                    frame, value_type = block.frame, block.value_type
+                    for k in range(p - first, min(hi, first + block.n) - first):
+                        out.append((first + k, value_type(k), frame(k)))
+                    p = first + block.n
+                    continue
+                if b + 1 < len(blocks) and blocks[b + 1].first <= p:
+                    b += 1
+                    continue
+                # no block holds p: the tail entry itself
+                entry = self._entry_record(p - self._base_position)
+                out.append((p, int(entry.metadata.value_type), entry))
+                p += 1
+        return out
+
+    def freeze_below(self, bound: int) -> int:
+        """Let go of the wave readbacks behind the tail entries below
+        ``bound``: each lazy ``(batch, row)`` ref becomes a frame ref
+        ``(block, i)`` into its append's frames (a row the batch already
+        built takes its place instead, keeping one object per position).
+        The caller vouches that nothing re-stages these positions from
+        their batches (its feed cursor and every pusher are past them).
+        Returns the frame refs made."""
+        made = 0
+        with self._view_lock:
+            base = self._base_position
+            p = max(self._frozen_to, base)
+            hi = min(bound, self._commit_position + 1)
+            if p >= hi:
+                return 0
+            records, blocks = self._records, self._blocks
+            b = bisect_right(blocks, p, key=_first) - 1
+            while p < hi:
+                if b < 0 or p >= blocks[b].first + blocks[b].n:
+                    b += 1
+                    if b >= len(blocks):
+                        break
+                    p = max(p, blocks[b].first)
+                    continue
+                block = blocks[b]
+                first = block.first
+                for q in range(p, min(hi, first + block.n)):
+                    entry = records[q - base]
+                    if type(entry) is tuple and type(entry[0]) is not FrameBlock:
+                        row = entry[0].cached_row(entry[1])
+                        if row is None:
+                            row = (block, q - first)
+                            made += 1
+                        records[q - base] = row
+                p = first + block.n
+            self._frozen_to = hi
+        return made
+
     def term_at(self, position: int) -> int:
         """Raft term at ``position``. For the position just below the
         PERSISTED compaction base the term is retained across compaction
         (replication prev-entry checks); live records win when still
         present — this makes the answer correct on both sides of the
-        crash window between writing base.meta and deleting segments."""
-        record = self.record_at(position)
-        if record is not None:
-            return record.raft_term
+        crash window between writing base.meta and deleting segments.
+        A lazy entry answers from its term column (nothing is built)."""
+        with self._view_lock:
+            idx = position - self._base_position
+            if 0 <= idx < len(self._records):
+                entry = self._records[idx]
+                if type(entry) is tuple:
+                    return entry[0].col("raft_term")[entry[1]]
+                return entry.raft_term
         if position == self._base_meta_position - 1:
             return self._base_prev_term
         if position == self._base_position - 1:
@@ -297,6 +385,9 @@ class LogStream:
         with self._view_lock:
             del self._records[: new_base - self._base_position]
             self._base_position = new_base
+            self._blocks = [
+                b for b in self._blocks if b.first + b.n > new_base
+            ]
         self._block_index = [e for e in self._block_index if e[0] >= new_base]
         # persist the base metadata BEFORE deleting segments: the prev-term
         # of base-1 must survive a crash anywhere in this sequence (leaders
@@ -323,6 +414,8 @@ class LogStream:
         with self._view_lock:
             self._records.clear()
             self._base_position = position
+            self._blocks = []
+            self._frozen_to = position
         self._block_index = []
         self._segment_first_pos = {}
         self._base_prev_term = term
@@ -343,25 +436,32 @@ class LogStream:
         codec pass into a single buffer, appends as one storage block, and
         the block index derives from the pass's frame offsets (no
         re-walk). A columnar batch's rows stay LAZY: the in-memory tail
-        holds ``(batch, row)`` refs that materialize on first read."""
+        holds ``(batch, row)`` refs that materialize on first read. The
+        buffer is kept as the append's :class:`FrameBlock`."""
         ts = self.clock()
         first_position = self._next_position
         columnar = isinstance(records, ColumnarBatch)
+        n = len(records)
         if columnar:
-            n = len(records)
             records.assign_positions(first_position, ts)
             buf, offsets = codec.encode_columnar(records)
-            self._records.extend(records.log_entries())
-            # response/push-relevant rows that are already materialized
-            # get their just-encoded frame cached, like the list path
-            records.cache_frames(buf, offsets)
         else:
-            n = len(records)
             for i, record in enumerate(records):
                 record.position = first_position + i
                 if record.timestamp < 0:
                     record.timestamp = ts
             buf, offsets = codec.encode_records(records)
+        buf = bytes(buf)
+        if n:
+            # before the entries: a reader that sees a position finds its
+            # block (list.append is atomic; readers take the view lock)
+            self._blocks.append(FrameBlock(buf, offsets, first_position))
+        if columnar:
+            self._records.extend(records.log_entries())
+            # response/push-relevant rows that are already materialized
+            # get their just-encoded frame cached, like the list path
+            records.cache_frames(buf, offsets)
+        else:
             self._records.extend(records)
         self._next_position = first_position + n
         address = self.storage.append(buf)
@@ -388,9 +488,7 @@ class LogStream:
                     md = record.metadata
                     if md.request_id >= 0 or md.request_stream_id >= 0:
                         end = offsets[i + 1] if i + 1 < n else total
-                        record._frame = (
-                            record.position, bytes(buf[offsets[i]:end]),
-                        )
+                        record._frame = (record.position, buf[offsets[i]:end])
         if commit:
             self.set_commit_position(self._next_position - 1)
         return self._next_position - 1
@@ -474,6 +572,16 @@ class LogStream:
             }
             with self._view_lock:
                 del self._records[position - self._base_position :]
+                blocks = [b for b in self._blocks if b.first < position]
+                last = blocks[-1] if blocks else None
+                if last is not None and last.first + last.n > position:
+                    # the block's buffer stays shared with its frame refs
+                    blocks[-1] = FrameBlock(
+                        last.buf, last.offsets[: position - last.first],
+                        last.first,
+                    )
+                self._blocks = blocks
+                self._frozen_to = min(self._frozen_to, position)
 
 
 def _iter_disk_frames(log: LogStream, target: int) -> Iterator[tuple]:

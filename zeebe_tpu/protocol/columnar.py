@@ -32,8 +32,10 @@ engine-built ``Record`` objects already, never lazy views).
 
 from __future__ import annotations
 
+import struct
 from typing import Callable, Dict, List, Optional, Sequence
 
+from zeebe_tpu.protocol import codec
 from zeebe_tpu.protocol.records import Record
 
 # canonical column names = the frame scalar fields (protocol/codec.py
@@ -97,7 +99,7 @@ class ColumnarBatch:
 
     __slots__ = ("n", "_cols", "_rows", "_materializer", "_values",
                  "_value_bytes", "_stamped", "_value_builder",
-                 "device_source")
+                 "device_source", "__weakref__")
 
     def __init__(
         self,
@@ -235,6 +237,10 @@ class ColumnarBatch:
     def rows(self) -> List[Record]:
         return [self.row(i) for i in range(self.n)]
 
+    def cached_row(self, i: int) -> Optional[Record]:
+        """Row ``i`` if it is built already, else None (builds nothing)."""
+        return self._rows[i]
+
     def value_bytes(self, i: int) -> bytes:
         """Row ``i``'s encoded value document (msgpack) without requiring
         a materialized ``Record`` when the value (or its bytes) is known
@@ -298,7 +304,7 @@ class ColumnarBatch:
             md = row.metadata
             if md.request_id >= 0 or md.request_stream_id >= 0:
                 end = offsets[i + 1] if i + 1 < n else total
-                row._frame = (row.position, bytes(buf[offsets[i]:end]))
+                row._frame = (row.position, buf[offsets[i]:end])
 
     def set_raft_term(self, term: int) -> None:
         """Stamp the raft term on every row (the group-commit drain does
@@ -329,10 +335,11 @@ class ColumnarBatch:
 class RecordsView:
     """A read-only window over log-tail entries with column access.
 
-    Entries are ``Record`` objects or ``(ColumnarBatch, idx)`` lazy refs;
-    column accessors never materialize a lazy ref (they read the backing
-    batch's columns), iteration/indexing does (counted, cached in the
-    backing batch so the log tail and this view share row identity)."""
+    Entries are ``Record`` objects, ``(ColumnarBatch, idx)`` lazy refs or
+    ``(FrameBlock, idx)`` frame refs; column accessors never materialize a
+    ref (they read the backing batch's columns or frame headers),
+    iteration/indexing does (counted, cached in the backing batch or block
+    so the log tail and this view share row identity)."""
 
     __slots__ = ("_entries", "_cols")
 
@@ -424,6 +431,108 @@ class RecordsView:
 
     def rows(self) -> List[Record]:
         return [self._materialize(e) for e in self._entries]
+
+
+# (struct format, byte offset) of each fixed frame-header field
+# (protocol/codec.py's layout)
+_HEADER_FIELDS = {
+    "source_record_position": ("<q", 16),
+    "key": ("<q", 24),
+    "timestamp": ("<q", 32),
+    "producer_id": ("<i", 40),
+    "raft_term": ("<i", 44),
+    "request_id": ("<q", 48),
+    "request_stream_id": ("<i", 56),
+    "incident_key": ("<q", 60),
+    "record_type": ("<B", 68),
+    "value_type": ("<B", 69),
+    "intent": ("<B", 70),
+    "rejection_type": ("<B", 71),
+}
+_VALUE_TYPE_AT = 69
+_REASON_AT = codec.HEADER_SIZE  # u32 length, the utf-8 reason, then the value
+_U32 = struct.Struct("<I")
+
+
+class FrameBlock:
+    """One log append's encoded frames: the immutable buffer the append
+    wrote to storage, its frame offsets and its first position.
+
+    A tail entry ``(block, i)`` is a FRAME REF: it answers the lazy-ref
+    protocol (``row``, ``col``, ``value_bytes``, ``device_ref``) from the
+    bytes alone, so it pins nothing of the wave that produced it. ``row``
+    decodes once, caches and counts into ``serving_rows_materialized_total``
+    like a lazy batch row; ``col`` reads the frame headers."""
+
+    __slots__ = ("first", "n", "buf", "offsets", "_rows")
+
+    def __init__(self, buf: bytes, offsets: List[int], first: int):
+        self.buf = buf
+        self.offsets = offsets
+        self.first = first
+        self.n = len(offsets)
+        self._rows: Optional[Dict[int, Record]] = None
+
+    def frame(self, i: int) -> bytes:
+        """Frame ``i`` exactly as storage holds it."""
+        end = self.offsets[i + 1] if i + 1 < self.n else len(self.buf)
+        return self.buf[self.offsets[i]:end]
+
+    def value_type(self, i: int) -> int:
+        """Frame ``i``'s value-type byte (255 = ``NULL_VAL``)."""
+        return self.buf[self.offsets[i] + _VALUE_TYPE_AT]
+
+    def row(self, i: int) -> Record:
+        rows = self._rows
+        if rows is None:
+            rows = self._rows = {}
+        record = rows.get(i)
+        if record is None:
+            record, _ = codec.decode_record(self.buf, self.offsets[i])
+            rows[i] = record
+            _count_materialized()
+        return record
+
+    def col(self, name: str) -> "_FrameColumn":
+        """Column ``name`` as an indexable view over the frame headers."""
+        return _FrameColumn(self, name)
+
+    def field(self, i: int, name: str):
+        """Frame ``i``'s header field ``name`` (a ``FRAME_COLUMNS`` name)."""
+        if name == "position":
+            return self.first + i
+        off = self.offsets[i]
+        if name == "rejection_reason":
+            (length,) = _U32.unpack_from(self.buf, off + _REASON_AT)
+            at = off + _REASON_AT + 4
+            return self.buf[at:at + length].decode("utf-8")
+        fmt, at = _HEADER_FIELDS[name]
+        return struct.unpack_from(fmt, self.buf, off + at)[0]
+
+    def value_bytes(self, i: int) -> bytes:
+        buf = self.buf
+        at = self.offsets[i] + _REASON_AT
+        (reason_len,) = _U32.unpack_from(buf, at)
+        at += 4 + reason_len
+        (value_len,) = _U32.unpack_from(buf, at)
+        return buf[at + 4:at + 4 + value_len]
+
+    def device_ref(self, i: int):
+        """Never re-staged from columns: the readback is gone."""
+        return None
+
+
+class _FrameColumn:
+    """``FrameBlock.col``: reads one header field per index, builds no list."""
+
+    __slots__ = ("_block", "_name")
+
+    def __init__(self, block: FrameBlock, name: str):
+        self._block = block
+        self._name = name
+
+    def __getitem__(self, i: int):
+        return self._block.field(i, self._name)
 
 
 class MixedBatch(ColumnarBatch):

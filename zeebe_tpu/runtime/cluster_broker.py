@@ -44,6 +44,9 @@ from zeebe_tpu.log import LogStream, SegmentedLogStorage
 from zeebe_tpu.log import stateser
 from zeebe_tpu.log.snapshot import SnapshotController, SnapshotMetadata, SnapshotStorage
 from zeebe_tpu.protocol import codec, msgpack
+from zeebe_tpu.protocol.columnar import RecordsView
+from zeebe_tpu.protocol.enums import RecordType, ValueType
+from zeebe_tpu.protocol.intents import TopicIntent
 from zeebe_tpu.protocol.records import Record
 from zeebe_tpu.runtime.actors import Actor, ActorFuture, ActorScheduler
 from zeebe_tpu.runtime.clock import SystemClock
@@ -59,6 +62,11 @@ from zeebe_tpu import tracing
 from zeebe_tpu.tracing.recorder import FLIGHT, record_event
 
 logger = logging.getLogger(__name__)
+
+# subscription admin records never reach a topic subscriber
+_UNPUSHED_VALUE_TYPES = frozenset(
+    int(v) for v in (ValueType.SUBSCRIBER, ValueType.SUBSCRIPTION, ValueType.EXPORTER)
+)
 
 
 def observe_append(
@@ -705,54 +713,68 @@ class PartitionServer:
                     )
         self.broker.metrics_events_processed.inc(len(records))
         if self.partition_id == 0:
-            # topic orchestration lives on the system partition only; the
-            # guard also keeps lazy columnar rows on data partitions from
-            # materializing just to be inspected and discarded
-            for record in records:
-                self._maybe_orchestrate_topic(record)
+            # topic orchestration lives on the system partition only
+            self._orchestrate_topics(records)
 
-    def _maybe_orchestrate_topic(self, record) -> None:
-        from zeebe_tpu.protocol.enums import RecordType, ValueType
-        from zeebe_tpu.protocol.intents import TopicIntent
+    def _orchestrate_topics(self, records) -> None:
+        """Start orchestration for each TOPIC CREATING event among
+        ``records``, found over the columns: only such a row is built."""
+        view = records if isinstance(records, RecordsView) else RecordsView(list(records))
+        vts = view.value_types()
+        topic = int(ValueType.TOPIC)
+        if topic not in vts:
+            return
+        rts = view.record_types()
+        its = view.intents()
+        event, creating = int(RecordType.EVENT), int(TopicIntent.CREATING)
+        for i, vt in enumerate(vts):
+            if vt == topic and rts[i] == event and its[i] == creating:
+                self.broker.start_topic_orchestration(view[i])
 
-        if (
-            self.partition_id == 0
-            and record.metadata.value_type == ValueType.TOPIC
-            and record.metadata.record_type == RecordType.EVENT
-            and record.metadata.intent == int(TopicIntent.CREATING)
-        ):
-            self.broker.start_topic_orchestration(record)
-
-    def pump_topic_subscriptions(self) -> None:
+    def pump_topic_subscriptions(self, clock=None) -> None:
         """Deliver committed records to open topic subscriptions with credit
-        flow control (reference TopicSubscriptionPushProcessor:36)."""
-        from zeebe_tpu.protocol.enums import ValueType
-
+        flow control (reference TopicSubscriptionPushProcessor:36). A record
+        leaves as the frame its log append wrote; one the log holds no
+        frame for (recovered, or appended as a follower) is encoded anew.
+        Then the log tail below the feed cursor and every pusher's cursor
+        lets its waves' readbacks go (``LogStream.freeze_below``)."""
+        frames = encoded = 0
         for key, pusher in list(self.topic_pushers.items()):
-            while len(pusher["unacked"]) < pusher["capacity"]:
-                batch = self.log.reader(pusher["cursor"]).read_committed()
+            unacked, capacity, push = (
+                pusher["unacked"], pusher["capacity"], pusher["push"]
+            )
+            while len(unacked) < capacity and key in self.topic_pushers:
+                batch = self.log.committed_frames(
+                    pusher["cursor"], capacity - len(unacked)
+                )
                 if not batch:
                     break
-                advanced = False
-                for record in batch:
-                    if len(pusher["unacked"]) >= pusher["capacity"]:
-                        break
-                    pusher["cursor"] = record.position + 1
-                    advanced = True
-                    if record.metadata.value_type in (
-                        ValueType.SUBSCRIBER, ValueType.SUBSCRIPTION,
-                        ValueType.EXPORTER,
-                    ):
+                for position, value_type, frame in batch:
+                    pusher["cursor"] = position + 1
+                    if value_type in _UNPUSHED_VALUE_TYPES:
                         continue
-                    if not pusher["push"](record):
+                    if type(frame) is bytes:
+                        frames += 1
+                    else:
+                        frame = codec.encode_record(frame)
+                        encoded += 1
+                    if not push(frame):
                         # dead connection: the close listener removes the
                         # pusher; stop delivering now
                         self.topic_pushers.pop(key, None)
-                        advanced = False
                         break
-                    pusher["unacked"].append(record.position)
-                if not advanced:
-                    break
+                    unacked.append(position)
+        bound = self.next_read_position
+        for pusher in self.topic_pushers.values():
+            bound = min(bound, pusher["cursor"])
+        own = clock is None
+        if own:
+            clock = tracing.PhaseClock()
+        clock.count("log_tail_frozen", self.log.freeze_below(bound))
+        clock.count("topic_push_frames", frames)
+        clock.count("topic_push_encoded", encoded)
+        if own:
+            observe_phases(clock)
 
     def _needs_workflow_fetch(self, record) -> bool:
         from zeebe_tpu.protocol.enums import RecordType, ValueType
@@ -1565,7 +1587,7 @@ class ClusterBroker(Actor):
                         # collected (a DEPLOYMENT inside it may have
                         # provided the workflow)
                         server.maybe_start_fetch()
-                        server.pump_topic_subscriptions()
+                        server.pump_topic_subscriptions(clock)
             observe_phases(clock, "drains")
         finally:
             # the flag falls BEFORE the look at what is left: a commit that
@@ -2090,20 +2112,20 @@ class ClusterBroker(Actor):
             ]), "topic-subscriber audit record", partition_id)
             if conn is not None:
                 epoch = int(msg.get("epoch", -1))
+                # a push is this map with the frame last: everything up to
+                # the frame's bin is packed once (an empty bin is c4 00)
+                head = msgpack.pack(
+                    {
+                        "t": "pushed-record",
+                        "partition": partition_id,
+                        "subscriber_key": subscriber_key,
+                        "epoch": epoch,
+                        "frame": b"",
+                    }
+                )[:-2]
 
-                def push(record, _conn=conn, _key=subscriber_key,
-                         _pid=partition_id, _epoch=epoch):
-                    return _conn.push(
-                        msgpack.pack(
-                            {
-                                "t": "pushed-record",
-                                "partition": _pid,
-                                "subscriber_key": _key,
-                                "epoch": _epoch,
-                                "frame": self._record_frame(record),
-                            }
-                        )
-                    )
+                def push(frame, _conn=conn, _head=head):
+                    return _conn.push(_head + msgpack.pack(frame))
 
                 logger.debug(
                     "broker %s: opening topic pusher %d (%r) on partition "

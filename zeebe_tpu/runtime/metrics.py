@@ -500,6 +500,26 @@ def _phase_handles() -> dict:
                 "values, on a clock of its own: inside the phases stage, "
                 "decode and push, which keep the time",
             ),
+            # the topic push (PartitionServer.pump_topic_subscriptions) and
+            # the log tail it reads (LogStream.committed_frames,
+            # freeze_below); hit share = frames / (frames + encoded)
+            topic_push_frames=g.counter(
+                "serving_topic_push_frames_total",
+                "Records pushed to topic subscribers as the frame their log "
+                "append wrote (nothing built or encoded)",
+            ),
+            topic_push_encoded=g.counter(
+                "serving_topic_push_encoded_total",
+                "Records pushed to topic subscribers encoded anew, because "
+                "the log holds no frame block for them (recovered from "
+                "disk, or appended by a follower)",
+            ),
+            log_tail_frozen=g.counter(
+                "log_tail_frozen_total",
+                "Log-tail entries turned from a ref into a wave's readback "
+                "into a frame ref, once the feed and every topic pusher "
+                "were past them",
+            ),
             payload_host_routed=g.counter(
                 "serving_payload_host_routed_total",
                 "Instances sent to the host engine because a variable their "
