@@ -10,9 +10,12 @@ import collections
 from zbench import reference as ref
 from zbench import traffic
 
+# ``correlation``: a message's correlation key (its ``element`` is its name)
 Row = collections.namedtuple(
-    "Row", "position rtype vtype intent key timestamp instance element payload jtype"
+    "Row", "position rtype vtype intent key timestamp instance element payload jtype correlation",
+    defaults=(None,),
 )
+MESSAGE, MESSAGE_PUBLISHED = 11, 1
 
 # value types whose records make up an instance's lifecycle: none of them
 # may be processed by the device engine's embedded host engine
@@ -22,14 +25,16 @@ LIFECYCLE_VALUE_TYPES = (0, 5, 6, 11, 12, 13, 14)
 def normalize(record) -> Row:
     md, v = record.metadata, record.value
     vtype = int(md.value_type)
-    instance = element = payload = jtype = None
+    instance = element = payload = jtype = correlation = None
     if vtype == ref.WORKFLOW_INSTANCE:
         instance, element, payload = v.workflow_instance_key, v.activity_id, dict(v.payload)
     elif vtype == ref.JOB:
         instance, jtype, payload = v.headers.workflow_instance_key, v.type, dict(v.payload)
+    elif vtype == MESSAGE:
+        element, correlation, payload = v.name, v.correlation_key, dict(v.payload)
     return Row(
         record.position, int(md.record_type), vtype, int(md.intent), record.key,
-        record.timestamp, instance, element, payload, jtype,
+        record.timestamp, instance, element, payload, jtype, correlation,
     )
 
 
@@ -50,8 +55,11 @@ def compare(logs: dict, gen: dict, graphs: dict, host_lifecycle: int,
             oracle_live: int, compiled_in_window: int, children_with_jax: int) -> tuple:
     """``logs``: partition -> rows of the committed log re-read from disk.
     ``gen``: the generator's report. Returns (name -> [value, limit], the
-    first mismatching instance with why and its records); the run is correct when no value is above its limit. Every comparison is
-    exact, so every limit is 0."""
+    first mismatching instance with why and its records); the run is
+    correct when no value is above its limit. Every comparison is exact, so
+    every limit is 0. Where the traffic publishes messages (``gen``'s
+    ``messages``), each publish is held to what a create is: answered, and
+    its ``PUBLISHED`` event in the committed log."""
     by_partition = {p: per_instance(rows) for p, rows in logs.items()}
     rows = gen["rows"]
     acked = [r for r in rows if "key" in r]
@@ -95,6 +103,7 @@ def compare(logs: dict, gen: dict, graphs: dict, host_lifecycle: int,
     out = {
         "create_errors": [sum(1 for r in rows if "error" in r), 0],
         "acked_not_in_log": [acked_not_in_log, 0],
+        **publishes(logs, gen, acked),
         "never_completed": [sum(1 for r in acked if "done" not in r), 0],
         "completed_not_once": [completed_not_once, 0],
         "seen_vs_log": [seen_vs_log, 0],
@@ -106,6 +115,27 @@ def compare(logs: dict, gen: dict, graphs: dict, host_lifecycle: int,
         "children_with_jax": [children_with_jax, 0],
     }
     return out, first_mismatch
+
+
+def publishes(logs: dict, gen: dict, acked: list) -> dict:
+    """``publish_errors``: acknowledged creates of a process that waits for
+    a message whose publish was refused, timed out or never went out;
+    ``acked_publish_not_in_log``: acknowledged publishes whose ``PUBLISHED``
+    event (key and correlation key) no partition's committed log holds.
+    Nothing where the traffic publishes no message."""
+    messages = gen.get("messages")
+    if not messages:
+        return {}
+    published = {
+        (r.key, r.correlation) for rows in logs.values() for r in rows
+        if r.vtype == MESSAGE and r.rtype == ref.EVENT and r.intent == MESSAGE_PUBLISHED
+    }
+    sent = [r.get("publish", {}) for r in acked if r["process"] in messages]
+    answered = [(p["key"], p["correlation_key"]) for p in sent if "key" in p]
+    return {
+        "publish_errors": [len(sent) - len(answered), 0],
+        "acked_publish_not_in_log": [sum(1 for k in answered if k not in published), 0],
+    }
 
 
 def is_correct(compared: dict) -> bool:

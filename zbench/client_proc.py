@@ -11,7 +11,9 @@ Events go to standard output as JSON lines; commands (``warm``, ``go``,
 
 from __future__ import annotations
 
+import heapq
 import importlib
+import itertools
 import json
 import sys
 import threading
@@ -23,6 +25,7 @@ from zbench import traffic
 WI_VALUE_TYPE = 5  # protocol: ValueType.WORKFLOW_INSTANCE
 EVENT = 0  # protocol: RecordType.EVENT
 ELEMENT_COMPLETED = 9  # protocol: WorkflowInstanceIntent.ELEMENT_COMPLETED
+PUBLISHER_THREADS = 8
 
 
 def emit(ev: str, **fields) -> None:
@@ -46,6 +49,73 @@ def connect(spec: dict):
         num_partitions=spec["partitions"],
         request_timeout_ms=spec["request_timeout_ms"],
     )
+
+
+class Publisher:
+    """One message for each acknowledged create of a process that the
+    workload's ``messages`` names (process id -> ``name``,
+    ``correlation_var``, ``delay_ms``, ``ttl_ms``), published ``delay_ms``
+    after the create's acknowledgement on threads of its own. The
+    correlation key is ``str(payload[correlation_var])``, the payload
+    ``traffic.message_for``. Each publish's answer goes into its create's
+    row under ``publish``."""
+
+    def __init__(self, gen, messages: dict):
+        self.gen = gen
+        self.messages = messages
+        self.due: list = []  # heap of (due, tie, row)
+        self.tie = itertools.count()
+        self.cv = threading.Condition()
+        self.closing = False
+        self.threads = [
+            threading.Thread(target=self._loop, daemon=True) for _ in range(PUBLISHER_THREADS)
+        ]
+        for t in self.threads:
+            t.start()
+
+    def schedule(self, row: dict) -> None:
+        due = row["acked"] + self.messages[row["process"]]["delay_ms"] / 1000.0
+        with self.cv:
+            heapq.heappush(self.due, (due, next(self.tie), row))
+            self.cv.notify()
+
+    def _loop(self) -> None:
+        while True:
+            with self.cv:
+                while True:
+                    now = time.monotonic()
+                    if self.due and self.due[0][0] <= now:
+                        due, _, row = heapq.heappop(self.due)
+                        break
+                    if self.closing and not self.due:
+                        return
+                    self.cv.wait(self.due[0][0] - now if self.due else 0.1)
+            self._publish(row, due)
+
+    def _publish(self, row: dict, due: float) -> None:
+        m = self.messages[row["process"]]
+        out = {"due": due, "correlation_key": str(row["payload"][m["correlation_var"]])}
+        out["sent"] = time.monotonic()
+        try:
+            rsp = self.gen.client.publish_message(
+                m["name"], out["correlation_key"],
+                traffic.message_for(row["process"], row["payload"]), m["ttl_ms"],
+            )
+            out["acked"] = time.monotonic()
+            out["key"] = rsp.key
+        except Exception as e:  # noqa: BLE001 - a failed publish is a counted result
+            out["error"] = repr(e)[:300]
+        with self.gen.lock:
+            row["publish"] = out
+
+    def close(self, timeout_s: float) -> None:
+        """Send what is due still, then stop the threads."""
+        with self.cv:
+            self.closing = True
+            self.cv.notify_all()
+        deadline = time.monotonic() + timeout_s
+        for t in self.threads:
+            t.join(max(0.0, deadline - time.monotonic()))
 
 
 class Generator:
@@ -75,6 +145,8 @@ class Generator:
             )
             for p in range(self.partitions)
         ]
+        messages = spec.get("messages")
+        self.publisher = Publisher(self, messages) if messages else None
 
     # the subscription: how a client of this era learns an instance ended
     def on_record(self, partition: int, record) -> None:
@@ -125,6 +197,8 @@ class Generator:
                     self._mark_done(row, t)
         if "key" not in row:
             self.slots.release()  # a refused create frees its slot
+        elif self.publisher is not None and pid in self.publisher.messages:
+            self.publisher.schedule(row)
         return row
 
     def wait_done(self, rows: list, timeout_s: float) -> bool:
@@ -167,16 +241,20 @@ class Generator:
             rows = list(self.rows)
         emit("done", drained=drained)
         expect("stop")
+        report = {}
+        if self.publisher is not None:
+            self.publisher.close(self.spec["request_timeout_ms"] / 1000 + 5)
+            report["messages"] = self.publisher.messages
         for s in self.subs:
             s.close()
         self.client.close()
-        with open(self.spec["out"], "w") as f:
+        with self.lock, open(self.spec["out"], "w") as f:
             json.dump(
                 {
                     "window_start": start, "window_end": end, "grace_end": grace_end,
                     "drained": drained, "sub_records": self.sub_records,
                     "unmatched_completions": len(self.early),
-                    "jax_imported": "jax" in sys.modules, "rows": rows,
+                    "jax_imported": "jax" in sys.modules, **report, "rows": rows,
                 },
                 f,
             )

@@ -43,7 +43,12 @@ def main() -> int:
     args = ap.parse_args()
     seeds = [int(s) for s in args.seeds.split(",")]
     bad = 0
+    traffic = spec.Cell(args.workload).traffic
     for fault in [None] * (not args.skip_sound) + args.faults.split(","):
+        if fault and not faults.applies(fault, traffic):
+            print(json.dumps({"workload": args.workload, "fault": fault,
+                              "skipped": "the cell cannot have this fault"}), flush=True)
+            continue
         for seed in seeds if fault else seeds[:1]:
             result = one(args.workload, seed, args.seconds, args.rehearsal, fault)
             failing = {k: v for k, v in result.get("compared", {}).items() if v[0] > v[1]}

@@ -11,15 +11,35 @@ must come out not correct. The benchmark's own runs never plant one.
                         (control: the exactly-once guarantee)
   lost_tail             the end of the log is lost after the broker closed
                         (control: the durability guarantee)
+  lost_publish          the message records of every seventh acknowledged
+                        publish, the first among them, are gone from the log
+                        on disk after the broker closed: each frame keeps its
+                        position and length but holds no value (control: the
+                        durability guarantee of a publish; only where the
+                        traffic publishes messages)
 """
 
 from __future__ import annotations
 
 import glob
 import os
+import struct
+import zlib
+
+from zbench.check import MESSAGE
 
 WORKFLOW_INSTANCE, EVENT, SEQUENCE_FLOW_TAKEN, ELEMENT_COMPLETED = 5, 0, 4, 9
-NAMES = ("altered_answer", "half_batch", "state_unchanged", "duplicate_completion", "lost_tail")
+NULL_VAL = 255
+# a record frame's header up to its value-type byte: length, crc32 over
+# bytes [8:length), position .. incident key, record type
+VALUE_TYPE_AT = struct.calcsize("<iIqqqqiiqiqB")
+NAMES = ("altered_answer", "half_batch", "state_unchanged", "duplicate_completion", "lost_tail",
+         "lost_publish")
+
+
+def applies(name: str, traffic: dict) -> bool:
+    """Whether a cell with this traffic can have the fault."""
+    return name != "lost_publish" or bool(traffic.get("messages"))
 
 
 def _materialized(entry):
@@ -119,9 +139,38 @@ def plant(name: str, servers: list) -> None:
             engine.dispatch_wave = dispatch_wave
 
 
-def after_close(name: str, data_dir: str) -> None:
-    if name != "lost_tail":
-        return
-    segments = sorted(glob.glob(os.path.join(data_dir, "partition-0", "segment-*.log")))
-    with open(segments[-1], "r+b") as f:
-        f.truncate(max(0, os.path.getsize(segments[-1]) - 16384))
+def after_close(name: str, data_dir: str, gen: dict) -> None:
+    if name == "lost_tail":
+        segments = sorted(glob.glob(os.path.join(data_dir, "partition-0", "segment-*.log")))
+        with open(segments[-1], "r+b") as f:
+            f.truncate(max(0, os.path.getsize(segments[-1]) - 16384))
+    elif name == "lost_publish":
+        _lose_publishes(data_dir, gen)
+
+
+def _lose_publishes(data_dir: str, gen: dict) -> None:
+    """Every frame of a MESSAGE record whose correlation key is a chosen
+    publish's, in every partition's segments, made a frame with no value
+    (value type NULL_VAL, its crc32 made good again): the log re-reads
+    whole, at the same positions, without those records."""
+    from zeebe_tpu.log.storage import SEGMENT_HEADER_SIZE
+    from zeebe_tpu.protocol import codec
+
+    acked = sorted(
+        (r for r in gen["rows"] if "key" in r.get("publish", {})), key=lambda r: r["seq"]
+    )
+    lost = {r["publish"]["correlation_key"] for r in acked[::7]}
+    for path in sorted(glob.glob(os.path.join(data_dir, "partition-*", "segment-*.log"))):
+        with open(path, "rb") as f:
+            data = bytearray(f.read())
+        offset, changed = SEGMENT_HEADER_SIZE, False
+        while offset < len(data):
+            record, end = codec.decode_record(data, offset)
+            if int(record.metadata.value_type) == MESSAGE and record.value.correlation_key in lost:
+                data[offset + VALUE_TYPE_AT] = NULL_VAL
+                struct.pack_into("<I", data, offset + 4, zlib.crc32(bytes(data[offset + 8:end])))
+                changed = True
+            offset = end
+        if changed:
+            with open(path, "r+b") as f:
+                f.write(data)

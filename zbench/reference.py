@@ -3,12 +3,24 @@
 A straightforward token walk over a process ``GRAPH`` (plain data under
 ``zbench/processes/``). It shares no code with the program: not the
 interpreter, not the kernel, not the model classes. Its inputs are what
-the generator sent (the create payload) and what the worker answered,
-both defined in ``zbench/traffic.py``. The numbers below are the wire
+the generator sent (the create payload) and what the traffic answered
+(``traffic.Inputs``: the worker's result, the published message), both
+defined in ``zbench/traffic.py``. The numbers below are the wire
 protocol's (reference ``protocol.xml``).
+
+Four element kinds are walked here: ``start``, ``exclusive_gateway``,
+``service_task`` and ``end``. Any other kind is a file found by its name,
+``zbench/rules/<kind>.py``, whose ``walk(node_id, node, payload, inputs)``
+returns the element's events, the jobs it adds, the payload after it and
+the node whose outgoing flow the token takes next.
 """
 
 from __future__ import annotations
+
+import importlib
+import re
+
+from zbench import traffic
 
 # value types
 JOB, WORKFLOW_INSTANCE = 0, 5
@@ -43,9 +55,27 @@ def _take(graph: dict, node_id: str, payload: dict) -> dict:
     return next(f for f in out if f["id"] == node["default"])
 
 
+_KIND = re.compile(r"^[a-z_][a-z0-9_]*$")
+
+
+def rule(kind: str):
+    """The rule file of an element kind: ``zbench.rules.<kind>``."""
+    name = f"zbench.rules.{kind}"
+    if _KIND.match(kind):
+        try:
+            return importlib.import_module(name)
+        except ModuleNotFoundError as e:
+            if e.name != name:
+                raise
+    raise ValueError(f"the reference has no rule for element kind {kind!r}")
+
+
 def expected(graph: dict, create_payload: dict, worker_result) -> dict:
     """The instance's workflow-instance events as (intent, element id,
-    payload) in order, and its jobs as (type, payload at creation, result)."""
+    payload) in order, and its jobs as (type, payload at creation, result).
+    A rule file gets the traffic's inputs: ``worker_result``, the traffic's
+    ``message_for``, and this instance's process id and create payload."""
+    inputs = traffic.Inputs(worker_result, traffic.message_for, graph["id"], dict(create_payload))
     gid = graph["id"]
     p = dict(create_payload)
     events = [
@@ -74,9 +104,12 @@ def expected(graph: dict, create_payload: dict, worker_result) -> dict:
                 (ELEMENT_COMPLETING, gid, dict(p)), (ELEMENT_COMPLETED, gid, dict(p)),
             ]
             return {"events": events, "jobs": jobs}
-        events += [(ELEMENT_READY, node_id, dict(p)), (ELEMENT_ACTIVATED, node_id, dict(p))]
         if kind != "service_task":
-            raise ValueError(f"the reference has no rule for element kind {kind!r}")
+            added, new_jobs, p, node_id = rule(kind).walk(node_id, node, dict(p), inputs)
+            events += added
+            jobs += new_jobs
+            continue
+        events += [(ELEMENT_READY, node_id, dict(p)), (ELEMENT_ACTIVATED, node_id, dict(p))]
         brought = worker_result(p)
         jobs.append((node["job_type"], dict(p), brought))
         events.append((ELEMENT_COMPLETING, node_id, dict(brought)))

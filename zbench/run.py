@@ -473,7 +473,7 @@ def main(argv=None) -> int:
         # -- the check: after the window, the peak read, the state freed --
         t = time.monotonic()
         if args.fault:
-            faults.after_close(args.fault, data_dir)
+            faults.after_close(args.fault, data_dir, gen)
         logs = read_logs(data_dir, cfg)
         rows_by_partition = {p: [check.normalize(r) for r in recs] for p, recs in logs.items()}
         try:

@@ -2,20 +2,24 @@
 CPU, in under two minutes. Arithmetic on made-up samples, the trace
 reduction on the small recorded trace, the roofline's byte count on a
 hand-made log, the loader's refusals, the controls of the check on
-hand-made logs, and one rehearsal run end to end."""
+hand-made logs, the reference against its golden digests, and one
+rehearsal run end to end."""
 
 from __future__ import annotations
 
+import hashlib
+import importlib
 import json
 import math
 import os
 import subprocess
 import sys
 
-from zbench import check, layers, reference as ref, roofline, spec, stats, traffic
+from zbench import check, faults, layers, reference as ref, roofline, spec, stats, traffic
 from zbench import trace as trace_mod
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+GOLDEN = os.path.join(HERE, "fixtures", "reference_golden.json")
 
 
 def expect(cond, what: str) -> None:
@@ -241,6 +245,162 @@ def controls_of_the_check() -> None:
            "a branch taken against its condition is not correct, and the instance's records are named")
 
 
+def process_graphs() -> dict:
+    """process id -> ``GRAPH`` of every process module under ``processes/``."""
+    graphs = {}
+    for name in sorted(os.listdir(os.path.join(HERE, "processes"))):
+        if name.endswith(".py") and not name.startswith("_"):
+            graph = importlib.import_module("zbench.processes." + name[:-3]).GRAPH
+            graphs[graph["id"]] = graph
+    return graphs
+
+
+def reference_digest(graph: dict, variant: int) -> str:
+    """sha256 of the JSON of ``reference.expected`` over eight create
+    payloads of one payload variant."""
+    got = []
+    for i in range(8):
+        payload = {"orderId": i * 37 + variant, "orderValue": traffic.ORDER_VALUES[i],
+                   "customer": f"c-{i % 7}"}
+        payload.update(graph["payload_variants"][variant])
+        got.append(ref.expected(graph, payload, traffic.worker_result))
+    return hashlib.sha256(json.dumps(got, sort_keys=True).encode()).hexdigest()
+
+
+def reference_is_the_golden() -> None:
+    with open(GOLDEN) as f:
+        golden = json.load(f)["digests"]
+    got = {
+        f"{pid}/{v}": reference_digest(graph, v)
+        for pid, graph in process_graphs().items() for v in range(len(graph["payload_variants"]))
+    }
+    expect(got == golden, f"reference.expected gives to the byte what fixtures/reference_golden.json "
+                          f"holds, for all {len(golden)} process x payload variant cases")
+
+
+# start -> an intermediate message catch on orderId -> end, as a process
+# module would give it; the traffic publishes its message
+WAITING = {
+    "id": "payment-wait",
+    "start": "start",
+    "nodes": {
+        "start": {"kind": "start"},
+        "paid": {"kind": "message_catch", "message": "payment-received",
+                 "correlation_var": "orderId"},
+        "end": {"kind": "end"},
+    },
+    "flows": [
+        {"id": "flow-start-paid-0", "from": "start", "to": "paid"},
+        {"id": "flow-paid-end-1", "from": "paid", "to": "end"},
+    ],
+    "payload_variants": [{}],
+}
+MESSAGES = {"payment-wait": {"name": "payment-received", "correlation_var": "orderId",
+                             "delay_ms": 0, "ttl_ms": 60000}}
+
+
+def publishing_log(n: int = 8) -> tuple:
+    """A sound hand-made log of ``n`` instances of ``WAITING``, one message
+    published for each, and the generator's report of it."""
+    rows, gen_rows = [], []
+    for i in range(n):
+        payload = {"orderId": i, "orderValue": 99, "customer": "c"}
+        key, message_key = 10 * (i + 1), 1000 + i
+        message = traffic.message_for("payment-wait", payload)
+        rows.append(check.Row(len(rows), 0, check.MESSAGE, check.MESSAGE_PUBLISHED, message_key,
+                              1000, None, "payment-received", message, None, str(i)))
+        want = ref.expected(WAITING, payload, traffic.worker_result)
+        for intent, element, p in want["events"]:
+            rows.append(check.Row(len(rows), 0, 5, intent, key, 1000, key, element, p, None))
+        gen_rows.append({
+            "seq": i, "process": "payment-wait", "partition": 0, "payload": payload, "key": key,
+            "sent": 1.0, "acked": 1.1, "done": 1.5,
+            "publish": {"due": 1.1, "correlation_key": str(i), "sent": 1.1, "acked": 1.2,
+                        "key": message_key},
+        })
+    return {0: rows}, {"rows": gen_rows, "unmatched_completions": 0, "messages": MESSAGES}
+
+
+def lost_publish_on_disk(messages: list, gen: dict) -> list:
+    """``messages`` (``check.Row`` of MESSAGE records) written by the
+    program's log to a data directory, ``lost_publish`` planted there as a
+    run plants it after close, and the log re-read from disk."""
+    import tempfile
+
+    from zeebe_tpu.log.logstream import LogStream
+    from zeebe_tpu.log.storage import SegmentedLogStorage
+    from zeebe_tpu.protocol.enums import RecordType, ValueType
+    from zeebe_tpu.protocol.metadata import RecordMetadata
+    from zeebe_tpu.protocol.records import MessageRecord, Record
+
+    with tempfile.TemporaryDirectory() as data_dir:
+        path = os.path.join(data_dir, "partition-0")
+        storage = SegmentedLogStorage(path)
+        LogStream(storage, partition_id=0).append([
+            Record(key=r.key, metadata=RecordMetadata(record_type=RecordType(r.rtype),
+                                                      value_type=ValueType.MESSAGE, intent=r.intent),
+                   value=MessageRecord(name=r.element, correlation_key=r.correlation,
+                                       payload=r.payload))
+            for r in messages
+        ])
+        storage.close()
+        faults.after_close("lost_publish", data_dir, gen)
+        storage = SegmentedLogStorage(path)
+        back = LogStream(storage, partition_id=0).reader(0).read_committed()
+        storage.close()
+    return [check.normalize(r) for r in back]
+
+
+def controls_of_the_publisher() -> None:
+    """Hand-made logs of a process that waits for a message: a sound one
+    passes with both publish counts at 0; a refused publish, an
+    acknowledged publish missing from the log and the ``lost_publish``
+    fault, planted in a log on disk, each turn ``correct`` false; without
+    messages neither count is there."""
+    graphs = {"payment-wait": WAITING}
+
+    def verdict(logs, gen):
+        compared, _ = check.compare(logs, gen, graphs, 0, 0, 0, 0)
+        return check.is_correct(compared), compared
+
+    ok, c = verdict(*publishing_log())
+    expect(ok and c["publish_errors"] == [0, 0] and c["acked_publish_not_in_log"] == [0, 0],
+           "a sound hand-made log of published messages is correct")
+    logs, gen = publishing_log()
+    del gen["rows"][3]["publish"]["key"]
+    gen["rows"][3]["publish"]["error"] = "TimeoutError()"
+    ok, c = verdict(logs, gen)
+    expect(not ok and c["publish_errors"][0] == 1, "a publish that timed out is not correct")
+    logs, gen = publishing_log()
+    del gen["rows"][5]["publish"]
+    ok, c = verdict(logs, gen)
+    expect(not ok and c["publish_errors"][0] == 1, "a publish that never went out is not correct")
+    logs, gen = publishing_log()
+    logs[0] = [r for r in logs[0] if not (r.vtype == check.MESSAGE and r.key == 1002)]
+    ok, c = verdict(logs, gen)
+    expect(not ok and c["acked_publish_not_in_log"][0] == 1,
+           "an acknowledged publish missing from the re-read log is not correct")
+    logs, gen = publishing_log()
+    messages = [r for r in logs[0] if r.vtype == check.MESSAGE]
+    back = lost_publish_on_disk(messages, gen)
+    expect([(r.position, r.key) for r in back] == [(i, r.key) for i, r in enumerate(messages)]
+           and [r.vtype for r in back].count(check.MESSAGE) == 6,
+           "lost_publish leaves the log whole on disk, at the same positions, "
+           "and the messages of the first and the eighth of 8 publishes without a value")
+    logs[0] = [r for r in logs[0] if r.vtype != check.MESSAGE] + back
+    ok, c = verdict(logs, gen)
+    expect(not ok and c["acked_publish_not_in_log"][0] == 2 and c["reference_mismatches"][0] == 0,
+           "lost_publish on disk is caught by acked_publish_not_in_log")
+    logs, gen = publishing_log()
+    del gen["messages"]
+    _, c = verdict(logs, gen)
+    expect("publish_errors" not in c and "acked_publish_not_in_log" not in c,
+           "a traffic without messages adds no publish count")
+    expect(faults.applies("lost_publish", {"messages": MESSAGES})
+           and not faults.applies("lost_publish", {}),
+           "lost_publish applies only to a cell whose traffic publishes messages")
+
+
 def rehearsal() -> None:
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     docs = spec.documents()
@@ -283,6 +443,8 @@ def main() -> int:
     roofline_bytes()
     refusals()
     controls_of_the_check()
+    reference_is_the_golden()
+    controls_of_the_publisher()
     if "--no-run" not in sys.argv:
         rehearsal()
     print("selfcheck passed")

@@ -1,13 +1,16 @@
 """The one general traffic generator's plan: which create goes out when,
-with what payload. Everything is drawn from the seed, and every seed gets
-the same multiset of processes, payload values and arrival gaps in another
-order, so that the seed changes the order of the work and not its amount.
-Standard library only: the child processes import it."""
+with what payload, and what the traffic answers an instance with besides
+(a worker's result, a published message). Everything is drawn from the
+seed, and every seed gets the same multiset of processes, payload values,
+messages and arrival gaps in another order, so that the seed changes the
+order of the work and not its amount. Standard library only: the child
+processes import it."""
 
 from __future__ import annotations
 
 import math
 import random
+from typing import Callable, NamedTuple
 
 ORDER_VALUES = (40, 99, 100, 250, 75, 180, 20, 1000)
 BLOCK = 20  # creates per shuffled block; shares are exact per block
@@ -24,6 +27,28 @@ def mix_block(mix: dict) -> list:
 def worker_result(job_payload: dict) -> dict:
     """What a worker answers a job with (the reference derives the same)."""
     return {"done": True, "receipt": (int(job_payload.get("orderId", 0)) * 7919 + 13) % 100003}
+
+
+def message_for(process_id: str, create_payload: dict) -> dict:
+    """The payload of the one message published for an instance of
+    ``process_id`` (the reference derives the same): a function of the
+    create's ``orderId``, its place in the sequence, so every seed gets the
+    same multiset of messages, in the order the seed gives the creates.
+    Scalars only, and no string unique to an instance."""
+    order = int(create_payload.get("orderId", 0))
+    return {"paid": True, "paymentId": (order * 104729 + 17) % 1000003}
+
+
+class Inputs(NamedTuple):
+    """What the traffic feeds an instance besides its create: the worker's
+    answer to a job and the message published for it, with the instance's
+    process id and create payload. The reference hands it to a rule file,
+    which calls what it needs."""
+
+    worker_result: Callable
+    message_for: Callable
+    process: str
+    create: dict
 
 
 class Plan:
