@@ -1437,6 +1437,15 @@ class PartitionEngine:
             self._write_wi_followup(out, record, record.key, WI.ELEMENT_COMPLETED, value)
         except MappingError as e:
             self._raise_incident(record, ErrorType.IO_MAPPING_ERROR, str(e), out)
+            return
+        if scope is not None:
+            # the scope's payload takes the merged one, so the next
+            # element's output merges into what this one added (the
+            # payload flows from element to element); a multi-instance
+            # container keeps its own, as at a consumed token
+            scope_el = workflow.element_by_id(scope.value.activity_id)
+            if scope_el is None or not scope_el.is_multi_instance:
+                scope.value.payload = dict(value.payload)
 
     def _h_activate_gateway(self, record, element, workflow, instance, scope, out):
         # reference ActivateGatewayHandler

@@ -530,6 +530,50 @@ def _phase_handles() -> dict:
                 "Activatable jobs a backlog sweep left alone because a pool "
                 "event or an ACTIVATE of theirs was on its way to a wave",
             ),
+            # the device engine's message tables (kernel.step's stats vector
+            # where the message store is on the device; tpu/engine.py)
+            message_cut=g.counter(
+                "serving_message_cut_seconds_total",
+                "Seconds checking the commands on the device's message tables "
+                "against the composites (message name, correlation value) "
+                "their pending segment holds: phase message_cut, cut out of "
+                "route",
+            ),
+            message_cuts=g.counter(
+                "serving_message_cuts_total",
+                "Commands on the device's message tables that met an earlier "
+                "one on the same composite in their segment and started a "
+                "segment of their own (a PUBLISH and an OPEN that would not "
+                "see each other)",
+            ),
+            message_publishes=g.counter(
+                "serving_message_publishes_total",
+                "Message PUBLISH commands the device stepped",
+            ),
+            messages_stored=g.counter(
+                "serving_messages_stored_total",
+                "Published messages with a time to live the device stored",
+            ),
+            messages_deleted=g.counter(
+                "serving_messages_deleted_total",
+                "Stored messages the device deleted (DELETE commands: the "
+                "time to live ran out)",
+            ),
+            message_correlated_at_open=g.counter(
+                "serving_message_correlated_at_open_total",
+                "Subscriptions whose OPEN found a stored message and "
+                "correlated it",
+            ),
+            message_correlated_at_publish=g.counter(
+                "serving_message_correlated_at_publish_total",
+                "Published messages that found an open subscription and "
+                "correlated to it",
+            ),
+            messages_live=g.gauge(
+                "serving_messages_live",
+                "Messages now in the device's store (stored less deleted, "
+                "set at each flush)",
+            ),
             job_commands_serialised=g.counter(
                 "serving_job_commands_serialised_total",
                 "Job commands that met an earlier command on the same job "
@@ -737,6 +781,10 @@ def observe_phases(clock, cycle: Optional[str] = None) -> None:
     if "payload_values_held" in clock.counts or "payload_values_freed" in clock.counts:
         h["payload_values_live"].set(
             h["payload_values_held"].value - h["payload_values_freed"].value
+        )
+    if "messages_stored" in clock.counts:
+        h["messages_live"].set(
+            h["messages_stored"].value - h["messages_deleted"].value
         )
     if cycle is not None:
         h[cycle].inc()

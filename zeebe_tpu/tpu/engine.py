@@ -40,6 +40,7 @@ from zeebe_tpu.engine.interpreter import (
 )
 from zeebe_tpu import tracing
 from zeebe_tpu.engine.mappings import MappingError, extract, merge
+from zeebe_tpu.models.bpmn.model import ElementType
 from zeebe_tpu.models.el.interpreter import ConditionEvalError, evaluate_condition
 from zeebe_tpu.protocol.enums import ErrorType, RecordType, RejectionType, ValueType
 from zeebe_tpu.protocol.intents import (
@@ -78,6 +79,24 @@ _MESSAGE_VALUE_TYPES = {
     int(ValueType.MESSAGE_SUBSCRIPTION),
     int(ValueType.WORKFLOW_INSTANCE_SUBSCRIPTION),
 }
+# workflow-instance events whose step reads or writes the payload of the
+# element's flow scope (kernel.step: an output mapping merges into it and
+# writes it back, a consumed token writes it)
+_SCOPE_PAYLOAD_INTENTS = frozenset(
+    int(i) for i in (
+        WI.ELEMENT_COMPLETING, WI.ELEMENT_COMPLETED, WI.END_EVENT_OCCURRED,
+    )
+)
+# the value types whose commands a device segment serialises
+# (TpuPartitionEngine._route_wave): a job's commands by job key, and where
+# the message store is on the device, the commands on the message tables
+# by their (message name, correlation value) composite
+_CUT_JOB = frozenset({int(ValueType.JOB)})
+_CUT_JOB_AND_MESSAGES = frozenset({
+    int(ValueType.JOB),
+    int(ValueType.MESSAGE),
+    int(ValueType.MESSAGE_SUBSCRIPTION),
+})
 
 _ERR_NO_RETRIES = 105  # kernel's JOB_NO_RETRIES incident code
 
@@ -244,6 +263,21 @@ def _as_record(entry) -> Record:
     if type(entry) is tuple:
         return entry[0].row(entry[1])
     return entry
+
+
+def _message_composite(vt: int, value) -> tuple:
+    """The (message name, correlation value) composite a MESSAGE or
+    MESSAGE_SUBSCRIPTION command touches in the device's message tables,
+    equal where ``state.corr_composite`` of its staged columns
+    (``_stage_corr``) is equal: a string as itself, a number by its f32
+    bits, an empty or other key as none."""
+    name = value.name if vt == int(ValueType.MESSAGE) else value.message_name
+    corr = value.correlation_key
+    if isinstance(corr, str):
+        return name, corr or None
+    if isinstance(corr, (int, float)):
+        return name, None, int(np.float32(float(corr)).view(np.int32))
+    return name, None
 
 
 # frame-field defaults of a fresh Record/metadata (producer_id,
@@ -655,6 +689,11 @@ class TpuPartitionEngine:
         # message store side (see _recompile): True = device tables serve
         # this partition's MESSAGE-partition role
         self._messages_on_device = False
+        self._cut_value_types = _CUT_JOB
+        # a deployed workflow lets one scope hold several tokens (a
+        # parallel fork, a boundary event): _route_wave then keeps the
+        # scope-payload steps of one scope in separate segments
+        self._scope_cuts = False
         self._restoring = False
         # ONE position→record cache shared with the embedded host oracle:
         # the broker fills it during recovery, host-side incident
@@ -852,6 +891,11 @@ class TpuPartitionEngine:
                 f"num_vars={self.num_vars}; raise num_vars"
             )
         self._compiled_count = len(workflows)
+        self._scope_cuts = self.graph.has_boundaries or any(
+            el.element_type == ElementType.PARALLEL_GATEWAY
+            and len(el.outgoing) > 1
+            for wf in workflows for el in wf.elements
+        )
         # The message store (this partition's MESSAGE-partition role: stored
         # messages + open subscriptions) lives on EXACTLY one side. Device
         # iff the deployed set compiles with message elements and has no
@@ -864,6 +908,9 @@ class TpuPartitionEngine:
     def _set_message_store_side(self, on_device: bool) -> None:
         prev = self._messages_on_device
         self._messages_on_device = on_device
+        self._cut_value_types = (
+            _CUT_JOB_AND_MESSAGES if on_device else _CUT_JOB
+        )
         if self._restoring:
             return
         if on_device and not prev:
@@ -2270,6 +2317,19 @@ class TpuPartitionEngine:
         # starts a new segment (the next step program of this wave, in log
         # order, on the table the first one left)
         seg_job_cmds: Dict[int, int] = {}
+        # the same for the message tables (kernel.step, "message tables"):
+        # a PUBLISH and an OPEN (or any two commands) on one composite in
+        # one segment would each read the tables as they were before it,
+        # so neither would see the other; the second starts a new segment
+        seg_msg_composites: set = set()
+        cut_vts = self._cut_value_types
+        # the scopes whose payload a step of the pending segment reads or
+        # writes (kernel.step, "scope payload"): where a scope can hold
+        # several tokens, a second such step of one scope starts a new
+        # segment, so it merges into what the first left
+        seg_scopes: set = set()
+        scope_cuts = self._scope_cuts
+        vt_wi = int(ValueType.WORKFLOW_INSTANCE)
         # job keys whose activation record this wave steps: they leave
         # ``_assigning`` when the wave is collected
         assigning_stepped = wave.assigning_stepped
@@ -2281,6 +2341,8 @@ class TpuPartitionEngine:
 
         def flush() -> None:
             seg_job_cmds.clear()
+            seg_msg_composites.clear()
+            seg_scopes.clear()
             if not pending:
                 return
             push_host_keys()  # device allocations continue after the host's
@@ -2334,6 +2396,15 @@ class TpuPartitionEngine:
                 rc = self._wave_route_class(entry, True, vt, rt, intent)
                 if pending and rc != pending_route[0]:
                     flush()
+                if scope_cuts and vt == vt_wi and (
+                    intent in _SCOPE_PAYLOAD_INTENTS
+                ):
+                    src, j = entry[0].device_ref(entry[1])
+                    scope_key = int(src.device_source[1]["scope_key"][j])
+                    if scope_key >= 0:
+                        if scope_key in seg_scopes:
+                            flush()
+                        seg_scopes.add(scope_key)
                 pending_route[0] = rc
                 pending.append(i)
                 if pool_event:
@@ -2356,16 +2427,30 @@ class TpuPartitionEngine:
                 rc = self._wave_route_class(record, False, vt, rt, intent)
                 if pending and rc != pending_route[0]:
                     flush()
-                if (
-                    vt == vt_job and rt == rt_command
-                    and intent in _JOB_ROW_COMMANDS
+                if scope_cuts and vt == vt_wi and rt == rt_event and (
+                    intent in _SCOPE_PAYLOAD_INTENTS
                 ):
-                    first = seg_job_cmds.get(key)
-                    if first is not None:
-                        clock.count("job_commands_serialised", 1)
-                        if first != intent or intent == _JI_UPDATE_RETRIES:
+                    scope_key = record.value.scope_instance_key
+                    if scope_key >= 0:
+                        if scope_key in seg_scopes:
                             flush()
-                    seg_job_cmds[key] = intent
+                        seg_scopes.add(scope_key)
+                elif rt == rt_command and vt in cut_vts:
+                    if vt != vt_job:
+                        with clock.phase("message_cut"):
+                            composite = _message_composite(vt, record.value)
+                            met = composite in seg_msg_composites
+                        if met:
+                            clock.count("message_cuts", 1)
+                            flush()
+                        seg_msg_composites.add(composite)
+                    elif intent in _JOB_ROW_COMMANDS:
+                        first = seg_job_cmds.get(key)
+                        if first is not None:
+                            clock.count("job_commands_serialised", 1)
+                            if first != intent or intent == _JI_UPDATE_RETRIES:
+                                flush()
+                        seg_job_cmds[key] = intent
                 pending_route[0] = rc
                 pending.append(i)
                 if pool_event:
@@ -2455,6 +2540,8 @@ class TpuPartitionEngine:
                     )
                 if len(self.repository.by_key) != deployed_before:
                     self._recompile()
+                    cut_vts = self._cut_value_types
+                    scope_cuts = self._scope_cuts
                 # key-sync check runs even for a poisoned record: a handler
                 # may allocate keys before raising
                 if (
@@ -3435,6 +3522,9 @@ class TpuPartitionEngine:
             raise RuntimeError(
                 "device table overflow — raise TpuPartitionEngine capacity"
             )
+        if len(stats) > len(kernel.STATS):
+            for name, n in zip(kernel.MESSAGE_STATS, stats[len(kernel.STATS):]):
+                clock.count(name, int(n))
         o = rb.column_views(packed)
         # collection is one-shot: clear the device refs BEFORE decoding so
         # a re-collect of this wave (the drain's finally path after an

@@ -550,7 +550,13 @@ def compile_graph(
         emit_width=emit_width,
         max_join_in=join_in,
         has_conditions=bool((cond_prog >= 0).any()),
-        has_parallel_joins=bool((join_nin >= 2).any()),
+        # a converging EXCLUSIVE gateway has several incoming flows too,
+        # but only a parallel one waits for them (PARALLEL_MERGE)
+        has_parallel_joins=any(
+            el.element_type == ElementType.PARALLEL_GATEWAY
+            and len(el.incoming) >= 2
+            for wf in workflows for el in wf.elements
+        ),
         has_timers=bool((timer_dur >= 0).any() or (bd_timer >= 0).any()),
         has_mappings=bool(
             (in_map_n > 0).any() or (out_map_n > 0).any()

@@ -351,6 +351,10 @@ def scope_to_global(ei_i32, prev_global_scope, shard_index, local_rows):
 
 # the entries of a step's stats vector (i32), in order
 STATS = ("processed", "stepped", "emitted", "completed_roots", "overflow")
+# appended to the vector where the graph has messages: the message tables'
+# outcomes of the step, for the engine's counters
+MESSAGE_STATS = ("message_publishes", "messages_stored", "messages_deleted",
+                 "message_correlated_at_open", "message_correlated_at_publish")
 
 
 def stats_of(stats) -> dict:
@@ -2064,9 +2068,15 @@ def step_kernel(
     ei_ops.append(pops.TableOp(T_EI32, "set", ei_clip, tokset_m, ts_vals, ts_mask))
 
     # scope payload on consume (oracle: scope value.payload = record
-    # payload — EXCEPT multi-instance containers, whose iteration-local
-    # variables must not leak into the container payload)
+    # payload) and on an element's output mapping (the merged payload, so
+    # the next element merges into what this one added) — EXCEPT
+    # multi-instance containers, whose iteration-local variables must not
+    # leak into the container payload. Two such rows of one scope never
+    # share a segment (TpuPartitionEngine._route_wave cuts between them
+    # where a scope can hold several tokens), so each merge above read the
+    # scope's payload as the oracle's sequential loop leaves it
     b_pay = pack_payload(batch.v_vt, batch.v_str, batch.v_num)
+    outmap_pay_m = outmap_ok & sc_found
     if graph.has_multi_instance:
         scope_elem_c = jnp.clip(
             jnp.where(sc_found, sc_rows[:, EI_ELEM], 0),
@@ -2078,11 +2088,15 @@ def step_kernel(
         )
         mi_scope = graph.mi_cardinality[scope_wf_c, scope_elem_c] > 0
         consume_pay_m = m_consume & ~mi_scope
+        outmap_pay_m = outmap_pay_m & ~mi_scope
     else:
         consume_pay_m = m_consume
+    scope_pay = jnp.where(
+        outmap_pay_m[:, None], pack_payload(out_vt, out_sid, out_num), b_pay
+    )
     ei_ops.append(pops.TableOp(
         T_EIPAY, "set", sc_clip,
-        _last_writer(sc_clip, consume_pay_m, n_cap), b_pay,
+        _last_writer(sc_clip, consume_pay_m | outmap_pay_m, n_cap), scope_pay,
     ))
     # scope state transition by consume completer
     cc_vals, cc_mask = _col_op(
@@ -2589,8 +2603,13 @@ def step_kernel(
         sub_rr=state.sub_rr,
         next_wf_key=next_wf_key, next_job_key=next_job_key,
     )
-    # one small vector (``STATS`` names its entries), so a collect fetches
-    # the wave's counts and its overflow flag as one array
+    # one small vector (``STATS`` names its entries, then ``MESSAGE_STATS``
+    # where the graph has messages), so a collect fetches the wave's counts
+    # and its overflow flag as one array
+    message_stats = [
+        jnp.sum(m, dtype=jnp.int32)
+        for m in (msg_pub, pub_store, del_ok, open_corr, pub_corr)
+    ] if graph.has_messages else []
     stats = jnp.stack([
         jnp.sum(valid, dtype=jnp.int32),
         jnp.sum(stepped, dtype=jnp.int32)
@@ -2603,6 +2622,7 @@ def step_kernel(
             ei_overflow | job_overflow | join_overflow | timer_overflow
             | message_overflow
         ).astype(jnp.int32),
+        *message_stats,
     ])
     return new_state, out, stats
 

@@ -56,8 +56,9 @@ CPU_CLOCK_STRIDE = 8
 # track ``actor`` holds the broker actor's jobs, inside which the others run
 TRACKS = {
     "actor": ("actor_idle",) + tuple("job:" + kind for kind in JOB_KINDS),
-    "wave": ("pack", "route", "stage", "h2d", "credit_flush", "launch",
-             "blocked", "readback", "decode", "apply", "push", "job_read"),
+    "wave": ("pack", "route", "message_cut", "stage", "h2d", "credit_flush",
+             "launch", "blocked", "readback", "decode", "apply", "push",
+             "job_read"),
     "drain": ("drain_wait", "pump"),
     "tick": ("tick", "credit_flush", "backlog", "job_read"),
     "raft": ("log_append", "fsync", "commit"),
@@ -67,6 +68,10 @@ TRACKS = {
 # job's row read back and made a record) out of whichever cycle asks for
 # it (a tick's ``backlog`` and deadline sweep; a subscription's backlog runs
 # outside every cycle and flushes a clock of its own).
+# ``message_cut`` is cut out of ``route``: where the message store is on
+# the device, the check of each command on the message tables against the
+# composites its pending segment already holds (a PUBLISH and an OPEN on one
+# composite may not share a segment), the flush it calls excluded.
 # The device engine's value store (tpu/values.py) times its own work
 # (holding, resolving, owning and freeing payload values) on a clock of its
 # own, counted as ``payload_value_seconds`` beside ``payload_values_held``,
@@ -76,7 +81,8 @@ TRACKS = {
 # what PendingWave.host_seconds / device_seconds sum: host work of the wave
 # path, and host time waiting for the device (never a device time); the job
 # path's phases are in neither
-WAVE_HOST_PHASES = ("route", "stage", "h2d", "launch", "decode")
+WAVE_HOST_PHASES = ("route", "message_cut", "stage", "h2d", "launch",
+                    "decode")
 WAVE_BLOCKED_PHASES = ("blocked", "readback")
 
 # What a thread keeps for the clocks that run on it. ``slices``: the
